@@ -4,7 +4,8 @@
 Usage: python scripts/profile_q3.py [query] [scale]
 Writes a profiler trace to /tmp/jaxprof (open the xplane.pb with
 tensorboard_plugin_profile, or parse it directly — see git history for
-a snippet) and prints cold/warm timings.
+a snippet) and prints cold/warm timings.  The compile cache is placed by
+TpuSession() (exec/compiled.configure_persistent_cache).
 """
 import os
 import sys
@@ -14,10 +15,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
 import jax
-
-jax.config.update("jax_compilation_cache_dir", _REPO + "/.jax_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 qname = sys.argv[1] if len(sys.argv) > 1 else "q3"
 scale = float(sys.argv[2]) if len(sys.argv) > 2 else 1.0

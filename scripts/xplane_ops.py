@@ -4,7 +4,7 @@
 Usage: python scripts/xplane_ops.py /tmp/jaxprof [topN]
 Aggregates XLA op events on the device plane by op category (the HLO
 fingerprint up to the numeric suffix) and prints total us + count,
-descending.  This is the measured per-op breakdown docs/PERF.md cites.
+descending: the measured per-op breakdown of one trace.
 """
 import collections
 import glob

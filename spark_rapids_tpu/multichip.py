@@ -33,19 +33,18 @@ from typing import Dict, List, Optional
 import numpy as np
 
 
-def _setup_devices(n_devices: int) -> None:
-    """Secure n virtual CPU devices BEFORE backend init (the
-    __graft_entry__.dryrun_multichip / tests-conftest recipe)."""
+def _setup_devices(n_devices: int) -> int:
+    """-> the mesh width to run at.  With JAX_PLATFORMS=cpu (tests, dry
+    run): secure n virtual CPU devices BEFORE backend init.  Otherwise
+    the suite runs on the chips jax finds, all of them."""
     import jax
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") +
-        f" --xla_force_host_platform_device_count={n_devices}")
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        return len(jax.devices())
     try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_enable_x64", True)
         jax.config.update("jax_num_cpu_devices", n_devices)
-    except (RuntimeError, AttributeError):
-        pass                    # backend already up, or pre-0.5 jax
+    except RuntimeError:
+        pass                    # backend already up: use what exists
+    return n_devices
 
 
 def gen_tables_sharded(scale: float, n_shards: int, seed: int = 20240706
@@ -228,10 +227,9 @@ def run_multichip_suite(n_devices: int = 8, sf: float = 10.0,
     the sharded TPC-H suite.  Prints a running JSON line after every
     stage (the bench.py lossless-kill discipline) and returns the final
     document."""
-    _setup_devices(n_devices)
+    n_devices = _setup_devices(n_devices)
     import jax
-    from .config import (COMPILE_CACHE_DIR, HBM_BUDGET_BYTES,
-                         MESH_DEVICES, MESH_ENABLED)
+    from .config import HBM_BUDGET_BYTES, MESH_DEVICES, MESH_ENABLED
     from .exec.plan import ExecContext
     from .parallel.mesh import make_mesh
     from .session import DataFrame, TpuSession
@@ -259,16 +257,14 @@ def run_multichip_suite(n_devices: int = 8, sf: float = 10.0,
             doc["peak_rss_mb"] = -1
         print(json.dumps(doc), flush=True)
 
-    # topology-scoped persistent compile cache (the bench.py discipline:
-    # cold numbers report cache loads; the per-round pcache delta below
-    # is the proof of what was compiled vs replayed)
-    cache_root = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache_bench")
-    from .config import TpuConf
+    # persistent compile cache (the bench.py discipline: cold numbers
+    # report cache loads; the per-round pcache delta below is the proof
+    # of what was compiled vs replayed).  The primitives below jit before
+    # any session exists, so place the cache explicitly.
+    from .config import DEFAULT_CONF, TpuConf
     from .exec.compiled import (configure_persistent_cache,
                                 persistent_cache_stats)
-    configure_persistent_cache(TpuConf(
-        {COMPILE_CACHE_DIR.key: cache_root}))
+    configure_persistent_cache(DEFAULT_CONF)
     pc0 = persistent_cache_stats()
 
     mesh = make_mesh(n_devices)
@@ -290,8 +286,7 @@ def run_multichip_suite(n_devices: int = 8, sf: float = 10.0,
 
     # -- r05-comparable mesh microqueries (tiny SF, same keys) ------------
     micro_tables = tpch.gen_tables(scale=0.002)
-    mesh_conf = {MESH_ENABLED.key: True, MESH_DEVICES.key: n_devices,
-                 COMPILE_CACHE_DIR.key: cache_root}
+    mesh_conf = {MESH_ENABLED.key: True, MESH_DEVICES.key: n_devices}
     s = TpuSession(mesh_conf)
     cpu = TpuSession({"spark.rapids.tpu.sql.enabled": "false"})
     for qname in ("q1", "q6", "q12"):

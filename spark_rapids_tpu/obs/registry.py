@@ -484,8 +484,8 @@ PLAN_CACHE = REGISTRY.counter(
 COMPILE_PERSISTENT_HITS = REGISTRY.counter(
     "tpu_compile_cache_persistent_hits_total",
     "XLA compiles served from the on-disk persistent compile cache "
-    "(jax compilation cache under spark.rapids.tpu.compile.cacheDir's "
-    "topology-scoped subdirectory).")
+    "(jax compilation cache; JAX_COMPILATION_CACHE_DIR, else "
+    "spark.rapids.tpu.compile.cacheDir, else <checkout>/.jax_cache).")
 
 COMPILE_PERSISTENT_MISSES = REGISTRY.gauge(
     "tpu_compile_cache_persistent_misses",

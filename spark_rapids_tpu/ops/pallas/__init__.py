@@ -108,7 +108,7 @@ def _resolve_tier(conf: TpuConf) -> KernelTier:
     # join/compact win on every backend (the interpreted kernels beat
     # the sort path on XLA-CPU too — measured in bench.py --kernels);
     # segagg's block accumulators only pay off where Pallas compiles
-    # natively (XLA-CPU scatters are fast, docs/PERF.md §8)
+    # natively (XLA-CPU scatters are fast)
     return KernelTier(
         join=mode(PALLAS_JOIN, True),
         segagg=mode(PALLAS_SEGAGG, native and not interpret),

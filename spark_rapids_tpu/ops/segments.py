@@ -4,7 +4,7 @@ Every holistic operator in this engine (group-by, count-distinct,
 percentile, collect, window frames, sort-merge joins) reduces over
 CONTIGUOUS RUNS of a sorted batch.  This module is the one home for the
 primitives those operators share, shaped by the two platform costs that
-dominate this chip (docs/PERF.md §1):
+dominated the round-5 chip profiles:
 
   * **Scatters are the enemy at runtime** (~70 ms per 1M rows, and their
     outputs land in S(1)-space buffers whose consumers run ~200 MB/s).

@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import types as t
@@ -58,7 +59,7 @@ from ..ops.bitpack import (bytes_to_words, for_decode, for_encode,
                            words_to_lane)
 from ..ops.hashing import hash_int64
 from ..runtime.faults import fire_active
-from .mesh import shard_map, SHARD_AXIS
+from .mesh import SHARD_AXIS
 
 #: lane wire treatments a caller can declare per lane
 RAW = "raw"      # integer/float payload; FOR-narrowed when range allows

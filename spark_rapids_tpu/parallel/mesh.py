@@ -18,20 +18,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SHARD_AXIS = "shards"
 
-# jax>=0.4.40 exports shard_map at top level (kwarg check_vma); older
-# jaxlibs keep it in jax.experimental with the kwarg spelled check_rep.
-# One resolved symbol so every collective program builder works on both.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                       # pragma: no cover - old jax
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
 
 def make_mesh(n_devices: Optional[int] = None,
               axis: str = SHARD_AXIS) -> Mesh:

@@ -31,9 +31,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .mesh import shard_map
 
 DCN_AXIS = "dcn"
 ICI_AXIS = "ici"

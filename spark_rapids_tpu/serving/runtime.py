@@ -219,6 +219,8 @@ class ServingRuntime:
         self._worker_pool = None
         self._device_slots = rconf.get(SERVING_DEVICE_SLOTS)
         if self._pool_procs > 0:
+            from .workers import require_shareable_device
+            require_shareable_device(self._pool_procs)
             # each worker process owns its own device slice + budget:
             # device phases genuinely run in parallel across processes,
             # so the grant width IS the pool width

@@ -96,6 +96,25 @@ def _unframe(data: bytes) -> dict:
 # Supervisor side
 # ===========================================================================
 
+def require_shareable_device(procs: int) -> None:
+    """A TPU chip belongs to ONE process: the supervisor — this process,
+    whose session, admission gate and callers all use jax — holds every
+    local chip, and a worker process that then needs one fails or hangs.
+    So on a TPU the pool refuses to start, at once and by name, instead
+    of spawning workers that cannot open the device.  On the CPU backend
+    every process has its own device and the pool runs as built."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return
+    devs = jax.devices()
+    raise RuntimeError(
+        f"spark.rapids.tpu.serving.pool.processes={procs} cannot run on a "
+        f"TPU: this process (pid {os.getpid()}) holds the chip(s) "
+        f"({len(devs)} x {devs[0].device_kind}), a chip belongs to one "
+        f"process, and worker processes could not open it. Serve "
+        f"in-process (serving.pool.processes=0), one process per chip.")
+
+
 class _Dispatch:
     """One in-flight query on one worker (supervisor bookkeeping)."""
 

@@ -40,9 +40,9 @@ class TpuSession:
         # Prometheus endpoint) as soon as a session exists
         from .obs.export import configure_plane
         configure_plane(self.conf)
-        # engine-level persistent compile cache (topology-scoped AOT
-        # executables; spark.rapids.tpu.compile.cacheDir) — a no-op
-        # when the conf is unset
+        # persistent compile cache: JAX_COMPILATION_CACHE_DIR, else
+        # spark.rapids.tpu.compile.cacheDir, else <checkout>/.jax_cache
+        # (touches jax.config only — no backend, no device)
         from .exec.compiled import configure_persistent_cache
         configure_persistent_cache(self.conf)
         # persistent performance-history store (structure-keyed measured

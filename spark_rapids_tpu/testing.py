@@ -67,7 +67,7 @@ def assert_device_cpu_equal(exprs: Sequence[Expression], data: Dict,
 # The two compile/runtime cliffs of this platform are directly visible in
 # the emitted jaxpr: variadic `sort` equations whose operand count blows
 # up XLA compile time, and `scatter*` equations whose outputs land in
-# slow S(1)-space buffers (docs/PERF.md §1).  These walkers turn both
+# slow S(1)-space buffers.  These walkers turn both
 # into assertable numbers for tier-1 tests and bench.py.
 
 _SCATTER_PRIMS = ("scatter", "scatter-add", "scatter-mul", "scatter-min",
@@ -164,8 +164,7 @@ def jaxpr_scatter_count(jaxpr) -> int:
 def jaxpr_gather_count(jaxpr) -> int:
     """Number of `gather` equations in the program — the descriptor-
     driven row-gather passes that dominate join-pipeline device time
-    (docs/PERF.md; each gathered lane moves at DMA rather than vector
-    bandwidth).  Late materialization (columnar/lanes.py) exists to
+    (each gathered lane moves at DMA rather than vector bandwidth).  Late materialization (columnar/lanes.py) exists to
     shrink this number: its per-query budget lint asserts the q3/q9/
     q15/q16-class programs emit FEWER gathers with the feature on."""
     return sum(1 for e in _iter_eqns(jaxpr)

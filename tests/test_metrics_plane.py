@@ -659,26 +659,31 @@ def test_metrics_docs_cover_every_registered_family():
 
 
 def test_check_regression_gate(tmp_path, capsys):
-    """Exit 0 on the committed BENCH_r*/MULTICHIP_r* trajectory; a
-    synthetic 2x slowdown of the newest round exits non-zero
+    """Exit 0 on the committed MULTICHIP_r*/KERNELS_r*/... trajectory; a
+    synthetic 2x slowdown of a per-query round exits non-zero
     (acceptance)."""
     mod = _load_script("check_regression")
     assert mod.main([]) == 0
     capsys.readouterr()
 
-    # build the 2x fixture from the real trajectory's newest data
-    # (load_file -> (queries, backend, compile_ms); net-of-RTT ms since
-    # the gate compares floor-subtracted values)
-    files = mod.default_trajectory()
-    per_file = [(p, *mod.load_file(p)) for p in files]
-    newest = [(qs, backend) for _, qs, backend, _cms in per_file if qs][-1]
-    assert newest[0], "no committed trajectory data to build the fixture"
-    slow = {q: {"device_ms_net": ms * 2.0}
-            for q, ms in newest[0].items()}
+    # a per-query round in bench.py's emitted shape (device_ms +
+    # sync_rtt_ms: the gate compares floor-subtracted values), and the
+    # same round 2x slower
+    base = tmp_path / "base.json"
+    base.write_text(json.dumps({
+        "backend": "cpu", "sync_rtt_ms": 1.0,
+        "tpch_suite_queries": {f"q{i}": {"device_ms": 100.0 * i + 1.0}
+                               for i in range(1, 6)}}))
+    qs, backend, _cms = mod.load_file(str(base))
+    assert backend == "cpu" and qs["q2"] == pytest.approx(200.0)
     fixture = tmp_path / "slow.json"
-    fixture.write_text(json.dumps({"tpch_suite_queries": slow,
-                                   "backend": newest[1]}))
-    rc = mod.main(["--current", str(fixture)])
+    fixture.write_text(json.dumps({
+        "tpch_suite_queries": {q: {"device_ms_net": ms * 2.0}
+                               for q, ms in qs.items()},
+        "backend": backend}))
+    assert mod.main(["--current", str(base), str(base)]) == 0
+    capsys.readouterr()
+    rc = mod.main(["--current", str(fixture), str(base)])
     out = capsys.readouterr().out
     assert rc == 1
     assert "REGRESSION" in out

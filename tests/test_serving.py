@@ -621,6 +621,29 @@ MP_FAST = {
 }
 
 
+def test_pool_refuses_a_tpu_at_once_and_by_name(monkeypatch):
+    """A chip belongs to one process and the supervisor holds it: on a
+    TPU the pool raises at serving() — naming the cause — instead of
+    spawning workers that cannot open the device and waiting on them."""
+    import jax
+    import spark_rapids_tpu.serving.workers as W
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        W.WorkerPool, "start",
+        lambda self, timeout=0: pytest.fail("the pool was started"))
+    s = TpuSession()
+    t0 = time.perf_counter()
+    with pytest.raises(RuntimeError, match="holds the chip") as ei:
+        s.serving({"spark.rapids.tpu.serving.pool.processes": "2"})
+    assert time.perf_counter() - t0 < 5.0
+    assert "serving.pool.processes=2" in str(ei.value)
+    assert s._serving is None
+    # in-process serving is what a TPU host runs; it still comes up
+    rt = s.serving()
+    assert rt.stats()["device_slots"] >= 1
+    s.close()
+
+
 def test_pool_mode_matches_plain_and_isolates_sessions():
     """MULTI-PROCESS serving: queries execute in supervised worker
     processes (each its own TpuSession/budget) and match the in-process

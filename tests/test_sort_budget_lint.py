@@ -1,7 +1,6 @@
 """Suite-wide program lints over every TPC-H and TPC-DS plan (tier-1).
 
-The two platform cliffs are visible in the emitted jaxpr (docs/PERF.md
-§1): variadic sorts whose XLA compile time scales brutally with operand
+The two platform cliffs are visible in the emitted jaxpr: variadic sorts whose XLA compile time scales brutally with operand
 count, and scatters whose outputs land in slow S(1) buffers.  These
 tests pin both numbers for all 22 queries, so any kernel change that
 re-introduces a wide lexsort or a segment scatter fails tier-1 instead
@@ -134,7 +133,7 @@ def test_pallas_off_programs_identical_to_default(tables, suite_stats):
 # gather budget: late materialization must keep paying for itself
 # ---------------------------------------------------------------------------
 
-# The BENCH_r05 tail (q3/q9-class join pipelines at 0.2-0.4x) is gather
+# The round-5 tail (q3/q9-class join pipelines at 0.2-0.4x) is gather
 # volume: chained joins re-gathering payload columns per join.  Late
 # materialization (columnar/lanes.py) defers payloads behind row-id
 # lanes and resolves them once at the pipeline sink; these are the
