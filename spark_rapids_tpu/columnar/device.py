@@ -560,7 +560,8 @@ def _build_host_batch(db: DeviceBatch, n: int, fetched) -> HostBatch:
 
 
 def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
-                       conf: Optional[TpuConf] = None) -> HostBatch:
+                       conf: Optional[TpuConf] = None,
+                       metrics: Optional[dict] = None) -> HostBatch:
     """Bring a RESULT batch to host in as few syncs and bytes as it needs.
 
     The live rows of every operator output are a front prefix of the
@@ -571,13 +572,22 @@ def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
       * static bound (limit/top-N) -> one trip, bound rows
       * unknown count              -> ONE speculative trip fetching the
         count + a RESULT_HEAD_ROWS prefix together; a second trip only
-        when the result is genuinely bigger than the head."""
+        when the result is genuinely bigger than the head.
+
+    Each trip is a blocking device-to-host read: `metrics["host_syncs"]`
+    counts them."""
     from ..config import (DEFAULT_CONF, RESULT_BOUND_FETCH_FACTOR,
                           RESULT_HEAD_ROWS)
     conf = conf or DEFAULT_CONF
     head_rows = conf.get(RESULT_HEAD_ROWS)
     bound_factor = conf.get(RESULT_BOUND_FETCH_FACTOR)
     cap = db.capacity
+
+    def trip():
+        if metrics is not None:
+            metrics["host_syncs"] = metrics.get("host_syncs", 0) + 1
+
+    trip()
     if isinstance(db.num_rows, int):
         return to_host(db, fetch_rows=min(db.num_rows, cap))
     if any(c.offsets is not None for c in db.columns):
@@ -588,6 +598,7 @@ def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
         if bound is not None and bound < cap:
             return to_host(db, fetch_rows=bound)
         n = int(jax.device_get(db.num_rows))
+        trip()
         return to_host(db, fetch_rows=max(n, 0) if n < cap else None)
     # a small static bound buys an exact one-trip fetch; a loose bound
     # (dense-domain group counts can reach 4M) must not defeat the head
@@ -602,6 +613,7 @@ def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
     if n <= head:
         return _build_host_batch(db, n, fetched)
     # result larger than the head: pay the second, exactly-sized trip
+    trip()
     return to_host(db, fetch_rows=n)
 
 

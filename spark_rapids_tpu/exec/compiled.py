@@ -46,7 +46,8 @@ from .. import types as t
 from ..columnar.device import (DeviceBatch, DeviceColumn, bucket_capacity,
                                to_device)
 from ..config import TpuConf
-from .plan import ExecContext, HostScanExec, PlanNode
+from ..obs.tracer import CollectSpan
+from .plan import ExecContext, HostScanExec, PlanNode, fetch_to_host
 
 
 _DISPATCH_FLOOR: Dict[str, float] = {}
@@ -662,6 +663,7 @@ class CompiledPlan:
                     db, i = _rebuild_batch(flat, spec, i)
                     batches.append(db)
                 node._trace_batches = batches
+            scoped = _scope_nodes(self.root)
             trace_ctx = _trace_context(ctx)
             try:
                 outs = list(self.root.execute(trace_ctx))
@@ -670,6 +672,11 @@ class CompiledPlan:
                     set_literal_bindings(None)
                 for node, _ in in_specs:
                     node._trace_batches = None
+                for node, execute in scoped:
+                    if execute is None:
+                        del node.execute
+                    else:
+                        node.execute = execute
                 # copy ONLY host numbers back: a traced metric value
                 # escaping the jit would be a leaked tracer
                 host_metrics = {k: v for k, v in trace_ctx.metrics.items()
@@ -678,17 +685,23 @@ class CompiledPlan:
                 ctx.metrics.update(host_metrics)
             flat_out = []
             specs = []
-            for db in outs:
-                if db.thin is not None:
-                    # the program boundary is a pipeline SINK: resolve
-                    # deferred columns INSIDE the traced program (the
-                    # composed gathers fuse into the whole-plan XLA
-                    # program; the flat output layer carries no lanes)
-                    from ..columnar.lanes import materialize_batch
-                    db = materialize_batch(db, ctx.conf)
-                arrays, spec = _flatten_batch(db)
-                flat_out.extend(arrays)
-                specs.append(spec)
+            # ops traced here, at the program's end, read
+            # `<root's node id>/sink/...` in a profiler trace
+            with jax.named_scope(getattr(self.root, "_node_id", None)
+                                 or self.root.name()), \
+                    jax.named_scope("sink"):
+                for db in outs:
+                    if db.thin is not None:
+                        # the program boundary is a pipeline SINK:
+                        # resolve deferred columns INSIDE the traced
+                        # program (the composed gathers fuse into the
+                        # whole-plan XLA program; the flat output layer
+                        # carries no lanes)
+                        from ..columnar.lanes import materialize_batch
+                        db = materialize_batch(db, ctx.conf)
+                    arrays, spec = _flatten_batch(db)
+                    flat_out.extend(arrays)
+                    specs.append(spec)
             out_holder["specs"] = specs
             out_holder["layout"] = [(tuple(x.shape), str(x.dtype))
                                     for x in flat_out]
@@ -826,14 +839,19 @@ class CompiledPlan:
         attributed to this program's plan-node-id range (the
         attribution plane — tracer `segment` span, tpu_segment_*
         registry families, segment.* query metrics)."""
+        with CollectSpan(ctx, "launch", "overhead.launch_ms"):
+            return self._launch(ctx)
+
+    def _launch(self, ctx: ExecContext) -> List[DeviceBatch]:
         import time as _time
         from ..config import PROFILE_SEGMENTS
         pairs = self._leaf_batches(ctx)
         flat_in, in_specs = self._flatten_inputs(pairs)
 
         if self._compiled is None:
-            if not self._try_plan_cache(ctx, pairs, flat_in, in_specs):
-                self.aot_compile(ctx, flat_in, in_specs, pairs)
+            with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
+                if not self._try_plan_cache(ctx, pairs, flat_in, in_specs):
+                    self.aot_compile(ctx, flat_in, in_specs, pairs)
         elif not self._fresh:
             ctx.bump("compile_cache_hits")
         self._fresh = False
@@ -1007,10 +1025,7 @@ class CompiledPlan:
                             **attrs)
 
     def collect(self, ctx: ExecContext) -> pa.Table:
-        import time as _time
-        from ..columnar.device import fetch_result_batch
         from ..columnar.host import struct_to_schema
-        from ..runtime.retry import retry_io
         # cancellation checkpoint before the program dispatches: a
         # deadline that expired in the queue cancels without paying for
         # the whole dispatch (single-program plans have no seams)
@@ -1020,22 +1035,50 @@ class CompiledPlan:
         hbs = []
         for db in outs:
             ctx.checkpoint("fetch")
-            t0 = _time.perf_counter()
-            with ctx.tracer.span("fetch", "transition"):
-                hb = retry_io(ctx.conf, "d2h",
-                              lambda: fetch_result_batch(db, bound,
-                                                         ctx.conf))
-            ctx.metrics["overhead.fetch_ms"] = ctx.metrics.get(
-                "overhead.fetch_ms", 0.0) \
-                + (_time.perf_counter() - t0) * 1e3
-            ctx.bump("d2h_rows", hb.num_rows)
-            ctx.tracer.add_bytes("d2h_bytes", hb.rb.nbytes)
-            hbs.append(hb)
+            hbs.append(fetch_to_host(db, bound, ctx))
         batches = [hb.rb for hb in hbs if hb.num_rows > 0]
         if not batches:
             return pa.Table.from_batches(
                 [], struct_to_schema(self.root.output_schema))
         return pa.Table.from_batches(batches, batches[0].schema)
+
+
+def _scope_nodes(root: PlanNode) -> list:
+    """For the duration of a whole-plan trace, step every node's
+    `execute` generator inside `jax.named_scope(<node id>)`: the ops a
+    node traces carry `.../HashJoinExec#4/...` in their `op_name`
+    metadata, so a profiler trace's device ops map back to plan nodes
+    (scripts/trace_by_operator.py).  Names are metadata of the compiled
+    program: no cost at run time.  Returns [(node, the instance's own
+    `execute` or None)] for the caller to put back; call it under
+    _TRACE_LOCK, like `_trace_batches`."""
+    from .metrics import _child_nodes
+
+    def scope(name, inner):
+        def execute(ctx):
+            with jax.named_scope(name):
+                it = iter(inner(ctx))
+            while True:
+                with jax.named_scope(name):
+                    try:
+                        out = next(it)
+                    except StopIteration:
+                        return
+                yield out
+        return execute
+
+    scoped, seen, stack = [], set(), [root]
+    while stack:
+        node = stack.pop()
+        if id(node) in seen:
+            continue
+        seen.add(id(node))
+        stack.extend(_child_nodes(node))
+        name = getattr(node, "_node_id", None)
+        if name is not None:
+            scoped.append((node, node.__dict__.get("execute")))
+            node.execute = scope(name, node.execute)
+    return scoped
 
 
 def _trace_context(ctx: ExecContext) -> ExecContext:
@@ -1382,10 +1425,15 @@ class SplitCompiledPlan:
                 return plan
 
             service.submit((id(self), nxt, key), thunk)
+            ctx.bump("compile_speculative_submitted")
 
     @staticmethod
-    def _shrink(outs: List[DeviceBatch], ctx) -> List[DeviceBatch]:
+    def _shrink(outs: List[DeviceBatch], ctx
+                ) -> Tuple[List[DeviceBatch], int]:
+        """The seam's re-bucket: (batches sliced down to the bucket of
+        their live rows, the live rows)."""
         sliced = []
+        rows = 0
         for db in outs:
             if db.sel is not None or db.thin is not None:
                 # lazy-join seam output: the seam re-buckets anyway, so
@@ -1394,18 +1442,23 @@ class SplitCompiledPlan:
                 db = ensure_prefix(db, ctx.conf)
             if any(c.offsets is not None for c in db.columns):
                 raise _SplitUnsupported()   # ragged seam output
-            n = db.num_rows if isinstance(db.num_rows, int) \
-                else int(db.num_rows)       # ONE host sync per batch
+            n = db.num_rows
+            if not isinstance(n, int):
+                # ONE host sync per batch: it waits for the segment
+                with CollectSpan(ctx, "seam_wait", "overhead.seam_wait_ms",
+                                 cat="transition", split=True):
+                    n = int(n)
+                ctx.bump("host_syncs")
+            rows += n
             cap = min(bucket_capacity(max(n, 1), ctx.conf), db.capacity)
             # num_rows stays a device scalar so segment traces are keyed
             # on the CAPACITY BUCKET only — a drifting row count
             # (growing table, streaming appends) reuses compiled
             # programs instead of recompiling per exact count
             sliced.append(_slice_batch(db, cap, jnp.int32(n)))
-        return sliced
+        return sliced, rows
 
     def collect(self, ctx: ExecContext) -> pa.Table:
-        import time as _time
         self._install_leaves()
         try:
             key: tuple = ()
@@ -1414,45 +1467,33 @@ class SplitCompiledPlan:
                 # deadline-armed query cancels between segments, never
                 # mid-dispatch (the reservation picture stays clean)
                 ctx.checkpoint("seam")
-                seg = self._segment(i, key, ctx)
                 # compile first, THEN speculate: the next segment's
                 # placeholder shapes need this segment's traced output
                 # specs (dtypes, dictionaries).  Its compiles overlap
                 # this segment's device execution + seam sync below.
-                seg.ensure_compiled(ctx)
-                self._speculate(i, seg, ctx)
+                with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
+                    seg = self._segment(i, key, ctx)
+                    seg.ensure_compiled(ctx)
+                with CollectSpan(ctx, "speculate", "overhead.speculate_ms"):
+                    self._speculate(i, seg, ctx)
                 outs = seg.execute(ctx)
-                # the seam bracket (always-on: two clock reads around
-                # host work the seam pays anyway): one host row-count
-                # sync + re-bucket per batch, the dominant fixed cost of
-                # split plans on small inputs — overhead.seam_* feeds
+                # the seam (always on): one host row-count sync +
+                # re-bucket per batch, the dominant fixed cost of split
+                # plans on small inputs — overhead.seam_* feeds
                 # wall_breakdown(), the history plane, and the seam gate
-                t0 = _time.perf_counter()
-                sliced = self._shrink(outs, ctx)
-                leaf.batches = sliced
-                key = tuple(db.capacity for db in sliced)
-                t1 = _time.perf_counter()
-                rows = 0
-                nbytes = 0
-                for db in sliced:
-                    try:
-                        rows += int(db.num_rows)  # concrete post-sync
-                        nbytes += int(db.nbytes())
-                    except Exception:             # noqa: BLE001
-                        pass
-                m = ctx.metrics
-                m["overhead.seam_ms"] = m.get(
-                    "overhead.seam_ms", 0.0) + (t1 - t0) * 1e3
-                m["overhead.seam_count"] = m.get(
-                    "overhead.seam_count", 0) + 1
-                m["overhead.seam_rows"] = m.get(
-                    "overhead.seam_rows", 0) + rows
-                m["overhead.seam_bytes"] = m.get(
-                    "overhead.seam_bytes", 0) + nbytes
-                ctx.tracer.add_span(
-                    "seam", "transition", t0, t1, seam=i, rows=rows,
-                    bytes=nbytes, seam_ms=round((t1 - t0) * 1e3, 4))
-            out = self._segment(len(self.seams), key, ctx).collect(ctx)
+                with CollectSpan(ctx, "seam", "overhead.seam_ms",
+                                 cat="transition"):
+                    sliced, rows = self._shrink(outs, ctx)
+                    leaf.batches = sliced
+                    key = tuple(db.capacity for db in sliced)
+                for k, v in (("overhead.seam_count", 1),
+                             ("overhead.seam_rows", rows),
+                             ("overhead.seam_bytes",
+                              sum(int(db.nbytes()) for db in sliced))):
+                    ctx.bump(k, v)
+            with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
+                last = self._segment(len(self.seams), key, ctx)
+            out = last.collect(ctx)
         finally:
             self._restore_leaves()
         ctx.bump("whole_plan_split_queries")
@@ -1509,7 +1550,8 @@ def collect_with_fallback(root: PlanNode, ctx: ExecContext,
     if plan is False:                    # previously failed to trace
         return None
     if plan is None:
-        plan = build_plan(root, ctx)
+        with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
+            plan = build_plan(root, ctx)
     try:
         try:
             out = plan.collect(ctx)

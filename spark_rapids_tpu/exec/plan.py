@@ -23,8 +23,10 @@ import pyarrow as pa
 
 from .. import types as t
 from ..config import TpuConf, DEFAULT_CONF
-from ..columnar.device import DeviceBatch, to_device, empty_device_batch
+from ..columnar.device import (DeviceBatch, empty_device_batch,
+                               fetch_result_batch, to_device)
 from ..columnar.host import HostBatch, schema_to_struct
+from ..obs.tracer import CollectSpan
 from ..ops.batch_ops import concat_batches, shrink_to_rows
 from ..ops.filter import compact_batch
 from ..plan import expressions as E
@@ -100,6 +102,13 @@ class ExecContext:
     # threading.Event — checkpoint() raises past either
     deadline: float = 0.0
     cancel: object = None
+    # the collect path's span seam (obs/tracer.CollectSpan): the query's
+    # process-wide sequence number (the `query` stat of every `tpu.*`
+    # annotation), the spans now open on the collecting thread, and
+    # those that closed before the query's scope bound a tracer
+    query_seq: int = 0
+    open_spans: list = dataclasses.field(default_factory=list)
+    early_spans: Optional[list] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         if self.tracer is None:
@@ -150,6 +159,23 @@ class ExecContext:
 
     def bump(self, name: str, n: int = 1):
         self.metrics[name] = self.metrics.get(name, 0) + n
+
+
+def fetch_to_host(db: DeviceBatch, bound: Optional[int],
+                  ctx: ExecContext) -> HostBatch:
+    """The result fetch of one batch, the tail host sync every query
+    pays (`tpu.fetch`, overhead.fetch_ms): the wait for the device, the
+    copy and the Arrow build.  The wait is not split off: an explicit
+    `block_until_ready` before the transfer costs a second round trip
+    (1.4 ms of a 8.9 ms q6 collect on a v5e: PERF.md, PR 25)."""
+    from ..runtime.retry import retry_io
+    with CollectSpan(ctx, "fetch", "overhead.fetch_ms", cat="transition"):
+        hb = retry_io(ctx.conf, "d2h",
+                      lambda: fetch_result_batch(db, bound, ctx.conf,
+                                                 ctx.metrics))
+    ctx.bump("d2h_rows", hb.num_rows)
+    ctx.tracer.add_bytes("d2h_bytes", hb.rb.nbytes)
+    return hb
 
 
 class PlanNode:
@@ -208,7 +234,11 @@ class PlanNode:
         return self.static_row_count()
 
     def tree_string(self, indent: int = 0) -> str:
-        lines = ["  " * indent + self.describe()]
+        # the node id, once assigned (exec/metrics.assign_node_ids), is
+        # the name its device ops carry in a profiler trace
+        nid = getattr(self, "_node_id", None)
+        lines = ["  " * indent + self.describe()
+                 + (f"  <{nid}>" if nid else "")]
         for c in self.children:
             lines.append(c.tree_string(indent + 1))
         return "\n".join(lines)
@@ -225,28 +255,13 @@ class PlanNode:
         exactly-sized trip, unknown counts via a speculative
         count+head-prefix trip (columnar.device.fetch_result_batch)."""
         ctx = ctx or ExecContext()
-        import time as _time
-        from ..columnar.device import fetch_result_batch
-        from ..runtime.retry import retry_io
         bound = self.row_upper_bound()
         hbs = []
         for db in self.execute(ctx):
             ctx.checkpoint("batch")
             if isinstance(db.num_rows, int) and db.num_rows == 0:
                 continue
-            t0 = _time.perf_counter()
-            with ctx.tracer.span("fetch", "transition"):
-                hb = retry_io(ctx.conf, "d2h",
-                              lambda: fetch_result_batch(db, bound,
-                                                         ctx.conf))
-            # always-on result-fetch bracket: the tail host sync every
-            # query pays (overhead plane, obs/profile.wall_breakdown)
-            ctx.metrics["overhead.fetch_ms"] = ctx.metrics.get(
-                "overhead.fetch_ms", 0.0) \
-                + (_time.perf_counter() - t0) * 1e3
-            ctx.bump("d2h_rows", hb.num_rows)
-            ctx.tracer.add_bytes("d2h_bytes", hb.rb.nbytes)
-            hbs.append(hb)
+            hbs.append(fetch_to_host(db, bound, ctx))
         schema = None
         batches = []
         for hb in hbs:

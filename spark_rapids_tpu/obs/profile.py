@@ -250,68 +250,117 @@ class QueryProfile:
           dispatch_ms        measured per-backend dispatch floor x
                              program launches
           seam_ms            host sync + re-bucket at every
-                             SplitCompiledPlan boundary
+                             SplitCompiledPlan boundary (tpu.seam)
           compile_ms         trace+compile span union (in-wall)
           fetch_ms           d2h/h2d transition span union (seams
                              excluded — they have their own line)
           shuffle_ms         shuffle span union
           host_prep_ms       in-wall setup before execution
+                             (tpu.scope_enter)
+          prepare_ms         program lookup and cache load, net of
+                             compile and upload (tpu.prepare)
+          speculate_ms       next-segment speculation (tpu.speculate)
+          launch_ms          flatten + enqueue + rebuild, net of the
+                             program's own wall (tpu.launch)
+          finish_ms          history feed, lazy-metric fetch, registry
+                             and scope exit (tpu.finish)
           unattributed_ms    the residual
 
         `pad_waste_ms`/`pad_rows` ride along as informational fields: the
         bucket-quantization tax is a SLICE of device_compute_ms, not an
-        additive category.  `plan_ms` and `semaphore_wait_ms` happen
-        before the query span opens and are reported as pre-wall lines.
-        Works from a live context or an event log; dispatch/pad fields
-        populate only on profiled (profile.segments) runs."""
+        additive category.  Works from a live context or an event log;
+        dispatch/pad fields populate only on profiled (profile.segments)
+        runs.
+
+        The wall is the query span where the run was traced: the
+        `tpu.*` spans of the collect path (obs/tracer.CollectSpan) are
+        clipped to it, and `plan_ms` and `semaphore_wait_ms`, which
+        happen before it opens, are pre-wall lines.  Without spans
+        (tracing off) the wall is `overhead.collect_ms`, the whole of
+        DataFrame.collect(): the categories are the span seam's own
+        always-on keys, planning is inside it, and the residual is
+        `overhead.unattributed_ms`, the `tpu.collect` span's own time."""
         roots = [s for s in self.spans if s.cat == "query"]
         q0 = min((s.t0 for s in roots), default=None)
         q1 = max((s.t1 for s in roots), default=None)
-        wall = self.wall_ms()
         ov = self.overheads()
+        m = self.metrics
 
-        def cat_union(cat: str, exclude_name: Optional[str] = None
-                      ) -> float:
+        def clipped(pick) -> List[tuple]:
             ivals = []
             for s in self.spans:
-                if s.cat != cat or \
-                        (exclude_name and s.name == exclude_name):
+                if not pick(s):
                     continue
                 t0, t1 = s.t0, s.t1
                 if q0 is not None:
                     t0, t1 = max(t0, q0), min(t1, q1)
                 if t1 > t0:
                     ivals.append((t0, t1))
-            return _union_ms(ivals)
+            return ivals
 
+        seam_names = ("tpu.seam", "tpu.seam_wait")
         seg_dev = sum(float(r.get("device_ms", 0.0))
                       for r in self.segments())
         dispatch_ms = float(ov.get("dispatch_ms", 0.0))
         if seg_dev <= 0.0:
             # unprofiled run: exec_device_ms is the dispatch wall; the
             # measured floor x launch count bounds its overhead share
-            seg_dev = float(self.metrics.get("exec_device_ms", 0.0))
+            seg_dev = float(m.get("exec_device_ms", 0.0))
             floor = float(ov.get("dispatch_floor_ms", 0.0))
             if not dispatch_ms and floor:
-                dispatch_ms = floor * float(
-                    self.metrics.get("exec_dispatches", 0))
+                dispatch_ms = floor * float(m.get("exec_dispatches", 0))
         dispatch_ms = min(dispatch_ms, seg_dev)
-        pad_ms = min(float(ov.get("pad_waste_ms", 0.0)),
-                     max(seg_dev - dispatch_ms, 0.0))
-        seam_ms = float(ov.get("seam_ms", 0.0))
         cats = {
             "device_compute_ms": max(seg_dev - dispatch_ms, 0.0),
             "dispatch_ms": dispatch_ms,
-            "seam_ms": seam_ms,
-            "compile_ms": cat_union("compile"),
-            "fetch_ms": cat_union("transition", exclude_name="seam"),
-            "shuffle_ms": cat_union("shuffle"),
-            "host_prep_ms": float(ov.get("host_prep_ms", 0.0)),
+            "seam_ms": float(ov.get("seam_ms", 0.0)),
         }
+        if roots or "collect_ms" not in ov:
+            wall = self.wall_ms()
+            by_cat = {
+                "compile_ms": clipped(lambda s: s.cat == "compile"),
+                "fetch_ms": clipped(lambda s: s.cat == "transition"
+                                    and s.name not in seam_names),
+                "shuffle_ms": clipped(lambda s: s.cat == "shuffle")}
+            cats.update({k: _union_ms(v) for k, v in by_cat.items()})
+            # what is counted so far, as intervals; each layer boundary
+            # then adds the part of its spans that these leave free
+            # (launch last: a single program looks its executable up
+            # inside its launch)
+            taken = [iv for v in by_cat.values() for iv in v] + clipped(
+                lambda s: s.cat == "execute" or s.name in seam_names)
+            for key, name in (("host_prep_ms", "tpu.scope_enter"),
+                              ("prepare_ms", "tpu.prepare"),
+                              ("speculate_ms", "tpu.speculate"),
+                              ("finish_ms", "tpu.finish"),
+                              ("launch_ms", "tpu.launch")):
+                mine = clipped(lambda s: s.name == name)
+                cats[key] = _union_ms(mine + taken) - _union_ms(taken)
+                taken += mine
+            residual = max(wall - sum(cats.values()), 0.0)
+            plan_ms = sum(s.dur_ms for s in self.spans if s.cat == "plan")
+        else:
+            wall = float(ov["collect_ms"])
+            compile_ms = min(float(m.get("compile_ms", 0.0)),
+                             float(ov.get("prepare_ms", 0.0)))
+            cats.update({
+                "compile_ms": compile_ms,
+                "fetch_ms": float(ov.get("fetch_ms", 0.0)),
+                "shuffle_ms": 0.0,
+                "host_prep_ms": float(ov.get("host_prep_ms", 0.0)),
+                "prepare_ms": float(ov.get("prepare_ms", 0.0))
+                - compile_ms,
+                "speculate_ms": float(ov.get("speculate_ms", 0.0)),
+                "finish_ms": float(ov.get("finish_ms", 0.0)),
+                "launch_ms": max(float(ov.get("launch_ms", 0.0))
+                                 - seg_dev, 0.0)})
+            residual = float(ov.get("unattributed_ms", 0.0))
+            plan_ms = float(ov.get("plan_ms", 0.0))
+            cats["plan_ms"] = plan_ms
         named = sum(cats.values())
         out: Dict[str, Any] = {"wall_ms": round(wall, 3)}
         out.update({k: round(v, 3) for k, v in cats.items()})
-        out["unattributed_ms"] = round(max(wall - named, 0.0), 3)
+        out["unattributed_ms"] = round(residual, 3)
         out["attributed_pct"] = round(100.0 * min(named / wall, 1.0), 1) \
             if wall > 0 else 0.0
         out["pad_waste_ms"] = round(float(ov.get("pad_waste_ms", 0.0)), 3)
@@ -321,14 +370,13 @@ class QueryProfile:
         if ov.get("dispatch_floor_ms"):
             out["dispatch_floor_ms"] = round(
                 float(ov["dispatch_floor_ms"]), 4)
-        n_disp = self.metrics.get("exec_dispatches")
+        n_disp = m.get("exec_dispatches")
         if n_disp:
             out["dispatches"] = int(n_disp)
-        # pre-wall lines: planning and the device-permit queue wait both
-        # happen before the query span opens
-        out["plan_ms"] = round(sum(s.dur_ms for s in self.spans
-                                   if s.cat == "plan"), 3)
-        sem = self.metrics.get("semaphore_wait_ms")
+        # planning and the device-permit queue wait: pre-wall lines of a
+        # traced run (both happen before the query span opens)
+        out["plan_ms"] = round(plan_ms, 3)
+        sem = m.get("semaphore_wait_ms")
         if sem:
             out["semaphore_wait_ms"] = round(float(sem), 3)
         return out
@@ -688,6 +736,10 @@ _BREAKDOWN_LABELS = (
     ("fetch_ms", "fetch/upload"),
     ("shuffle_ms", "shuffle"),
     ("host_prep_ms", "host prep"),
+    ("prepare_ms", "program lookup"),
+    ("speculate_ms", "speculation"),
+    ("launch_ms", "launch (host)"),
+    ("finish_ms", "finish"),
     ("unattributed_ms", "unattributed"),
 )
 

@@ -19,6 +19,12 @@ Serialization is two-way:
 Tracing is OFF by default (`NULL_TRACER` no-ops keep the disabled path
 near-free); enable with `spark.rapids.tpu.trace.enabled` (in-memory, for
 `TpuSession.last_query_profile()`) or by setting the event-log dir.
+
+The layer boundaries of `DataFrame.collect()` are the exception: each is
+one `CollectSpan`, which is always on.  It writes a `tpu.<name>`
+`jax.profiler.TraceAnnotation` (so a profiler trace holds the host's
+spans on the device's clock), adds its time to one `ctx.metrics` key,
+and records the same span here when a `QueryTracer` is enabled.
 """
 from __future__ import annotations
 
@@ -30,9 +36,11 @@ import time
 from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..config import EVENT_LOG_DIR, TRACE_ENABLED, TpuConf
 from .recorder import FLIGHT_RECORDER
-from .registry import DATA_BYTES, RUNTIME_EVENTS
+from .registry import DATA_BYTES, RUNTIME_EVENTS, next_query_seq
 
 #: tracer byte-counter key -> always-on registry data-movement channel
 _BYTE_CHANNELS = {
@@ -153,32 +161,45 @@ class QueryTracer:
              **({"node": node} if node else {})}, query=self.query_id)
         return sp
 
+    def begin(self, name: str, cat: str, node: Optional[str] = None,
+              t0: Optional[float] = None, parent: Optional[int] = None,
+              **attrs) -> Span:
+        """Open a range on the calling thread; spans opened before
+        `end()` parent to it.  `t0`/`parent` place a range that started
+        before this tracer existed (CollectSpan)."""
+        with self._lock:
+            sid = self._next_sid
+            self._next_sid += 1
+        if parent is None:
+            parent = self._parent()
+        if cat == "query" and self._root_sid is None:
+            self._root_sid = sid
+        self._stack().append(sid)
+        if t0 is None:
+            t0 = time.perf_counter()
+        return Span(sid, parent, name, cat, t0, t0, node,
+                    {k: _jsonable(v) for k, v in attrs.items()})
+
+    def end(self, sp: Span, t1: Optional[float] = None) -> None:
+        self._stack().pop()
+        sp.t1 = time.perf_counter() if t1 is None else t1
+        with self._lock:
+            self.spans.append(sp)
+        FLIGHT_RECORDER.record(
+            "span", sp.name, sp.cat,
+            {"dur_ms": round(sp.dur_ms, 3),
+             **({"node": sp.node} if sp.node else {})},
+            query=self.query_id)
+
     @contextmanager
     def span(self, name: str, cat: str, node: Optional[str] = None,
              **attrs):
         """Time a range; nested spans parent to it (per-thread)."""
-        t0 = time.perf_counter()
-        with self._lock:
-            sid = self._next_sid
-            self._next_sid += 1
-        parent = self._parent()
-        if cat == "query" and self._root_sid is None:
-            self._root_sid = sid
-        self._stack().append(sid)
+        sp = self.begin(name, cat, node, **attrs)
         try:
             yield
         finally:
-            self._stack().pop()
-            t1 = time.perf_counter()
-            with self._lock:
-                self.spans.append(Span(
-                    sid, parent, name, cat, t0, t1, node,
-                    {k: _jsonable(v) for k, v in attrs.items()}))
-            FLIGHT_RECORDER.record(
-                "span", name, cat,
-                {"dur_ms": round((t1 - t0) * 1e3, 3),
-                 **({"node": node} if node else {})},
-                query=self.query_id)
+            self.end(sp)
 
     def instant(self, name: str, cat: str, **attrs) -> None:
         with self._lock:
@@ -455,3 +476,104 @@ def make_tracer(conf: TpuConf):
         _NEXT_QUERY_ID += 1
         qid = _NEXT_QUERY_ID
     return QueryTracer(qid)
+
+
+class CollectSpan:
+    """One layer boundary of the collect path, always on:
+
+        with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
+            ...
+
+    Entering opens `TraceAnnotation("tpu.<name>", query=<query seq>)`,
+    which lands on the `/host:CPU` plane of a live profiler trace, on
+    the device's clock.  Leaving adds the span's own time to
+    `ctx.metrics[key]`: its duration less that of the spans opened
+    inside it, so the keys of one collect add up to the outermost
+    span's duration and the counters cannot disagree with the spans.
+    A `split` span is a part of its parent, not a category beside it
+    (the wait inside a fetch): its whole duration goes to its key and
+    the parent's time keeps it.  Where the context's tracer is enabled
+    the span is recorded there too, parented to the enclosing
+    CollectSpan.  Spans are serial on the collecting thread."""
+
+    __slots__ = ("ctx", "name", "key", "cat", "split", "whole_key",
+                 "after", "_ann", "_outer", "_span", "_t0", "_t1",
+                 "_inner_ms")
+
+    def __init__(self, ctx, name: str, key: str, cat: str = "collect",
+                 split: bool = False, whole_key: Optional[str] = None):
+        self.ctx = ctx
+        self.name = "tpu." + name
+        self.key = key
+        self.cat = cat
+        self.split = split
+        #: a second key, given the whole duration (`tpu.collect`, whose
+        #: own time is the residual)
+        self.whole_key = whole_key
+        #: run once the span has closed and its time is in the metrics
+        self.after = None
+        self._span = None
+
+    def __enter__(self) -> "CollectSpan":
+        ctx = self.ctx
+        if not ctx.query_seq:
+            ctx.query_seq = next_query_seq()
+        self._ann = TraceAnnotation(self.name, query=ctx.query_seq)
+        self._ann.__enter__()
+        open_spans = ctx.open_spans
+        self._outer = open_spans[-1] if open_spans else None
+        open_spans.append(self)
+        self._inner_ms = 0.0
+        self._t0 = time.perf_counter()
+        if ctx.tracer.enabled:
+            self._record_begin(ctx.tracer)
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        ctx = self.ctx
+        self._t1 = time.perf_counter()
+        ms = (self._t1 - self._t0) * 1e3
+        ctx.open_spans.pop()
+        m = ctx.metrics
+        m[self.key] = m.get(self.key, 0.0) + \
+            (ms if self.split else ms - self._inner_ms)
+        if self.whole_key is not None:
+            m[self.whole_key] = m.get(self.whole_key, 0.0) + ms
+        if self._outer is not None and not self.split:
+            self._outer._inner_ms += ms
+        if self._span is not None:
+            ctx.tracer.end(self._span, self._t1)
+        elif ctx.early_spans is not None:
+            ctx.early_spans.append(self)
+        self._ann.__exit__(et, ev, tb)
+        if self.after is not None:
+            self.after()
+        return False
+
+    def _outer_sid(self) -> Optional[int]:
+        outer = self._outer
+        return None if outer is None or outer._span is None \
+            else outer._span.sid
+
+    def _record_begin(self, tracer) -> None:
+        self._span = tracer.begin(self.name, self.cat, t0=self._t0,
+                                  parent=self._outer_sid())
+
+
+def bind_tracer(ctx, tracer) -> Dict[str, int]:
+    """Make `tracer` the context's tracer for this query's scope.  The
+    spans of the collect path that opened before it existed (the
+    enclosing `tpu.collect`, `tpu.plan`, the first piece of
+    `tpu.scope_enter`) are handed to it with their own clock readings;
+    returns {name: span id} of those that had already closed."""
+    ctx.tracer = tracer
+    early, ctx.early_spans = ctx.early_spans, None
+    closed: Dict[str, int] = {}
+    if not tracer.enabled:
+        return closed
+    for sp in ctx.open_spans:
+        sp._record_begin(tracer)
+    for sp in early or ():
+        closed[sp.name] = tracer.add_span(
+            sp.name, sp.cat, sp._t0, sp._t1, parent=sp._outer_sid()).sid
+    return closed

@@ -1198,6 +1198,12 @@ class PhysicalQuery:
         # (wrap/tag/convert), stamped by apply_overrides; the tracer
         # replays them as cat=plan spans at collect time
         self.plan_phases: List[tuple] = []
+        if kind == "device":
+            # one id per node (`HashJoinExec#4`) for EXPLAIN, the
+            # per-node metrics, every whole-plan segment and, through
+            # `jax.named_scope`, the device ops of a profiler trace
+            from ..exec.metrics import assign_node_ids
+            assign_node_ids(root)
 
     def explain(self) -> str:
         return "\n".join(self.meta.explain_lines())
@@ -1249,22 +1255,21 @@ class PhysicalQuery:
         from ..config import EVENT_LOG_DIR
         from ..exec.metrics import (instrument, profile_trace,
                                     publish_registry, should_instrument)
+        from ..obs import memattr
         from ..obs.export import configure_plane
         from ..obs.recorder import FLIGHT_RECORDER
         from ..obs.registry import (ACTIVE_QUERIES, QUERIES_TOTAL,
-                                    QUERY_WALL_MS, next_query_seq)
-        from ..obs.tracer import NULL_TRACER, make_tracer, set_active
+                                    QUERY_WALL_MS)
+        from ..obs.tracer import (NULL_TRACER, CollectSpan, bind_tracer,
+                                  make_tracer, set_active)
         from ..runtime import faults
         from ..runtime.semaphore import device_permit
 
-        @contextmanager
-        def scope():
+        def enter():
             # always-on plane: apply this query's conf (enabled flag,
             # recorder capacity, exporter start) before anything records
             configure_plane(ctx.conf)
-            qseq = next_query_seq()
-            t_start = _time.perf_counter()
-            status = "ok"
+            qseq = ctx.query_seq
             ACTIVE_QUERIES.add(1)
             FLIGHT_RECORDER.record("instant", "query_start", "query",
                                    {"plan_kind": self.kind}, query=qseq)
@@ -1281,7 +1286,7 @@ class PhysicalQuery:
                 w = _os.environ.get("SPARK_RAPIDS_TPU_WORKER_ID")
                 if w:
                     tracer.meta["worker"] = w
-            ctx.tracer = tracer
+            early = bind_tracer(ctx, tracer)
             # chaos: conf-less sites (mesh exchange collectives) fire on
             # the active injector for this query's scope
             faults.set_active(faults.get_injector(ctx.conf))
@@ -1289,7 +1294,6 @@ class PhysicalQuery:
             # under profile.segments + profile.memory; set active so
             # the lazily-created MemoryBudget binds its watermark
             # events to THIS query's HBM timeline
-            from ..obs import memattr
             ctx._memattr = memattr.make_recorder(ctx.conf)
             memattr.set_active(ctx._memattr)
             if tracer.enabled:
@@ -1297,7 +1301,8 @@ class PhysicalQuery:
                 tracer.meta["fallbacks"] = self.fallback_reasons()
                 tracer.meta["plan_kind"] = self.kind
                 for name, t0, t1 in self.plan_phases:
-                    tracer.add_span(name, "plan", t0, t1)
+                    tracer.add_span(name, "plan", t0, t1,
+                                    parent=early.get("tpu.plan"))
                 if self.kind == "device":
                     try:
                         kp = self.kernel_plan()
@@ -1315,56 +1320,79 @@ class PhysicalQuery:
                     tracer.meta["prediction"] = pred
                 tracer.instant("admission_prediction", "serving", **pred)
             set_active(tracer)
+            return tracer, qseq
+
+        @contextmanager
+        def scope():
+            t_start = _time.perf_counter()
+            status = "ok"
+            with CollectSpan(ctx, "scope_enter", "overhead.host_prep_ms"):
+                tracer, qseq = enter()
+
+            def write_log():
+                tracer.finish(ctx.metrics)
+                log_dir = str(ctx.conf.get(EVENT_LOG_DIR) or "")
+                if log_dir:
+                    ctx.metrics["event_log_files"] = tracer.write(log_dir)
+
             try:
                 if should_instrument(self.conf):
-                    instrument(self.root, ctx)
+                    with CollectSpan(ctx, "scope_enter",
+                                     "overhead.host_prep_ms"):
+                        instrument(self.root, ctx)
                 with profile_trace(self.conf), \
                         device_permit(self.conf, ctx.metrics):
                     with tracer.span("query", "query"):
                         yield
-                # metrics accumulated as device scalars (lazy counts)
-                # coerce in ONE batched fetch at query end
-                import jax
-                lazy = {k: v for k, v in ctx.metrics.items()
-                        if isinstance(v, jax.Array)}
-                if lazy:
-                    for k, v in zip(lazy,
-                                    jax.device_get(list(lazy.values()))):
-                        ctx.metrics[k] = v.item()
-                if ctx._budget is not None:
-                    for k, v in ctx.budget.metrics.items():
-                        ctx.metrics[f"memory.{k}"] = v
-                # measured working set + HBM timeline + the residual
-                # naked-reservation leak check (exec/metrics.py)
-                from ..exec.metrics import finish_memattr
-                finish_memattr(ctx)
-                publish_registry(ctx)
+                with CollectSpan(ctx, "finish", "overhead.finish_ms"):
+                    # metrics accumulated as device scalars (lazy
+                    # counts) coerce in ONE batched fetch at query end
+                    import jax
+                    lazy = {k: v for k, v in ctx.metrics.items()
+                            if isinstance(v, jax.Array)}
+                    if lazy:
+                        ctx.bump("host_syncs")
+                        for k, v in zip(lazy, jax.device_get(
+                                list(lazy.values()))):
+                            ctx.metrics[k] = v.item()
+                    if ctx._budget is not None:
+                        for k, v in ctx.budget.metrics.items():
+                            ctx.metrics[f"memory.{k}"] = v
+                    # measured working set + HBM timeline + the residual
+                    # naked-reservation leak check (exec/metrics.py)
+                    from ..exec.metrics import finish_memattr
+                    finish_memattr(ctx)
+                    publish_registry(ctx)
             except BaseException:
                 status = "error"
                 raise
             finally:
-                set_active(NULL_TRACER)
-                faults.set_active(faults.NULL_INJECTOR)
-                memattr.set_active(None)
-                if tracer.enabled:
-                    tracer.finish(ctx.metrics)
-                    log_dir = str(ctx.conf.get(EVENT_LOG_DIR) or "")
-                    if log_dir:
-                        ctx.metrics["event_log_files"] = \
-                            tracer.write(log_dir)
-                wall_ms = (_time.perf_counter() - t_start) * 1e3
-                ACTIVE_QUERIES.add(-1)
-                QUERY_WALL_MS.observe(wall_ms)
-                QUERIES_TOTAL.inc(status=status, kind=self.kind)
-                # NOTE: the crash-dump writer (runtime/failure.py) runs
-                # before this finally (crash_capture is the inner cm),
-                # so a fatal fault's dump never contains this marker —
-                # under default conf its last flight event stays the
-                # fault instant itself
-                FLIGHT_RECORDER.record(
-                    "instant", "query_end", "query",
-                    {"status": status, "wall_ms": round(wall_ms, 3)},
-                    query=qseq)
+                with CollectSpan(ctx, "finish", "overhead.finish_ms"):
+                    set_active(NULL_TRACER)
+                    faults.set_active(faults.NULL_INJECTOR)
+                    memattr.set_active(None)
+                    wall_ms = (_time.perf_counter() - t_start) * 1e3
+                    ACTIVE_QUERIES.add(-1)
+                    QUERY_WALL_MS.observe(wall_ms)
+                    QUERIES_TOTAL.inc(status=status, kind=self.kind)
+                    # NOTE: the crash-dump writer (runtime/failure.py)
+                    # runs before this finally (crash_capture is the
+                    # inner cm), so a fatal fault's dump never contains
+                    # this marker — under default conf its last flight
+                    # event stays the fault instant itself
+                    FLIGHT_RECORDER.record(
+                        "instant", "query_end", "query",
+                        {"status": status, "wall_ms": round(wall_ms, 3)},
+                        query=qseq)
+                if not ctx.open_spans:
+                    ctx.query_seq = 0    # a context used again: a new query
+                    if tracer.enabled:
+                        write_log()
+                elif tracer.enabled:
+                    # DataFrame.collect()'s `tpu.collect` is still open:
+                    # the event log is written when it has closed, so
+                    # that it holds every span of the collect
+                    ctx.open_spans[0].after = write_log
         return scope()
 
     def _whole_plan_enabled(self) -> bool:
@@ -1381,34 +1409,38 @@ class PhysicalQuery:
 
     def collect(self, ctx: Optional[ExecContext] = None) -> pa.Table:
         ctx = ctx or ExecContext(self.conf)
-        from ..plan.misc import set_current_input_file
-        set_current_input_file("")   # provenance never leaks across queries
-        from ..config import SESSION_TIMEZONE
-        from ..plan.datetime import set_session_timezone
-        set_session_timezone(str(self.conf.get(SESSION_TIMEZONE)))
+        from ..exec import ooc as O
+        from ..exec.metrics import record_history
+        from ..obs.tracer import CollectSpan
         from ..runtime.failure import crash_capture, install_fault_injection
-        install_fault_injection(self.root, self.conf)
+        # tpu.scope_enter, in its pieces: the in-wall setup before
+        # execution starts (fault wiring, the scope's own entry, the
+        # per-node metric wrappers, OOC election) — a named category of the wall decomposition
+        # (obs/profile.wall_breakdown)
+        with CollectSpan(ctx, "scope_enter", "overhead.host_prep_ms"):
+            from ..plan.misc import set_current_input_file
+            set_current_input_file("")   # provenance never leaks across queries
+            from ..config import SESSION_TIMEZONE
+            from ..plan.datetime import set_session_timezone
+            set_session_timezone(str(self.conf.get(SESSION_TIMEZONE)))
+            install_fault_injection(self.root, self.conf)
         with self._instrumented(ctx), crash_capture(self.conf, ctx):
             import time as _time
-            t_prep = _time.perf_counter()
-            from ..exec import ooc as O
-            from ..exec.metrics import record_history
             if self.kind == "device":
                 # proactive OOC election: the cost oracle's MEASURED
                 # working-set history vs the HBM budget — an oversized
                 # query runs spilled from the start (exec/ooc.py)
-                O.elect_proactive(self, ctx)
+                with CollectSpan(ctx, "scope_enter",
+                                 "overhead.host_prep_ms"):
+                    O.elect_proactive(self, ctx)
             t0 = _time.perf_counter()
-            # host-prep bracket: in-wall setup before execution starts
-            # (OOC election, fault wiring) — a named category of the
-            # wall decomposition (obs/profile.wall_breakdown)
-            ctx.metrics["overhead.host_prep_ms"] = ctx.metrics.get(
-                "overhead.host_prep_ms", 0.0) + (t0 - t_prep) * 1e3
             out = self._collect_with_query_retry(ctx)
             # the performance-history feed: runs INSIDE crash_capture
             # (the `history` chaos site's fatal kind dumps classified;
             # ioerror skips the entry, the result below is untouched)
-            record_history(self, ctx, (_time.perf_counter() - t0) * 1e3)
+            with CollectSpan(ctx, "finish", "overhead.finish_ms"):
+                record_history(self, ctx,
+                               (_time.perf_counter() - t0) * 1e3)
             return out
 
     def prewarm(self, ctx: Optional[ExecContext] = None) -> bool:

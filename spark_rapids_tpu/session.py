@@ -23,6 +23,7 @@ import pyarrow as pa
 from . import types as t
 from .config import TpuConf
 from .exec.plan import ExecContext
+from .obs.tracer import CollectSpan
 from .plan import expressions as E
 from .plan import logical as L
 from .plan.aggregates import AggregateFunction
@@ -537,11 +538,16 @@ class DataFrame:
         return apply_overrides(self._plan, self._session.conf)
 
     def collect(self) -> pa.Table:
-        q = self.physical()
-        ctx = ExecContext(q.conf)
-        out = q.collect(ctx)
-        self._last_ctx = ctx
-        self._session._record_query(ctx)
+        ctx = ExecContext(self._session.conf)
+        with CollectSpan(ctx, "collect", "overhead.unattributed_ms",
+                         whole_key="overhead.collect_ms"):
+            with CollectSpan(ctx, "plan", "overhead.plan_ms"):
+                q = self.physical()
+                ctx.conf = q.conf       # planning may have adjusted it
+            out = q.collect(ctx)
+            with CollectSpan(ctx, "finish", "overhead.finish_ms"):
+                self._last_ctx = ctx
+                self._session._record_query(ctx)
         return out
 
     def metrics(self) -> Optional[dict]:

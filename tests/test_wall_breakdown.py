@@ -115,7 +115,8 @@ def test_wall_breakdown_categories_sum_and_wall_pct_method():
     bd = prof.wall_breakdown()
     named = (bd["device_compute_ms"] + bd["dispatch_ms"] + bd["seam_ms"]
              + bd["compile_ms"] + bd["fetch_ms"] + bd["shuffle_ms"]
-             + bd["host_prep_ms"])
+             + bd["host_prep_ms"] + bd["prepare_ms"] + bd["speculate_ms"]
+             + bd["launch_ms"] + bd["finish_ms"])
     assert bd["unattributed_ms"] >= 0.0
     # categories + residual reconstruct the wall (3-decimal rounding
     # slack; when measured categories slightly overlap the wall the
