@@ -9,7 +9,11 @@ Two tables, both on the profiler's one clock:
     traced with every operator inside `jax.named_scope(<node id>)`
     (exec/compiled.py), so an op's `op_name` reads
     `jit(run)/SortExec#0/HashAggregateExec#1/HashJoinExec#4/...`; the op
-    belongs to the innermost node.  The ids are EXPLAIN's.
+    belongs to the innermost node.  The ids are EXPLAIN's.  Ops traced at
+    a program boundary stand in a row of their own: `<node>/sink` (a
+    program's end: deferred lanes resolved for the fetch) and
+    `<node>/seam` (the program that resolves a split plan's seam batch
+    after its row-count sync).
   * host time by `tpu.*` span (obs/tracer.CollectSpan) inside each
     `collect:<query>` annotation, each span's own time (nested spans taken
     out), and how much of it the device sat idle.
@@ -42,7 +46,9 @@ from harness.trace_reduce import (ANNOTATION, DEVICE_PLANE, clip,  # noqa: E402
                                   fold, gaps, length, load, self_times,
                                   union)
 
-NODE = re.compile(r"([A-Za-z_]\w*#\d+)")
+# a node id, and the program boundary an op was traced at, if any: the
+# sink of a whole-plan program, or a split plan's seam (its own program)
+NODE = re.compile(r"([A-Za-z_]\w*#\d+(?:/(?:sink|seam)(?=/))?)")
 SPAN = "tpu."
 NO_NODE = "(no plan node)"
 EAGER = "(eager) "               # + the op's source file
@@ -132,7 +138,8 @@ def op_names(path: str) -> Tuple[Dict[str, set], Dict[str, set],
 
 
 def node_of(texts: set) -> str:
-    """The innermost plan node of an op's `op_name`(s)."""
+    """The innermost plan node of an op's `op_name`(s), with `/sink` or
+    `/seam` where the op was traced at that boundary of the node."""
     nodes = {(NODE.findall(t) or [NO_NODE])[-1] for t in texts} or {NO_NODE}
     return nodes.pop() if len(nodes) == 1 else "(several nodes)"
 

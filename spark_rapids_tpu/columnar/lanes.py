@@ -91,6 +91,20 @@ class ThinState:
             return None
         return ThinState(self.capacity, used, new_pending)
 
+    def pruned(self) -> "ThinState":
+        """The same state over sources cut to the columns `pending`
+        references: what has to cross a program boundary for the
+        deferred columns to be gathered on the other side."""
+        refs: List[List[int]] = [[] for _ in self.sources]
+        pending: Dict[int, Tuple[int, int]] = {}
+        for pos, (s, c) in sorted(self.pending.items()):
+            if c not in refs[s]:
+                refs[s].append(c)
+            pending[pos] = (s, refs[s].index(c))
+        sources = [LaneSource(src.batch.select(cols), src.lane)
+                   for src, cols in zip(self.sources, refs)]
+        return ThinState(self.capacity, sources, pending)
+
 
 def deferred_column(src_col: DeviceColumn) -> DeviceColumn:
     """Zero-capacity placeholder for a deferred column.  It carries the
@@ -103,6 +117,20 @@ def deferred_column(src_col: DeviceColumn) -> DeviceColumn:
         src_col.dtype,
         src_col.dictionary,
         None if src_col.data_hi is None else jnp.zeros((0,), jnp.int64))
+
+
+def resolved_columns(db: DeviceBatch) -> List[DeviceColumn]:
+    """Per output position, the column that says what the position
+    holds once resolved (logical dtype, dictionary, lane dtypes, hi
+    lane): the column itself, or for a deferred position its lane
+    source's column — the placeholder has no capacity to speak of."""
+    ts = db.thin
+    if ts is None:
+        return list(db.columns)
+    return [c if i not in ts.pending
+            else ts.sources[ts.pending[i][0]].batch
+            .columns[ts.pending[i][1]]
+            for i, c in enumerate(db.columns)]
 
 
 def _count_gather(site: str, rows: int, cols: List[DeviceColumn]) -> None:
@@ -280,11 +308,16 @@ def materialize_needed(db: DeviceBatch, exprs, conf: TpuConf = DEFAULT_CONF
 
 
 def compact_thin(db: DeviceBatch, keep: jax.Array,
-                 conf: TpuConf = DEFAULT_CONF) -> DeviceBatch:
+                 conf: TpuConf = DEFAULT_CONF,
+                 out_capacity: Optional[int] = None) -> DeviceBatch:
     """Compact a THIN batch: materialized columns move through the
     compaction order as usual; each deferred column is gathered ONCE,
     straight from its source into compacted position (the lane composes
-    with the order — no materialize-then-compact double pass)."""
+    with the order — no materialize-then-compact double pass).
+
+    `out_capacity` cuts the compaction order to its first rows, so every
+    gather below runs at that capacity: for a caller that knows the kept
+    rows fit (a split-plan seam, after its row-count sync)."""
     from ..ops.filter import (compaction_order, grouped_take,
                               pallas_compact_order)
     ts = db.thin
@@ -293,7 +326,10 @@ def compact_thin(db: DeviceBatch, keep: jax.Array,
     if order is None:
         order = compaction_order(keep)
     count = jnp.sum(keep, dtype=jnp.int32)
-    live_out = jnp.arange(db.capacity, dtype=jnp.int32) < count
+    if out_capacity is not None:
+        order = order[:out_capacity]
+        count = jnp.minimum(count, out_capacity)
+    live_out = jnp.arange(order.shape[0], dtype=jnp.int32) < count
     out_cols: List[Optional[DeviceColumn]] = [None] * len(db.columns)
     # materialized columns: the ordinary stacked compact gather
     mat = [i for i in range(len(db.columns)) if i not in ts.pending]

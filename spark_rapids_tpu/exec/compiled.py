@@ -126,12 +126,24 @@ def _flatten_batch(db: DeviceBatch):
     has_sel = db.sel is not None
     if has_sel:
         arrays.append(db.sel)
+    # deferred columns (a seam segment's output): per lane source its
+    # row-id lane, then the source batch itself
+    thin = None
+    if db.thin is not None:
+        src_specs = []
+        for src in db.thin.sources:
+            arrays.append(src.lane)
+            src_arrays, src_spec = _flatten_batch(src.batch)
+            arrays.extend(src_arrays)
+            src_specs.append(src_spec)
+        thin = (db.thin.capacity, src_specs,
+                tuple(sorted(db.thin.pending.items())))
     return arrays, (cols, list(db.names), static_rows, db.origin_file,
-                    has_sel)
+                    has_sel, thin)
 
 
 def _rebuild_batch(arrays, spec, i: int) -> Tuple[DeviceBatch, int]:
-    cols_spec, names, static_rows, origin, has_sel = spec
+    cols_spec, names, static_rows, origin, has_sel, thin_spec = spec
     cols = []
     for dtype, dictionary, has_hi, has_off in cols_spec:
         data = arrays[i]
@@ -156,7 +168,41 @@ def _rebuild_batch(arrays, spec, i: int) -> Tuple[DeviceBatch, int]:
     if has_sel:
         sel = arrays[i]
         i += 1
-    return DeviceBatch(cols, num_rows, names, origin, sel=sel), i
+    thin = None
+    if thin_spec is not None:
+        from ..columnar.lanes import LaneSource, ThinState
+        capacity, src_specs, pending = thin_spec
+        sources = []
+        for src_spec in src_specs:
+            lane = arrays[i]
+            src_batch, i = _rebuild_batch(arrays, src_spec, i + 1)
+            sources.append(LaneSource(src_batch, lane))
+        thin = ThinState(capacity, sources, dict(pending))
+    return DeviceBatch(cols, num_rows, names, origin, sel=sel,
+                       thin=thin), i
+
+
+def _spec_sig(spec) -> tuple:
+    """A batch spec as a hashable key that pins no host object: a
+    dictionary counts as there or not."""
+    cols, names, static_rows, origin, has_sel, thin = spec
+    if thin is not None:
+        capacity, src_specs, pending = thin
+        thin = (capacity, tuple(_spec_sig(s) for s in src_specs), pending)
+    return (tuple((dt.simple_string, d is not None, hi, off)
+                  for dt, d, hi, off in cols),
+            tuple(names), static_rows, origin, has_sel, thin)
+
+
+def _bare_spec(spec):
+    """The spec without its dictionaries: all that a cached program
+    which only moves rows may keep of the batch it was traced for."""
+    cols, names, static_rows, origin, has_sel, thin = spec
+    if thin is not None:
+        capacity, src_specs, pending = thin
+        thin = (capacity, [_bare_spec(s) for s in src_specs], pending)
+    return ([(dt, None, hi, off) for dt, _d, hi, off in cols], names,
+            static_rows, origin, has_sel, thin)
 
 
 def _shard_batch(db: DeviceBatch, mesh) -> DeviceBatch:
@@ -563,10 +609,16 @@ class CompiledPlan:
     reference's shuffle-exchange fabric role (RapidsShuffleManager/UCX)."""
 
     def __init__(self, root: PlanNode, conf: TpuConf, mesh=None,
-                 leaf_overrides: Optional[Dict[int, list]] = None):
+                 leaf_overrides: Optional[Dict[int, list]] = None,
+                 seam: bool = False):
         self.root = root
         self.conf = conf
         self.mesh = mesh
+        #: the root is a split plan's seam: the output goes to
+        #: SplitCompiledPlan._shrink, which reads the row count and
+        #: resolves `sel` / `thin` at the bucket it names, so the
+        #: program hands them over as they stand
+        self.seam = seam
         self._out_specs: Optional[list] = None
         self._compiled = None
         self._input_specs = None
@@ -687,16 +739,27 @@ class CompiledPlan:
             specs = []
             # ops traced here, at the program's end, read
             # `<root's node id>/sink/...` in a profiler trace
-            with jax.named_scope(getattr(self.root, "_node_id", None)
-                                 or self.root.name()), \
+            with jax.named_scope(_node_scope(self.root)), \
                     jax.named_scope("sink"):
                 for db in outs:
-                    if db.thin is not None:
+                    if self.seam:
+                        # the seam gathers after its row-count sync, at
+                        # the bucket of the live rows: hand over the
+                        # live count and, of every lane source, its
+                        # lane and the columns still referenced
+                        if db.sel is not None:
+                            db = dataclasses.replace(
+                                db, num_rows=jnp.sum(db.sel,
+                                                     dtype=jnp.int32))
+                        if db.thin is not None:
+                            db = dataclasses.replace(
+                                db, thin=db.thin.pruned())
+                    elif db.thin is not None:
                         # the program boundary is a pipeline SINK:
                         # resolve deferred columns INSIDE the traced
                         # program (the composed gathers fuse into the
-                        # whole-plan XLA program; the flat output layer
-                        # carries no lanes)
+                        # whole-plan XLA program; the output goes to
+                        # the fetch and has to be dense)
                         from ..columnar.lanes import materialize_batch
                         db = materialize_batch(db, ctx.conf)
                     arrays, spec = _flatten_batch(db)
@@ -731,16 +794,11 @@ class CompiledPlan:
         skey = plan_structure_key(self.root, self.conf)
         if skey is None:
             return None
-        spec_sig = []
-        for node, node_specs in in_specs:
-            per = []
-            for cols, names, static_rows, origin, has_sel in node_specs:
-                per.append((tuple((dt.simple_string, d is not None, hi, off)
-                                  for dt, d, hi, off in cols),
-                            tuple(names), static_rows, origin, has_sel))
-            spec_sig.append((type(node).__name__, tuple(per)))
+        spec_sig = tuple((type(node).__name__,
+                          tuple(_spec_sig(spec) for spec in node_specs))
+                         for node, node_specs in in_specs)
         input_sig = tuple((tuple(a.shape), str(a.dtype)) for a in flat_in)
-        return (skey, tuple(spec_sig), input_sig)
+        return (skey, spec_sig, input_sig, self.seam)
 
     def _try_plan_cache(self, ctx: ExecContext, pairs, flat_in,
                         in_specs) -> bool:
@@ -1043,6 +1101,13 @@ class CompiledPlan:
         return pa.Table.from_batches(batches, batches[0].schema)
 
 
+def _node_scope(node: PlanNode) -> str:
+    """The `jax.named_scope` of ops traced for `node` outside its own
+    `execute`: EXPLAIN's node id (scripts/trace_by_operator.py files
+    device ops by it), or the node's name where ids were not given."""
+    return getattr(node, "_node_id", None) or node.name()
+
+
 def _scope_nodes(root: PlanNode) -> list:
     """For the duration of a whole-plan trace, step every node's
     `execute` generator inside `jax.named_scope(<node id>)`: the ops a
@@ -1222,6 +1287,48 @@ def _slice_batch(db: DeviceBatch, cap: int, n: int) -> DeviceBatch:
     return DeviceBatch(cols, n, db.names, db.origin_file)
 
 
+#: signature -> the jitted program that resolves one seam batch's `sel`
+#: and `thin` at a shrunken capacity.  Module-level, as _COMPACT_CACHE
+#: is: a SplitCompiledPlan dies with its plan at every collect.
+_SEAM_CACHE: Dict[tuple, object] = {}
+
+
+def _seam_trace(spec, cap: int, scope: str, conf: TpuConf):
+    """The traced function behind _resolve_at: flat lanes of a seam
+    batch -> flat lanes of its dense prefix form at capacity `cap`."""
+    def run(flat):
+        from ..ops.filter import compact_batch
+        db, _ = _rebuild_batch(flat, spec, 0)
+        with jax.named_scope(scope), jax.named_scope("seam"):
+            out = compact_batch(db, db.row_mask(), conf, out_capacity=cap)
+        return _flatten_batch(out)[0]
+    return run
+
+
+def _resolve_at(db: DeviceBatch, cap: int, scope: str,
+                conf: TpuConf) -> DeviceBatch:
+    """Resolve a seam batch's selection vector and deferred columns at
+    capacity `cap` (the bucket of its live rows, which the caller has
+    just read): ONE program — the compaction order of `sel` cut to
+    `cap`, materialised columns taken at it, every deferred column
+    gathered once from its lane source into compacted position."""
+    from ..columnar.lanes import resolved_columns
+    from ..ops.pallas import elect_compact
+    flat, spec = _flatten_batch(db)
+    spec = _bare_spec(spec)
+    tier = elect_compact(conf, db.capacity)
+    sig = (_spec_sig(spec),
+           tuple((tuple(a.shape), str(a.dtype)) for a in flat), cap, scope,
+           None if tier is None else tier.interpret)
+    fn = _SEAM_CACHE.get(sig)
+    if fn is None:
+        fn = _SEAM_CACHE[sig] = jax.jit(_seam_trace(spec, cap, scope, conf))
+    out_spec = ([(c.dtype, c.dictionary, c.data_hi is not None, False)
+                 for c in resolved_columns(db)],
+                db.names, None, db.origin_file, False, None)
+    return _rebuild_batch(fn(flat), out_spec, 0)[0]
+
+
 def _swap_child(root: PlanNode, old: PlanNode, new: PlanNode):
     """EVERY (parent, index) link to `old` under `root`; caller mutates
     + restores.  Plan-level CSE (plan/overrides._dedupe_agg_twins) can
@@ -1322,47 +1429,37 @@ class SplitCompiledPlan:
                     except TimeoutError:
                         plan = None      # hung pool: compile inline
         if plan is None:
-            seg_root = self.seams[i] if i < len(self.seams) else self.root
-            plan = CompiledPlan(seg_root, ctx.conf)
-            progs[key] = plan
+            plan = progs[key] = self._new_segment(i, ctx.conf)
         return plan
+
+    def _new_segment(self, i: int, conf: TpuConf,
+                     leaf_overrides=None) -> CompiledPlan:
+        """Segment i's program object: rooted at seam i, whose output
+        _shrink resolves, or for the last segment at the plan's root."""
+        at_seam = i < len(self.seams)
+        return CompiledPlan(self.seams[i] if at_seam else self.root, conf,
+                            leaf_overrides=leaf_overrides, seam=at_seam)
 
     # -- background speculation --------------------------------------------
     @staticmethod
-    def _lane_dtypes(spec, layout) -> List[str]:
-        """Per-column data-lane dtype strings of one output batch,
-        recovered from the flat layout in _flatten_batch order."""
-        cols_spec = spec[0]
-        dts = []
-        j = 0
-        for _dt, _d, has_hi, has_off in cols_spec:
-            dts.append(layout[j][1])
-            j += 2                       # data + validity
-            if has_hi:
-                j += 1
-            if has_off:
-                j += 2
-        return dts
-
-    @staticmethod
-    def _placeholder_batch(spec, lane_dtypes, cap: int) -> DeviceBatch:
+    def _placeholder_batch(seam_out: DeviceBatch, cap: int) -> DeviceBatch:
         """A post-shrink-shaped stand-in batch of ShapeDtypeStruct lanes
-        (capacity `cap`, dynamic row count, real dictionaries): enough
-        for jit(...).lower() to trace the next segment without data."""
+        (dense, capacity `cap`, dynamic row count, real dictionaries):
+        enough for jit(...).lower() to trace the next segment without
+        data.  A deferred position is described by its source's column,
+        as _shrink will resolve it."""
         import numpy as np
-        cols_spec, names, _static, origin, _sel = spec
-        cols = []
-        for (dt, dictionary, has_hi, _off), lane_dt in zip(cols_spec,
-                                                           lane_dtypes):
-            cols.append(DeviceColumn(
-                jax.ShapeDtypeStruct((cap,), np.dtype(lane_dt)),
-                jax.ShapeDtypeStruct((cap,), np.dtype(bool)),
-                dt, dictionary,
-                jax.ShapeDtypeStruct((cap,), np.dtype(np.int64))
-                if has_hi else None))
+        from ..columnar.lanes import resolved_columns
+        cols = [DeviceColumn(
+            jax.ShapeDtypeStruct((cap,), c.data.dtype),
+            jax.ShapeDtypeStruct((cap,), np.dtype(bool)),
+            c.dtype, c.dictionary,
+            None if c.data_hi is None
+            else jax.ShapeDtypeStruct((cap,), np.dtype(np.int64)))
+            for c in resolved_columns(seam_out)]
         return DeviceBatch(cols,
                            jax.ShapeDtypeStruct((), np.dtype(np.int32)),
-                           list(names), origin)
+                           list(seam_out.names), seam_out.origin_file)
 
     def _candidate_caps(self, i: int, cap_in: int, conf) -> List[int]:
         """Predicted post-shrink buckets for seam i's output: exact when
@@ -1404,21 +1501,23 @@ class SplitCompiledPlan:
         spec = specs[0]
         if any(off for _dt, _d, _hi, off in spec[0]):
             return                       # ragged seam output never splits
-        lane_dtypes = self._lane_dtypes(spec, layout)
-        cap_in = layout[0][0][0] if layout[0][0] else 0
+        # segment i's output as the program will hand it over, lanes
+        # standing for arrays: its `sel` and `thin` included
+        seam_out, _ = _rebuild_batch(
+            [jax.ShapeDtypeStruct(shape, dt) for shape, dt in layout],
+            spec, 0)
+        cap_in = seam_out.capacity
         if not cap_in:
             return
         service = get_service(ctx.conf)
-        seg_root = self.seams[nxt] if nxt < len(self.seams) else self.root
         conf = ctx.conf
         for cap in self._candidate_caps(i, cap_in, conf):
             key = (cap,)
             if key in self._programs[nxt]:
                 continue
-            placeholder = [self._placeholder_batch(spec, lane_dtypes, cap)]
-            plan = CompiledPlan(
-                seg_root, conf,
-                leaf_overrides={id(self.leaves[i]): placeholder})
+            placeholder = [self._placeholder_batch(seam_out, cap)]
+            plan = self._new_segment(
+                nxt, conf, leaf_overrides={id(self.leaves[i]): placeholder})
 
             def thunk(plan=plan, conf=conf):
                 plan.aot_compile(ExecContext(conf))
@@ -1428,18 +1527,15 @@ class SplitCompiledPlan:
             ctx.bump("compile_speculative_submitted")
 
     @staticmethod
-    def _shrink(outs: List[DeviceBatch], ctx
+    def _shrink(outs: List[DeviceBatch], ctx, scope: str
                 ) -> Tuple[List[DeviceBatch], int]:
-        """The seam's re-bucket: (batches sliced down to the bucket of
-        their live rows, the live rows)."""
-        sliced = []
+        """The seam's re-bucket: (dense prefix batches at the bucket of
+        their live rows, the live rows).  The row count is read FIRST;
+        a selection vector and deferred lanes are resolved after it, at
+        that bucket (`scope`: the seam's node id, for the trace)."""
+        shrunk = []
         rows = 0
         for db in outs:
-            if db.sel is not None or db.thin is not None:
-                # lazy-join seam output: the seam re-buckets anyway, so
-                # materialize the selection vector / deferred lanes here
-                from ..ops.batch_ops import ensure_prefix
-                db = ensure_prefix(db, ctx.conf)
             if any(c.offsets is not None for c in db.columns):
                 raise _SplitUnsupported()   # ragged seam output
             n = db.num_rows
@@ -1451,12 +1547,18 @@ class SplitCompiledPlan:
                 ctx.bump("host_syncs")
             rows += n
             cap = min(bucket_capacity(max(n, 1), ctx.conf), db.capacity)
-            # num_rows stays a device scalar so segment traces are keyed
-            # on the CAPACITY BUCKET only — a drifting row count
-            # (growing table, streaming appends) reuses compiled
-            # programs instead of recompiling per exact count
-            sliced.append(_slice_batch(db, cap, jnp.int32(n)))
-        return sliced, rows
+            if db.sel is None and db.thin is None:
+                # num_rows stays a device scalar so segment traces are
+                # keyed on the CAPACITY BUCKET only — a drifting row
+                # count (growing table, streaming appends) reuses
+                # compiled programs instead of recompiling per exact
+                # count
+                shrunk.append(_slice_batch(db, cap, jnp.int32(n)))
+                continue
+            ctx.bump("overhead.seam_lazy_count")
+            ctx.bump("overhead.seam_capacity_rows", db.capacity)
+            shrunk.append(_resolve_at(db, cap, scope, ctx.conf))
+        return shrunk, rows
 
     def collect(self, ctx: ExecContext) -> pa.Table:
         self._install_leaves()
@@ -1483,7 +1585,8 @@ class SplitCompiledPlan:
                 # wall_breakdown(), the history plane, and the seam gate
                 with CollectSpan(ctx, "seam", "overhead.seam_ms",
                                  cat="transition"):
-                    sliced, rows = self._shrink(outs, ctx)
+                    sliced, rows = self._shrink(
+                        outs, ctx, _node_scope(self.seams[i]))
                     leaf.batches = sliced
                     key = tuple(db.capacity for db in sliced)
                 for k, v in (("overhead.seam_count", 1),

@@ -16,7 +16,7 @@ TPU-first realization on static shapes:
 """
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,7 +90,7 @@ def pallas_compact_order(keep: jax.Array, conf: TpuConf):
 
 
 def _compact_trace(ncols: int, has_hi: Tuple[bool, ...],
-                   pallas_interpret=None):
+                   pallas_interpret=None, out_capacity=None):
     def run(datas, valids, his, keep):
         if pallas_interpret is not None:
             from .pallas.compact import compaction_order as pallas_order
@@ -98,6 +98,9 @@ def _compact_trace(ncols: int, has_hi: Tuple[bool, ...],
         else:
             order = compaction_order(keep)
         count = jnp.sum(keep, dtype=jnp.int32)
+        if out_capacity is not None:
+            order = order[:out_capacity]
+            count = jnp.minimum(count, out_capacity)
         lanes = []
         for i in range(ncols):
             lanes.append(datas[i])
@@ -105,7 +108,7 @@ def _compact_trace(ncols: int, has_hi: Tuple[bool, ...],
             if has_hi[i]:
                 lanes.append(his[i])
         moved = grouped_take(lanes, order)
-        live = jnp.arange(keep.shape[0], dtype=jnp.int32) < count
+        live = jnp.arange(order.shape[0], dtype=jnp.int32) < count
         out = []
         j = 0
         for i in range(ncols):
@@ -123,8 +126,13 @@ def _compact_trace(ncols: int, has_hi: Tuple[bool, ...],
 
 def compact_batch(db: DeviceBatch, keep: jax.Array,
                   conf: TpuConf = DEFAULT_CONF,
-                  sync: bool = False) -> DeviceBatch:
+                  sync: bool = False,
+                  out_capacity: Optional[int] = None) -> DeviceBatch:
     """Keep rows where `keep` is True (padding rows must already be False).
+
+    `out_capacity` (default: the batch's own) is the capacity of the
+    result: the first that many kept rows, gathered at that capacity.  A
+    caller passes it when it knows the kept rows fit.
 
     By default the surviving row count stays on device (`num_rows` becomes a
     0-d jax scalar) so a filter feeding another device operator costs zero
@@ -139,7 +147,7 @@ def compact_batch(db: DeviceBatch, keep: jax.Array,
         # sources into compacted position — one pass, no
         # materialize-then-compact double gather
         from ..columnar.lanes import compact_thin
-        db = compact_thin(db, keep, conf)
+        db = compact_thin(db, keep, conf, out_capacity)
         if not sync:
             return db
         return shrink_to_rows(db, int(db.num_rows), conf)
@@ -149,11 +157,11 @@ def compact_batch(db: DeviceBatch, keep: jax.Array,
     pallas_interpret = None if tier is None else tier.interpret
     sig = (db.num_columns, has_hi, db.capacity,
            tuple(str(c.data.dtype) for c in db.columns),
-           pallas_interpret)
+           pallas_interpret, out_capacity)
     fn = _COMPACT_CACHE.get(sig)
     if fn is None:
         fn = jax.jit(_compact_trace(db.num_columns, has_hi,
-                                    pallas_interpret))
+                                    pallas_interpret, out_capacity))
         _COMPACT_CACHE[sig] = fn
     if any(has_hi):
         zeros = jnp.zeros((db.capacity,), jnp.int64)

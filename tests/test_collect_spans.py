@@ -279,5 +279,8 @@ def test_trace_by_operator_reads_op_names_off_a_chip_trace():
     assert sum(dev["by_node"].values()) == pytest.approx(0.0392, abs=5e-4)
     assert max(dev["by_node"], key=dev["by_node"].get) == \
         ("collect:q6", tool.EAGER + "ops/groupby.py")
-    assert tool.node_of({"jit(run)/SortExec#0/HashJoinExec#4/sink/gather:"}) \
-        == "HashJoinExec#4"
+    assert [tool.node_of({"jit(run)/SortExec#0/HashJoinExec#4/" + rest})
+            for rest in ("mul:", "sink/jit(_take)/gather:",
+                         "seam/jit(run)/sort:", "sinker:")] == \
+        ["HashJoinExec#4", "HashJoinExec#4/sink", "HashJoinExec#4/seam",
+         "HashJoinExec#4"]
