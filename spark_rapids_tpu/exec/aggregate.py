@@ -177,9 +177,27 @@ def _domains_as_pack(domains):
     return tuple((0, size + 1) for size in domains)
 
 
+#: the strategies whose program sorts its rows by key
+_SORT_STRATEGIES = ("packed_sort", "lexsort")
+
+
+def _strategy(num_keys: int, pallas_interp, domains, pack) -> str:
+    """The name of the aggregate program that the branches below
+    (_run_groupby, HashAggregate.partial_fused) build from these
+    elections; a keyless aggregate is a "reduce"."""
+    if not num_keys:
+        return "reduce"
+    if pallas_interp is not None:
+        return "pallas"
+    if domains is not None:
+        return "dense"
+    return "packed_sort" if G.all_keys_pack(pack, num_keys) else "lexsort"
+
+
 def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
                  specs: List[G.AggSpec], live, capacity: int,
                  key_ranges=None, conf=None):
+    """-> (key_cols, out_keys, outs, num_groups, strategy)."""
     key_cols = [ensure_unique_dict(c) for c in key_cols]
     if conf is not None and any(c.dictionary is not None for c in key_cols):
         # dictionary group keys aggregate UNDECODED (codes hash/pack/
@@ -248,7 +266,8 @@ def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
     # tracing the count is a Tracer and must stay on device
     if not isinstance(num_groups, jax.core.Tracer):
         num_groups = int(num_groups)
-    return key_cols, out_keys, outs, num_groups
+    return key_cols, out_keys, outs, num_groups, _strategy(
+        len(key_cols), pallas_interp, domains, pack)
 
 
 def _run_reduce(agg_cols: List[DeviceColumn], specs: List[G.AggSpec],
@@ -289,7 +308,12 @@ class HashAggregate:
     def __init__(self, key_exprs: Sequence[E.Expression],
                  key_names: Sequence[str],
                  aggs: Sequence[Tuple[AggregateFunction, str]],
-                 conf: TpuConf, key_ranges=None, input_ranges=None):
+                 conf: TpuConf, key_ranges=None, input_ranges=None,
+                 bump=None):
+        #: the running collect's `ExecContext.bump` (the trace context's,
+        #: under whole-plan tracing): every aggregate program run counts
+        #: its strategy and its capacity through it (_note)
+        self.bump = bump
         self.key_exprs = list(key_exprs)
         self.key_names = list(key_names)
         self.aggs = list(aggs)
@@ -327,6 +351,18 @@ class HashAggregate:
             for (mkind, mdt) in fn.merge_ops():
                 self.merge_specs.append(G.AggSpec(mkind, mi, mdt))
                 mi += 1
+
+    def _note(self, strategy: str, capacity: int) -> None:
+        """Count one aggregate program: `agg.strategy.<strategy>` (and
+        `agg.strategy.sorted` where it sorts its rows), and
+        `agg.capacity_rows` by the padded rows it ran at."""
+        bump = self.bump
+        if bump is None:
+            return
+        bump(f"agg.strategy.{strategy}")
+        if strategy in _SORT_STRATEGIES:
+            bump("agg.strategy.sorted")
+        bump("agg.capacity_rows", int(capacity))
 
     # ---- phases ----
 
@@ -369,10 +405,12 @@ class HashAggregate:
             live = db.row_mask()
         if not self.key_exprs:
             outs = _run_reduce(agg_cols, self.update_specs, live, db.capacity)
+            self._note("reduce", db.capacity)
             return self._reduce_outs_to_batch(outs)
-        key_cols, out_keys, outs, n_groups = _run_groupby(
+        key_cols, out_keys, outs, n_groups, strategy = _run_groupby(
             key_batch.columns, agg_cols, self.update_specs, live,
             db.capacity, key_ranges=self.key_ranges, conf=self.conf)
+        self._note(strategy, db.capacity)
         return self._groupby_outs_to_batch(key_cols, out_keys, outs, n_groups)
 
     def can_fuse_filter(self, db: "Optional[DeviceBatch]" = None) -> bool:
@@ -560,6 +598,8 @@ class HashAggregate:
         out_keys, outs, ng = fn(_col_lanes(db),
                                 tuple(c.validity for c in db.columns),
                                 _num_rows_scalar(db.num_rows), aux, *extra)
+        self._note(_strategy(len(self.key_exprs), pallas_interp,
+                             dense_domains, pack), db.capacity)
         if not self.key_exprs:
             return outs if raw else self._reduce_outs_to_batch(outs)
         nconds = len(conds)
@@ -594,6 +634,7 @@ class HashAggregate:
                        for i in range(len(self.update_specs)))
         valids = tuple(jnp.stack([p[i][1] for p in partial_outs])
                        for i in range(len(self.update_specs)))
+        self._note("reduce", k)
         return list(fn(stacks, valids))
 
     def final_host(self, outs) -> pa.Table:
@@ -639,10 +680,12 @@ class HashAggregate:
         if not self.key_exprs:
             outs = _run_reduce(buf_cols, self.merge_specs, merged.row_mask(),
                                merged.capacity)
+            self._note("reduce", merged.capacity)
             return self._reduce_outs_to_batch(outs)
-        key_cols, out_keys, outs, n_groups = _run_groupby(
+        key_cols, out_keys, outs, n_groups, strategy = _run_groupby(
             key_cols, buf_cols, self.merge_specs, merged.row_mask(),
             merged.capacity, key_ranges=self.key_ranges, conf=self.conf)
+        self._note(strategy, merged.capacity)
         return self._groupby_outs_to_batch(key_cols, out_keys, outs, n_groups)
 
     def final(self, merged: DeviceBatch) -> DeviceBatch:

@@ -597,6 +597,15 @@ def _plan_cache_put(key, entry: tuple, conf: TpuConf) -> None:
             _PLAN_EXEC_CACHE.pop(next(iter(_PLAN_EXEC_CACHE)))
 
 
+#: Trace-time counters under this prefix count once per RUN of the
+#: program: an aggregate's strategy, capacity and merged batches
+#: (exec/aggregate.py `_note`, HashAggregateExec), decided while its
+#: node is traced into the program.  Every other host number of a trace
+#: is a fact of the plan, copied into `ctx.metrics` when the program is
+#: compiled or adopted.
+_PER_RUN_PREFIX = "agg."
+
+
 class CompiledPlan:
     """A traced-and-jitted device plan bound to its leaf scans.
 
@@ -624,6 +633,7 @@ class CompiledPlan:
         self._input_specs = None
         self._out_layout = None        # [(shape, dtype str)] of flat outputs
         self._host_metrics: Dict[str, object] = {}
+        self._run_counts: Dict[str, int] = {}    # see _PER_RUN_PREFIX
         #: static XLA cost surface (flops / bytes accessed / peak temp)
         #: captured at compile time for the attribution plane
         self._cost: Dict[str, float] = {}
@@ -733,6 +743,9 @@ class CompiledPlan:
                 # escaping the jit would be a leaked tracer
                 host_metrics = {k: v for k, v in trace_ctx.metrics.items()
                                 if isinstance(v, (int, float))}
+                out_holder["run_counts"] = {
+                    k: host_metrics.pop(k) for k in list(host_metrics)
+                    if k.startswith(_PER_RUN_PREFIX)}
                 out_holder["host_metrics"] = host_metrics
                 ctx.metrics.update(host_metrics)
             flat_out = []
@@ -813,7 +826,8 @@ class CompiledPlan:
         if entry is None:
             return False
         (self._compiled, self._out_specs, self._out_layout,
-         self._host_metrics, self._cost, _anchors) = entry
+         self._host_metrics, self._run_counts, self._cost,
+         _anchors) = entry
         self._input_specs = [(n, list(s)) for n, s in in_specs]
         ctx.metrics.update(self._host_metrics)
         ctx.bump("compile_cache_hits")
@@ -855,6 +869,7 @@ class CompiledPlan:
         self._out_specs = out_holder["specs"]
         self._out_layout = out_holder["layout"]
         self._host_metrics = out_holder.get("host_metrics", {})
+        self._run_counts = out_holder.get("run_counts", {})
         self._compiled = compiled
         from ..config import PROFILE_COST_ANALYSIS
         self._cost = _compiled_cost(compiled) \
@@ -873,7 +888,8 @@ class CompiledPlan:
                 _plan_cache_put(self._cache_key,
                                 (compiled, self._out_specs,
                                  self._out_layout, self._host_metrics,
-                                 self._cost, anchors), self.conf)
+                                 self._run_counts, self._cost, anchors),
+                                self.conf)
 
     def ensure_compiled(self, ctx: ExecContext) -> None:
         """Compile (or adopt a cached executable) without executing —
@@ -965,6 +981,8 @@ class CompiledPlan:
         # feed) multiplies it by the measured per-backend dispatch floor
         # when no profiled decomposition exists for this run
         m["exec_dispatches"] = m.get("exec_dispatches", 0) + 1
+        for k, n in self._run_counts.items():
+            ctx.bump(k, n)
         # always-on measured working-set floor: the largest XLA
         # memory_analysis() footprint this query dispatched (args +
         # output + temp + code, captured at compile time — no conf

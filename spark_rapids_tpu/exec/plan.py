@@ -718,7 +718,8 @@ class HashAggregateExec(PlanNode):
         from . import ooc as O
         from .ooc_agg import OutOfCoreAggregator
         agg = HashAggregate(self.key_exprs, self.key_names, self.aggs,
-                            ctx.conf, key_ranges=self._key_ranges())
+                            ctx.conf, key_ranges=self._key_ranges(),
+                            bump=ctx.bump)
         agg._input_ranges_by_expr = self._input_ranges(agg)
         # Fuse upstream filters into the map side for EVERY aggregation:
         # the predicates become the groupby's live-mask, so filter +
@@ -765,6 +766,9 @@ class HashAggregateExec(PlanNode):
                     for c in conds:
                         live = live & compute_predicate(c, db, ctx.conf)
                 p = agg.partial(db, live)
+            # one input batch more whose partial result the final
+            # aggregate merges
+            ctx.bump("agg.partial_batches")
             if oocagg is not None:
                 oocagg.add(p)
                 continue
@@ -829,7 +833,7 @@ class HashAggregateExec(PlanNode):
             raise ValueError("collect_device is for global aggregations")
         ctx = ctx or ExecContext()
         agg = HashAggregate(self.key_exprs, self.key_names, self.aggs,
-                            ctx.conf)
+                            ctx.conf, bump=ctx.bump)
         source, conds = self._strip_filters(True)
         raw = []
         for db in source.execute(ctx):
@@ -842,6 +846,7 @@ class HashAggregateExec(PlanNode):
                 db = materialize_refs(db, list(conds) +
                                       list(agg.input_exprs), ctx.conf)
             raw.append(agg.partial_fused(db, conds, raw=True))
+            ctx.bump("agg.partial_batches")
         if not raw:
             empty = empty_device_batch(source.output_schema, ctx.conf)
             raw.append(agg.partial_fused(empty, conds, raw=True))

@@ -464,6 +464,19 @@ def packed_groupby_trace(pack_spec, key_lanes_info, agg_specs,
     return run
 
 
+def all_keys_pack(pack_spec, num_keys: int) -> bool:
+    """Whether `pack_spec` folds EVERY group key into the one sort lane
+    of packed_groupby_trace; groupby_trace otherwise sorts by a capped
+    lexsort over the keys' own lanes."""
+    if pack_spec is None or \
+            sum(s is not None for s in pack_spec) != num_keys:
+        return False
+    tot = 1
+    for _lo, span in pack_spec:
+        tot *= span
+    return tot <= (1 << 62)
+
+
 def groupby_trace(key_lanes_info, agg_specs, num_segments, capacity,
                   pack_spec=None, scatter_free=True,
                   max_sort_operands=2):
@@ -487,14 +500,10 @@ def groupby_trace(key_lanes_info, agg_specs, num_segments, capacity,
     gathers are the expensive op on TPU, masked VPU work is nearly free.
     """
     packed_idx = {i for i, s in enumerate(pack_spec or []) if s is not None}
-    if pack_spec is not None and len(packed_idx) == len(key_lanes_info):
-        tot = 1
-        for _lo, span in pack_spec:
-            tot *= span
-        if tot <= (1 << 62):
-            return packed_groupby_trace(pack_spec, key_lanes_info,
-                                        agg_specs, num_segments, capacity,
-                                        scatter_free=scatter_free)
+    if all_keys_pack(pack_spec, len(key_lanes_info)):
+        return packed_groupby_trace(pack_spec, key_lanes_info,
+                                    agg_specs, num_segments, capacity,
+                                    scatter_free=scatter_free)
 
     def key_sort_lanes(keys, keys_valid):
         """[(lanes...)] for sorting/boundaries: packed keys collapse into
