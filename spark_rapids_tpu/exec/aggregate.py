@@ -190,7 +190,7 @@ def _strategy(num_keys: int, pallas_interp, domains, pack) -> str:
     if pallas_interp is not None:
         return "pallas"
     if domains is not None:
-        return "dense"
+        return "dense_masked" if G.dense_is_masked(domains) else "dense"
     return "packed_sort" if G.all_keys_pack(pack, num_keys) else "lexsort"
 
 
@@ -354,12 +354,16 @@ class HashAggregate:
 
     def _note(self, strategy: str, capacity: int) -> None:
         """Count one aggregate program: `agg.strategy.<strategy>` (and
-        `agg.strategy.sorted` where it sorts its rows), and
-        `agg.capacity_rows` by the padded rows it ran at."""
+        `agg.strategy.sorted` where it sorts its rows; `.dense` too
+        where it is the dense program built without scatters,
+        `dense_masked`), and `agg.capacity_rows` by the padded rows it
+        ran at."""
         bump = self.bump
         if bump is None:
             return
         bump(f"agg.strategy.{strategy}")
+        if strategy == "dense_masked":
+            bump("agg.strategy.dense")
         if strategy in _SORT_STRATEGIES:
             bump("agg.strategy.sorted")
         bump("agg.capacity_rows", int(capacity))

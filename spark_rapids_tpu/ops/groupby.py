@@ -81,21 +81,45 @@ def _eq_prev(lane):
     return jnp.concatenate([jnp.ones((1,), bool), lane[1:] != lane[:-1]])
 
 
-def _segment_minmax_float(vals, valid_live, seg_ids, num_segments, is_min):
+def _scatter_reducer(seg_ids, num_segments):
+    """reduce_to(lane, ident, op) -> (num_segments,) by one
+    jax.ops.segment_<op> scatter; op is "sum", "min" or "max".  `ident`
+    is op's identity in the lane's dtype: the lane already holds it on
+    the rows that must not count, and a scatter needs it nowhere else."""
+    ops = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+           "max": jax.ops.segment_max}
+
+    def reduce_to(lane, ident, op):
+        return ops[op](lane, seg_ids, num_segments=num_segments)
+    return reduce_to
+
+
+def _masked_reducer(hit):
+    """The same contract without a scatter: `hit` is the (D, N) mask of
+    the rows of each bucket (XLA fuses it into every reduce; it is never
+    materialised) and each bucket reduces the lane over its own rows,
+    the other rows standing in as `ident`.  Integers accumulate in the
+    lane's own dtype (no promotion, no narrowing)."""
+    def reduce_to(lane, ident, op):
+        rows = jnp.where(hit, lane[None, :], jnp.asarray(ident, lane.dtype))
+        if op == "sum":
+            return jnp.sum(rows, axis=1, dtype=lane.dtype)
+        return (jnp.min if op == "min" else jnp.max)(rows, axis=1)
+    return reduce_to
+
+
+def _segment_minmax_float(vals, valid_live, reduce_to, is_min):
     """Java-ordering min/max for float values (NaN greatest).
 
     Value-space with NaN tracking; the exact bit-space path for int64-bits
     DOUBLE lanes lives inline in groupby_trace via _bits_total_order."""
     isnan = jnp.isnan(vals) & valid_live
-    has_nan = jax.ops.segment_max(isnan.astype(jnp.int32), seg_ids,
-                                  num_segments=num_segments) > 0
+    has_nan = reduce_to(isnan.astype(jnp.int32), 0, "max") > 0
     all_nan_ident = jnp.float64(np.inf) if is_min else jnp.float64(-np.inf)
     clean = jnp.where(valid_live & ~isnan, vals, all_nan_ident)
-    red = (jax.ops.segment_min if is_min else jax.ops.segment_max)(
-        clean, seg_ids, num_segments=num_segments)
-    non_nan_count = jax.ops.segment_sum(
-        (valid_live & ~isnan).astype(jnp.int32), seg_ids,
-        num_segments=num_segments)
+    red = reduce_to(clean, all_nan_ident, "min" if is_min else "max")
+    non_nan_count = reduce_to((valid_live & ~isnan).astype(jnp.int32), 0,
+                              "sum")
     if is_min:
         # min is NaN only when every valid value is NaN
         return jnp.where(has_nan & (non_nan_count == 0), jnp.float64(np.nan),
@@ -129,10 +153,13 @@ _ORDER_MIN = np.int64(-2**63)
 
 
 
-def _queue_sum_lanes(agg_specs, spec_vls, live_all):
+def _queue_sum_lanes(agg_specs, spec_vls, live_all, count_dtype=jnp.int64):
     """Collect every sum-like lane (SUM buffers, COUNT/COUNT_ALL,
     per-input valid counts) into two dtype-class stacks.  Shared by all
     group-by variants so the lane/dtype rules cannot drift.
+    `count_dtype`: what the 0/1 lanes of the counts add up in.  A stack
+    needs its sums' int64; a lane reduced alone may count in int32,
+    which holds any capacity (row indices are int32 throughout).
 
     Returns (int_lanes, int_slots, f64_lanes, f64_slots)."""
     int_lanes, int_slots = [], {}
@@ -149,9 +176,9 @@ def _queue_sum_lanes(agg_specs, spec_vls, live_all):
         d, vl = spec_vls[si]
         dt = spec.dtype
         if spec.kind == COUNT_ALL:
-            queue(("cnt", si), live_all.astype(jnp.int64), False)
+            queue(("cnt", si), live_all.astype(count_dtype), False)
         elif spec.kind == COUNT:
-            queue(("cnt", si), vl.astype(jnp.int64), False)
+            queue(("cnt", si), vl.astype(count_dtype), False)
         elif spec.kind == SUM:
             cd = compute_view(d, dt)
             if t.is_floating(dt):
@@ -161,34 +188,39 @@ def _queue_sum_lanes(agg_specs, spec_vls, live_all):
                 queue(("sum", si),
                       jnp.where(vl, cd.astype(jnp.int64), 0), False)
         if spec.kind not in (COUNT, COUNT_ALL):
-            queue(("vc", spec.input_idx), vl.astype(jnp.int64), False)
+            queue(("vc", spec.input_idx), vl.astype(count_dtype), False)
     return int_lanes, int_slots, f64_lanes, f64_slots
 
 
-def _batched_sums(agg_specs, spec_vls, live_all, seg, num_segments,
-                  reindex):
-    """ONE wide (N, K) segment_sum for every sum-like lane — TPU scatters
-    pay a fixed serialization cost per pass, so K-wide rows amortize it
-    (measured 4.5x for 10 aggregates at 8M rows).
+def _batched_sums(agg_specs, spec_vls, live_all, sum_lanes,
+                  count_dtype=jnp.int64):
+    """Every sum-like lane of the dense group-by through
+    `sum_lanes(lanes) -> column`, one call per dtype class: `lanes` are
+    K (N,) arrays, `column(j)` is the (G,) sums of lane j by group.
+    dense_groupby_trace passes one of two:
 
-    spec_vls: per-spec (data, valid&live) with any permutation already
-    applied; live_all: the COUNT(*) lane; reindex: maps the (S, K)
-    segment output onto the caller's group order.
+      * the scatter: ONE wide (N, K) segment_sum.  On the chip a scatter
+        pays per row, not per lane (my chip runs, PR 28, 4M rows into 12
+        buckets: 366 ms for K = 2 int64 lanes, 365 ms for K = 18), so
+        K-wide rows cost what one lane does;
+      * up to MASKED_DOMAIN_MAX: each lane reduced apart under the bucket
+        masks (_masked_reducer), one fused pass over the batch a lane,
+        no (N, K) stack.  Lanes that hold the same values (the valid
+        counts of inputs without nulls) are one lane to XLA.  The count
+        lanes add up in int32 (`count_dtype`) and widen afterwards: in
+        Q1's program that took the batch from 2.4 to 1.85 ms (my chip
+        runs, PR 28).
+
+    spec_vls: per-spec (data, valid&live); live_all: the COUNT(*) lane.
     Returns sum_of(key, is_float) -> (G,) lane."""
     int_lanes, int_slots, f64_lanes, f64_slots = _queue_sum_lanes(
-        agg_specs, spec_vls, live_all)
-
-    int_out = f64_out = None
-    if int_lanes:
-        int_out = reindex(jax.ops.segment_sum(
-            jnp.stack(int_lanes, axis=1), seg, num_segments=num_segments))
-    if f64_lanes:
-        f64_out = reindex(jax.ops.segment_sum(
-            jnp.stack(f64_lanes, axis=1), seg, num_segments=num_segments))
+        agg_specs, spec_vls, live_all, count_dtype)
+    int_col = sum_lanes(int_lanes) if int_lanes else None
+    f64_col = sum_lanes(f64_lanes) if f64_lanes else None
 
     def sum_of(key, is_float):
-        return (f64_out[:, f64_slots[key]] if is_float
-                else int_out[:, int_slots[key]])
+        return (f64_col(f64_slots[key]) if is_float
+                else int_col(int_slots[key]))
     return sum_of
 
 
@@ -311,8 +343,9 @@ def sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary, starts_c,
                     data = _segment_minmax_float_sorted(
                         cd, vl, boundary, ends_c, is_min)
                 else:
-                    data = _segment_minmax_float(cd, vl, seg(),
-                                                 num_segments, is_min)
+                    data = _segment_minmax_float(
+                        cd, vl, _scatter_reducer(seg(), num_segments),
+                        is_min)
             else:
                 if isinstance(dt, t.BooleanType):
                     ident = jnp.asarray(is_min)
@@ -327,7 +360,9 @@ def sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary, starts_c,
             pick = jnp.clip(reduce_lane(masked, is_first), 0,
                             capacity - 1)
             data = cd[pick]
-            out_valid = vl[pick] & group_live
+            # a run with no valid row picks out of range, clipped onto
+            # another run's row: the valid count says so, vl[pick] not
+            out_valid = vl[pick] & out_valid
         elif spec.kind == ANY:
             data = reduce_lane(
                 jnp.where(vl, cd, False).astype(jnp.int8), False) > 0
@@ -662,17 +697,59 @@ def reduce_trace(agg_specs, capacity):
     return run
 
 
+#: Largest combined key domain (null slots included) whose dense group-by
+#: reduces under bucket masks; above it the buckets are scattered into.
+#: Measured on a TPU v5e at capacity 4M with Q1's update specs (PERF.md
+#: section 6, PR 28): masked 4.3 / 5.9 / 8.5 / 22.6 / 80.9 ms a batch at
+#: D = 13 / 27 / 64 / 256 / 1024 (0.076 ms a bucket), the scatters
+#: 357-365 ms whatever D and however many lanes ride the stack; a lone
+#: float64 sum, float or integer min/max and first/last 24-43 ms masked
+#: against 650-1300 ms scattered at D = 1024.  The lines would cross near
+#: D = 4700, past `agg.denseDomainMax` (4096); 1024 is the largest domain
+#: that was measured, and a program of four times Q1's lanes is still
+#: ahead there.
+MASKED_DOMAIN_MAX = 1024
+
+
+def dense_domain(domain_sizes) -> int:
+    """Buckets of the dense group-by: every key's codes and its null
+    slot, multiplied out."""
+    total = 1
+    for size in domain_sizes:
+        total *= size + 1
+    return total
+
+
+def dense_is_masked(domain_sizes) -> bool:
+    """Whether dense_groupby_trace builds these domains' program from
+    bucket-masked reductions (no scatter) — the trace and the strategy
+    counter (exec/aggregate.py `_strategy`) both ask here."""
+    return dense_domain(domain_sizes) <= MASKED_DOMAIN_MAX
+
+
 def dense_groupby_trace(domain_sizes, agg_specs, capacity):
     """Bounded-domain groupby: NO SORT, NO ROW GATHERS.
 
     When every group key has a small static domain (dictionary codes,
     booleans), rows map to a dense bucket id (base-mixed radix over the
     key slots, one extra slot per key for null) and every aggregate is a
-    single segment reduction into D buckets.  For the classic low-
-    cardinality shapes (TPC-H q1's returnflag x linestatus) this replaces
-    an O(C log C) multi-lane lexsort + per-column gathers with one
-    masked pass — the difference between seconds and milliseconds at
-    8M-row capacities.
+    reduction of the rows into D buckets, in one of two realisations
+    chosen by D alone (dense_is_masked):
+
+      * D <= MASKED_DOMAIN_MAX: per bucket a masked reduction over the
+        rows, `reduce(where(seg == b, lane, identity))`, every lane a
+        (N,) array of its own.  No scatter anywhere in the program: XLA
+        fuses the (D, N) compare and select into each lane's reduce.
+        TPC-H q1 (12 buckets, 7 int64 sums and the counts) takes 1.8
+        ms a 4M-row batch inside its whole-plan program on a v5e.
+      * above: one jax.ops.segment_* scatter per reduction (the sum-like
+        lanes stacked into one, _batched_sums).  A scatter costs this
+        chip 85 ns a row whatever D (360 ms a 4M-row batch for the
+        stack, 280-300 more for each min/max; q1 paid 427 ms a batch
+        until PR 28).
+
+    Neither sorts nor gathers rows, which is what the sorted group-by
+    pays for (O(C log C) multi-lane sort + per-column gathers).
 
     domain_sizes: static per-key domain size (codes in [0, size)).
     Returns fn(keys, keys_valid, agg_data, agg_valid, live) with the same
@@ -686,6 +763,7 @@ def dense_groupby_trace(domain_sizes, agg_specs, capacity):
         d_total *= size + 1                       # +1: the null slot
     strides.reverse()
     D = d_total
+    masked = dense_is_masked(domain_sizes)
 
     def run(keys, keys_valid, agg_data, agg_valid, live):
         comb = jnp.zeros((capacity,), jnp.int32)
@@ -696,15 +774,28 @@ def dense_groupby_trace(domain_sizes, agg_specs, capacity):
                 slot = jnp.where(kv, slot, jnp.int32(size))
             comb = comb + slot * jnp.int32(stride)
         seg = jnp.where(live, comb, jnp.int32(D))   # dead rows -> bucket D
-        ns = D + 1
 
-        occupied = jax.ops.segment_max(live.astype(jnp.int32), seg,
-                                       num_segments=ns)[:D] > 0
+        if masked:
+            # a dead row's D matches no bucket: no slot for it
+            hit = seg[None, :] == jnp.arange(D, dtype=jnp.int32)[:, None]
+            reduce_to = _masked_reducer(hit)
+            occupied = jnp.any(hit, axis=1)
+        else:
+            ns = D + 1
+            reduce_to = _scatter_reducer(seg, ns)
+            occupied = jax.ops.segment_max(live.astype(jnp.int32), seg,
+                                           num_segments=ns)[:D] > 0
         num_groups = jnp.sum(occupied, dtype=jnp.int32)
         # compact occupied buckets to the front, stably (bucket order)
         order = jnp.argsort(jnp.where(occupied, jnp.int32(0),
                                       jnp.int32(1)), stable=True)
         group_live = jnp.arange(D, dtype=jnp.int32) < num_groups
+
+        def reindex(a):
+            return a[:D][order]
+
+        def bucket(lane, ident, op):
+            return reindex(reduce_to(lane, ident, op))
 
         out_keys = []
         for size, stride, kd in zip(domain_sizes, strides, keys):
@@ -723,8 +814,19 @@ def dense_groupby_trace(domain_sizes, agg_specs, capacity):
                 d, v = None, live
             vl = (v & live) if d is not None else live
             spec_vls.append((d, vl))
-        sum_of = _batched_sums(agg_specs, spec_vls, live, seg, ns,
-                               lambda a: a[:D][order])
+        if masked:
+            def sum_lanes(lanes):
+                # an int32 count lane widens to the stack's int64
+                return [bucket(lane, 0, "sum").astype(
+                    jnp.result_type(lane.dtype, jnp.int64))
+                    for lane in lanes].__getitem__
+        else:
+            def sum_lanes(lanes):
+                out = reindex(jax.ops.segment_sum(
+                    jnp.stack(lanes, axis=1), seg, num_segments=ns))
+                return lambda j: out[:, j]
+        sum_of = _batched_sums(agg_specs, spec_vls, live, sum_lanes,
+                               jnp.int32 if masked else jnp.int64)
 
         outs = []
         for si, spec in enumerate(agg_specs):
@@ -740,51 +842,43 @@ def dense_groupby_trace(domain_sizes, agg_specs, capacity):
                 data = sum_of(("sum", si), t.is_floating(dt))
             elif spec.kind in (MIN, MAX):
                 is_min = spec.kind == MIN
+                op = "min" if is_min else "max"
                 if isinstance(dt, t.DoubleType) and d.dtype == jnp.int64:
                     o = _bits_total_order(d)
                     ident = jnp.int64(_ORDER_MAX if is_min else _ORDER_MIN)
                     o = jnp.where(vl, o, ident)
-                    red = (jax.ops.segment_min if is_min
-                           else jax.ops.segment_max)(
-                        o, seg, num_segments=ns)[:D][order]
-                    data = _bits_from_order(red)
+                    data = _bits_from_order(bucket(o, ident, op))
                 elif t.is_floating(dt):
-                    data = _segment_minmax_float(cd, vl, seg, ns,
-                                                 is_min)[:D][order]
+                    data = reindex(_segment_minmax_float(cd, vl, reduce_to,
+                                                         is_min))
                 else:
                     if isinstance(dt, t.BooleanType):
                         ident = jnp.asarray(is_min)
-                        acc = cd
                     else:
                         info = np.iinfo(np.dtype(cd.dtype))
                         ident = jnp.asarray(info.max if is_min
                                             else info.min, cd.dtype)
-                        acc = cd
-                    acc = jnp.where(vl, acc, ident)
-                    data = (jax.ops.segment_min if is_min
-                            else jax.ops.segment_max)(
-                        acc, seg, num_segments=ns)[:D][order]
+                    data = bucket(jnp.where(vl, cd, ident), ident, op)
             elif spec.kind in (FIRST, LAST, FIRST_NN, LAST_NN):
                 idx = jnp.arange(capacity, dtype=jnp.int32)
                 is_first = spec.kind in (FIRST, FIRST_NN)
-                sel = vl if spec.kind in (FIRST_NN, LAST_NN) else live
-                masked = jnp.where(sel, idx,
-                                   jnp.int32(capacity) if is_first
-                                   else jnp.int32(-1))
-                pick = (jax.ops.segment_min if is_first
-                        else jax.ops.segment_max)(
-                    masked, seg, num_segments=ns)[:D][order]
+                ignore_nulls = spec.kind in (FIRST_NN, LAST_NN)
+                ident = jnp.int32(capacity) if is_first else jnp.int32(-1)
+                pick = bucket(jnp.where(vl if ignore_nulls else live, idx,
+                                        ident),
+                              ident, "min" if is_first else "max")
                 pick = jnp.clip(pick, 0, capacity - 1)
                 data = cd[pick]
-                out_valid = vl[pick] & group_live
+                # ignore-nulls: a bucket with no valid row picks out of
+                # range, clipped onto another bucket's row
+                out_valid = vl[pick] & (out_valid if ignore_nulls
+                                        else group_live)
             elif spec.kind == ANY:
-                data = jax.ops.segment_max(
-                    jnp.where(vl, cd, False).astype(jnp.int8), seg,
-                    num_segments=ns)[:D][order] > 0
+                data = bucket(jnp.where(vl, cd, False).astype(jnp.int8),
+                              0, "max") > 0
             elif spec.kind == EVERY:
-                data = jax.ops.segment_min(
-                    jnp.where(vl, cd, True).astype(jnp.int8), seg,
-                    num_segments=ns)[:D][order] > 0
+                data = bucket(jnp.where(vl, cd, True).astype(jnp.int8),
+                              1, "min") > 0
             else:
                 raise ValueError(f"unknown agg kind {spec.kind}")
             outs.append((data, out_valid))

@@ -323,7 +323,9 @@ def pallas_groupby_trace(pack_spec, key_lanes_info, agg_specs,
                                           capacity if is_first else -1),
                                 0, capacity - 1)
                 data = cd[pick]
-                out_valid = vl[pick] & group_live
+                # no valid row: the pick is out of range, clipped onto
+                # another group's row
+                out_valid = vl[pick] & out_valid
             elif spec.kind == G.ANY:
                 data = reduce_of(jnp.where(vl, cd, False).astype(
                     jnp.int8), False, np.int8(0)) > 0

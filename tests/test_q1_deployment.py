@@ -10,6 +10,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from spark_rapids_tpu.ops.groupby import MASKED_DOMAIN_MAX
 from spark_rapids_tpu.plan.aggregates import Count, Sum
 from spark_rapids_tpu.session import TpuSession, col
 
@@ -71,7 +72,9 @@ def test_q1_in_many_batches_is_exact_and_counts_what_the_plan_says(bench):
         assert m["exec_dispatches"] == 2
         counts = _agg_counts(m)
         assert counts.pop("agg.capacity_rows") >= rows
+        # 12 buckets: every dense program is the one without scatters
         assert counts == {"agg.strategy.dense": batches + 1,
+                          "agg.strategy.dense_masked": batches + 1,
                           "agg.partial_batches": batches}
 
 
@@ -83,7 +86,8 @@ def _keyed(s, table, keys):
 
 @pytest.mark.parametrize("engine", ["eager", "whole_plan"])
 @pytest.mark.parametrize("strategy,keys", [
-    ("dense", ["s"]),                # a small dictionary: 12 buckets
+    ("dense_masked", ["s"]),         # a small dictionary: 4 buckets
+    ("dense", ["w"]),                # a domain to scatter into
     ("packed_sort", ["i", "j"]),     # ranged integers: one packed lane
     ("lexsort", ["i", "d"]),         # a double key packs into nothing
     ("reduce", [])])
@@ -93,6 +97,8 @@ def test_each_strategy_bumps_its_own_key_and_no_other(engine, strategy,
     n = 3000
     table = pa.table({
         "s": pa.array(rng.choice(["a", "b", "c"], n)),
+        # one word more than ops/groupby.py reduces under bucket masks
+        "w": pa.array([f"w{i % (MASKED_DOMAIN_MAX + 1)}" for i in range(n)]),
         "i": pa.array(rng.integers(0, 50, n), pa.int64()),
         "j": pa.array(rng.integers(-5, 5, n), pa.int64()),
         "d": pa.array(rng.integers(0, 9, n).astype(np.float64)),
@@ -104,6 +110,8 @@ def test_each_strategy_bumps_its_own_key_and_no_other(engine, strategy,
             "agg.partial_batches": 1}
     if strategy in ("packed_sort", "lexsort"):
         want["agg.strategy.sorted"] = 1
+    if strategy == "dense_masked":   # counted as a dense program too
+        want["agg.strategy.dense"] = 1
     assert _agg_counts(df.metrics()) == want
     df.collect()                     # counted per run, not per compile
     assert _agg_counts(df.metrics()) == want
@@ -143,13 +151,41 @@ def test_manifest_of_the_q1_cells(bench, cell, config, trace_queries):
     assert not any(name.startswith("wall_ms.") for name in applied)
     assert {"xla_programs_roofline", "device_idle_pct",
             "peak_hbm_GB"} <= set(applied)
-    for name, key in (("agg_sort_strategies_per_query",
+    for name, key in (("agg_masked_dense_per_query",
+                       "agg.strategy.dense_masked"),
+                      ("agg_sort_strategies_per_query",
                        "agg.strategy.sorted"),
                       ("agg_partial_batches_per_query",
                        "agg.partial_batches"),
                       ("agg_capacity_rows_per_query", "agg.capacity_rows")):
         assert (applied[name]["reader"], applied[name]["key"],
                 applied[name]["per"]) == ("ctx_metric", key, "query")
+
+
+def test_the_masked_dense_metric_and_its_manifest_entry_agree(bench):
+    """ISSUE 28's one per-layer metric: data only, applied in every cell
+    (no `workloads` list), reading the key that `HashAggregate._note`
+    bumps."""
+    import json
+    entry = [m for m in bench.manifest.benchmark()["per_layer"]
+             if m["name"] == "agg_masked_dense_per_query"]
+    assert entry == [{"name": "agg_masked_dense_per_query",
+                      "unit": "count", "better": "higher",
+                      "source": "program_counter",
+                      "layer": "XLA programs (kernels)",
+                      "moves": "query_ms"}]
+    with open(os.path.join(_BENCH, "layer_metrics",
+                           "agg_masked_dense_per_query.json")) as f:
+        spec = json.load(f)
+    assert {k: spec[k] for k in ("reader", "key", "per", "unit", "layer")} \
+        == {"reader": "ctx_metric", "key": "agg.strategy.dense_masked",
+            "per": "query", "unit": entry[0]["unit"],
+            "layer": entry[0]["layer"]}
+    for cell in ("tpch-sf1.joins", "tpch-sf10.q6", "tpch-sf1.groupby",
+                 "tpch-sf10.q1"):
+        assert "agg_masked_dense_per_query" in [
+            name for name, _spec, _reader in
+            bench.manifest.Cell(cell).per_layer]
 
 
 def test_the_q1_configuration_is_its_own_deployment(bench):

@@ -81,6 +81,47 @@ def test_dense_via_sort_makes_whole_suite_scatter_free(tables):
         assert st["sort_operand_max"] <= 2, (name, st)
 
 
+def test_small_domain_dense_groupby_lowers_without_scatter(suite_stats,
+                                                            monkeypatch):
+    """Q1's dense program ((3, 2): 12 buckets with the null slots, its
+    own update specs) is bucket-masked reductions: no scatter in the
+    lowered text, nor in the whole-plan program; the same specs over a
+    domain above ops/groupby.py MASKED_DOMAIN_MAX still scatter."""
+    import jax
+    from spark_rapids_tpu.ops import groupby as G
+    seen = []
+    real = G.dense_groupby_trace
+
+    def spy(domain_sizes, agg_specs, capacity):
+        fn = real(domain_sizes, agg_specs, capacity)
+
+        def run(*args):
+            seen.append((tuple(domain_sizes), list(agg_specs), capacity,
+                         jax.tree_util.tree_map(
+                             lambda x: jax.ShapeDtypeStruct(x.shape,
+                                                            x.dtype),
+                             args)))
+            return fn(*args)
+        return run
+
+    monkeypatch.setattr(G, "dense_groupby_trace", spy)
+    # a capacity that no other test of this module traces: the spy sees
+    # a trace, not a cached program
+    q = tpch.QUERIES["q1"](TpuSession(),
+                           tpch.gen_tables(scale=0.005)).physical()
+    assert plan_program_stats(q)["scatter_op_count"] == 0
+    assert suite_stats["q1"]["scatter_op_count"] == 0
+    domains, specs, capacity, shapes = seen[0]      # the update program
+    assert domains == (3, 2) and G.dense_is_masked(domains)
+    assert len(specs) == 11
+    text = jax.jit(real(domains, specs, capacity)).lower(*shapes).as_text()
+    assert "scatter" not in text
+    wide = (G.MASKED_DOMAIN_MAX, 2)
+    assert not G.dense_is_masked(wide)
+    text = jax.jit(real(wide, specs, capacity)).lower(*shapes).as_text()
+    assert "scatter" in text
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel-tier sort budget: the hash/accumulate kernels must keep
 # removing sorts from the join/agg-heavy tail (ISSUE 11)
