@@ -268,8 +268,7 @@ class Suite:
 
 
 #: --conf key=value session overrides (applied to the DEVICE session
-#: only; the CPU oracle baseline never sees them) — how the committed
-#: kernel-tier bench rounds flip spark.rapids.tpu.sql.kernels.pallas.*
+#: only; the CPU oracle baseline never sees them)
 EXTRA_CONF = {}
 
 
@@ -478,187 +477,6 @@ def run_compile_only(suite_name: str, scale: float, query_names):
            "final": True}
     print(json.dumps(out), flush=True)
     return not any("error" in v for v in per_q.values())
-
-
-#: --kernels microbench sizes (rows) and skew levels
-KERNEL_SIZES = {"256k": 1 << 18, "1m": 1 << 20, "4m": 1 << 22}
-KERNEL_SKEWS = ("uniform", "skewed")
-
-
-def run_kernels():
-    """--kernels: Pallas-vs-sorted A/B microbenchmarks of the three
-    kernel families (ISSUE 11) at 3 sizes x 2 skew levels, emitting
-    `kernel_timings_ms` entries scripts/check_regression.py gates under
-    the `kn:` prefix (same backend-separation rule as qN device_ms).
-
-    Shapes: probe = hash-probe join primitive (build table + aligned
-    probe of N rows against an N/8-row build side) and counts = the
-    same with the duplicate-run count probe, vs the sorted-lane
-    merge-rank probe; segagg = 32-bucket segmented int64 sums (the
-    block-accumulate matmul kernel vs jax.ops.segment_sum) and segmin
-    = the same buckets' min (the masked one-hot reduction vs
-    segment_min); compact = 10%-selectivity compaction order (rank
-    search vs keep-mask argsort).  'skewed' concentrates 90% of
-    probe/segment rows on 1% of the key space — the collision/
-    hot-bucket regime.  Pallas kernels run interpreted off-TPU (the
-    same discharged bodies the query path dispatches).  On a TPU they
-    compile natively through Mosaic: `kernel_lowering` records, per
-    kernel and size, "lowers" or "does not lower: <the compiler's
-    message>"; a kernel that does not lower has no timing and fails
-    the run's exit code."""
-    import numpy as np
-    import jax.numpy as jnp
-    from spark_rapids_tpu.ops.join import _merge_rank
-    from spark_rapids_tpu.ops.pallas import hashjoin as HK
-    from spark_rapids_tpu.ops.pallas.compact import \
-        compaction_order as pallas_order
-    from spark_rapids_tpu.ops.pallas.segagg import (_seg_matmul_sums,
-                                                    _seg_reduce)
-    from spark_rapids_tpu.ops.filter import compaction_order
-    interpret = jax.default_backend() != "tpu"
-    rng = np.random.default_rng(17)
-    out = {}
-    lowering = {}
-
-    def timed(name, fn):
-        jax.block_until_ready(fn())                      # compile+warm
-        times = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            times.append(time.perf_counter() - t0)
-        out[name] = round(min(times) * 1e3, 2)
-        print(f"# {name}: {out[name]}ms", file=sys.stderr)
-
-    def lowers(name, fn) -> bool:
-        """The verdict boundary: a kernel the compiler refuses is
-        RECORDED with its message (that is this mode's result) and the
-        other kernels still get theirs."""
-        try:
-            jax.block_until_ready(fn())
-            lowering[name] = "lowers"
-        except Exception as e:                       # noqa: BLE001
-            msg = " ".join(f"{type(e).__name__}: {e}".split())
-            lowering[name] = f"does not lower: {msg[:600]}"
-        print(f"# {name}: {lowering[name]}", file=sys.stderr)
-        return lowering[name] == "lowers"
-
-    i64max = np.iinfo(np.int64).max
-    for sname, n in KERNEL_SIZES.items():
-        if left() < 60:
-            print(f"# budget: skipping kernel size {sname}",
-                  file=sys.stderr)
-            continue
-        b = n // 8
-        for skew in KERNEL_SKEWS:
-            if skew == "uniform":
-                pk = rng.integers(0, b, n)
-            else:
-                hot = rng.integers(0, max(b // 100, 1), n)
-                cold = rng.integers(0, b, n)
-                pk = np.where(rng.random(n) < 0.9, hot, cold)
-            bkeys = jnp.asarray(np.arange(b) * 7 + 3, jnp.int64)
-            pkeys = jnp.asarray(pk * 7 + 3, jnp.int64)
-            bvalid = jnp.ones((b,), bool)
-            pvalid = jnp.ones((n,), bool)
-            seg = jnp.asarray(pk % 32, jnp.int32)
-            lanes = [jnp.asarray(rng.integers(-(10 ** 12), 10 ** 12, n),
-                                 jnp.int64) for _ in range(4)]
-            keep = jnp.asarray(rng.random(n) < 0.1)
-
-            def probe_pallas():
-                tbl = HK.build_table(bkeys, bvalid, interpret)
-                return HK.probe_first(tbl, pkeys, pvalid)
-
-            def counts_pallas():
-                tbl = HK.build_table(bkeys, bvalid, interpret)
-                return HK.probe_counts(tbl, pkeys, pvalid)
-
-            def segagg_pallas():
-                return _seg_matmul_sums(seg, lanes, [], 32, n, interpret)
-
-            def segmin_pallas():
-                return _seg_reduce(seg, lanes[0], 32, n, True, i64max,
-                                   interpret)
-
-            if skew == KERNEL_SKEWS[0]:
-                # one verdict per kernel per size (skew moves no shape).
-                # The probes get a table even where the native build
-                # does not lower: built by the interpreted body.
-                tbl = HK.build_table(bkeys, bvalid, True)._replace(
-                    interpret=interpret)
-                ok = {
-                    "build": lowers(f"hash_build_{sname}", lambda:
-                                    HK.build_table(bkeys, bvalid,
-                                                   interpret)[:2]),
-                    "first": lowers(f"probe_first_{sname}", lambda:
-                                    HK.probe_first(tbl, pkeys, pvalid)),
-                    "counts": lowers(f"probe_counts_{sname}", lambda:
-                                     HK.probe_counts(tbl, pkeys, pvalid)),
-                    "sums": lowers(f"segagg_sums_{sname}", segagg_pallas),
-                    "reduce": lowers(f"segagg_reduce_{sname}",
-                                     segmin_pallas),
-                    "compact": lowers(f"compact_{sname}", lambda:
-                                      pallas_order(keep, interpret)),
-                }
-
-            @jax.jit
-            def probe_sorted(bkeys, pkeys):
-                sh = jnp.sort(HK.mix64(bkeys))
-                return _merge_rank(sh, HK.mix64(pkeys), side="left")
-
-            if ok["build"] and ok["first"]:
-                timed(f"probe_{sname}_{skew}_pallas", probe_pallas)
-            if ok["build"] and ok["counts"]:
-                timed(f"counts_{sname}_{skew}_pallas", counts_pallas)
-            timed(f"probe_{sname}_{skew}_sorted",
-                  lambda: probe_sorted(bkeys, pkeys))
-
-            @jax.jit
-            def segagg_scatter(seg, stacked):
-                return jax.ops.segment_sum(stacked, seg, num_segments=32)
-            stacked = jnp.stack(lanes, axis=1)
-            if ok["sums"]:
-                timed(f"segagg_{sname}_{skew}_pallas", segagg_pallas)
-            timed(f"segagg_{sname}_{skew}_scatter",
-                  lambda: segagg_scatter(seg, stacked))
-
-            @jax.jit
-            def segmin_scatter(seg, lane):
-                return jax.ops.segment_min(lane, seg, num_segments=32)
-            if ok["reduce"]:
-                timed(f"segmin_{sname}_{skew}_pallas", segmin_pallas)
-            timed(f"segmin_{sname}_{skew}_scatter",
-                  lambda: segmin_scatter(seg, lanes[0]))
-
-            if ok["compact"]:
-                timed(f"compact_{sname}_{skew}_pallas",
-                      lambda: pallas_order(keep, interpret))
-            timed(f"compact_{sname}_{skew}_sorted",
-                  lambda: compaction_order(keep))
-
-    ratios = {}
-    for k in sorted(out):
-        if k.endswith("_pallas"):
-            base = out.get(k.replace("_pallas", "_sorted"),
-                           out.get(k.replace("_pallas", "_scatter")))
-            if base:
-                ratios[k[:-7]] = round(out[k] / base, 3)
-    print(json.dumps({
-        "mode": "kernels",
-        "metric": "kernel_microbench_pallas_vs_sorted",
-        "value": round(float(np.exp(np.mean(np.log(
-            [max(r, 1e-6) for r in ratios.values()])))), 3)
-        if ratios else None,
-        "unit": "x (pallas/sorted, lower is better)",
-        "backend": jax.default_backend(),
-        "interpret": interpret,
-        "kernel_timings_ms": out,
-        "kernel_lowering": lowering,
-        "pallas_over_sorted_ratio": ratios,
-        "elapsed_s": round(time.perf_counter() - _T0, 1),
-        "final": True}), flush=True)
-    return all(v == "lowers" for v in lowering.values())
 
 
 #: --encodings microbench sizes (rows) and selectivities
@@ -1284,7 +1102,6 @@ def main():
     suite_name = "tpch"
     compile_only = False
     serving = False
-    kernels = False
     encodings = False
     ooc = False
     multichip = False
@@ -1301,8 +1118,6 @@ def main():
                 kv = args[i]
             k, _, v = kv.partition("=")
             EXTRA_CONF[k] = v
-        elif a == "--kernels":
-            kernels = True
         elif a == "--encodings":
             encodings = True
         elif a == "--ooc":
@@ -1364,9 +1179,6 @@ def main():
     query_names = names or sorted(workload.QUERIES,
                                   key=lambda q: int(q[1:]))
 
-    if kernels:
-        # Pallas-vs-sorted kernel microbench A/B (KERNELS_r*.json)
-        return run_kernels()
     if encodings:
         # encoded-vs-decode-first microbench A/B (ENCODINGS_r*.json)
         run_encodings()

@@ -138,49 +138,13 @@ def extract_serving(doc):
     return {}, None
 
 
-def extract_kernels(doc):
-    """-> ({'kn:<entry>': ms}, backend or None) from a bench.py
-    --kernels result: the `kernel_timings_ms` A/B dict (pallas vs
-    sorted per kernel family / size / skew, lower = better) becomes
-    `kn:`-prefixed entries that gate like per-query device_ms under
-    the same backend-separation rule (never colliding with qN / mc: /
-    sv: names).  Accepts the runner's JSON line, the driver wrapper,
-    and a tail."""
-    if not isinstance(doc, dict):
-        return {}, None
-    tim = doc.get("kernel_timings_ms")
-    if isinstance(tim, dict) and tim:
-        out = {f"kn:{k}": float(v) for k, v in tim.items()
-               if isinstance(v, (int, float))}
-        return out, doc.get("backend")
-    parsed = doc.get("parsed")
-    if isinstance(parsed, dict):
-        out, backend = extract_kernels(parsed)
-        if out:
-            return out, backend
-    tail = doc.get("tail")
-    if isinstance(tail, str) and "kernel_timings_ms" in tail:
-        for line in reversed(tail.splitlines()):
-            if "kernel_timings_ms" not in line:
-                continue
-            try:
-                rec = json.loads(line.strip())
-            except json.JSONDecodeError:
-                continue
-            if isinstance(rec, dict):
-                out, backend = extract_kernels(rec)
-                if out:
-                    return out, backend
-    return {}, None
-
-
 def extract_encodings(doc):
     """-> ({'en:<entry>': ms}, backend or None) from a bench.py
     --encodings result: the `encoding_timings_ms` A/B dict (encoded vs
     decode-first per encoding family / operator / selectivity, lower =
     better) becomes `en:`-prefixed entries that gate like per-query
     device_ms under the same backend-separation rule (never colliding
-    with qN / mc: / sv: / kn: names).  Accepts the runner's JSON line,
+    with qN / mc: / sv: names).  Accepts the runner's JSON line,
     the driver wrapper, and a tail."""
     if not isinstance(doc, dict):
         return {}, None
@@ -216,7 +180,7 @@ def extract_ooc(doc):
     through the out-of-core tier, {qN}_uncapped = the resident
     baseline, lower = better) becomes `oc:`-prefixed entries that gate
     like per-query device_ms under the same backend-separation rule
-    (never colliding with qN / mc: / sv: / kn: / en: names).  Accepts
+    (never colliding with qN / mc: / sv: / en: names).  Accepts
     the runner's JSON line, the driver wrapper, and a tail."""
     if not isinstance(doc, dict):
         return {}, None
@@ -427,12 +391,6 @@ def load_file(path: str):
         # serving record carries its own backend tag
         qs = {**qs, **sv}
         backend = backend or sv_backend
-    kn, kn_backend = extract_kernels(doc)
-    if kn:
-        # kernel-microbench entries gate under their kn: prefix; a pure
-        # kernels record carries its own backend tag
-        qs = {**qs, **kn}
-        backend = backend or kn_backend
     en, en_backend = extract_encodings(doc)
     if en:
         # encoded-execution microbench entries gate under their en:
@@ -487,7 +445,6 @@ def default_trajectory() -> list:
     return (sorted(glob.glob(os.path.join(_ROOT, "BENCH_r*.json"))) +
             sorted(glob.glob(os.path.join(_ROOT, "MULTICHIP_r*.json"))) +
             sorted(glob.glob(os.path.join(_ROOT, "SERVING_r*.json"))) +
-            sorted(glob.glob(os.path.join(_ROOT, "KERNELS_r*.json"))) +
             sorted(glob.glob(os.path.join(_ROOT, "ENCODINGS_r*.json"))) +
             sorted(glob.glob(os.path.join(_ROOT, "OOC_r*.json"))))
 

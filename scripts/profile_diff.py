@@ -77,9 +77,9 @@ def _eventlog_families(path: str) -> dict:
 
 def _bench_families(path: str) -> dict:
     from check_regression import (extract_compile_ms, extract_hbm,
-                                  extract_kernels, extract_multichip,
-                                  extract_overheads, extract_queries,
-                                  extract_segments, extract_serving)
+                                  extract_multichip, extract_overheads,
+                                  extract_queries, extract_segments,
+                                  extract_serving)
     with open(path) as f:
         doc = json.load(f)
     fams = {}
@@ -88,12 +88,9 @@ def _bench_families(path: str) -> dict:
     queries = {**qs, **mc}
     if queries:
         fams["queries"] = queries
-    # kernel A/B (KERNELS_r*.json kn: entries) and serving-latency
-    # (SERVING_r*.json sv: entries) records diff with the same tool —
-    # the regression gate already mines them, so reuse its extractors
-    kn, _ = extract_kernels(doc)
-    if kn:
-        fams["kernels"] = kn
+    # serving-latency (SERVING_r*.json sv: entries) records diff with
+    # the same tool — the regression gate already mines them, so reuse
+    # its extractor
     sv, _ = extract_serving(doc)
     if sv:
         fams["serving"] = sv
@@ -192,12 +189,11 @@ def render(res: dict, name_a: str, name_b: str, top: int) -> str:
 def self_test() -> int:
     """Built-in proof the diff works end to end: (1) a synthetic A/B
     orders regressions and improvements correctly; (2) a synthetic
-    event-log pair diffs per segment; (3) kernel (kn:) and serving
-    (sv:) records load and diff as their own families; (3c) a
-    seam-elimination win surfaces in the overhead family; (4) the
-    committed MULTICHIP trajectory reproduces the PR 8 fused-groupby
-    win (119.4s -> 11.1s) as an `mc:`-keyed improvement, and the
-    committed KERNELS record loads as a kernels family."""
+    event-log pair diffs per segment; (3) serving (sv:) records load
+    and diff as their own family; (3c) a seam-elimination win surfaces
+    in the overhead family; (4) the committed MULTICHIP trajectory
+    reproduces the PR 8 fused-groupby win (119.4s -> 11.1s) as an
+    `mc:`-keyed improvement."""
     import tempfile
     # 1: synthetic family diff
     a = {"segments": {"agg": 100.0, "join": 500.0, "sort": 50.0}}
@@ -230,22 +226,18 @@ def self_test() -> int:
         imp = res["segments"]["improved"]
         assert imp and imp[0]["entry"] == "HashJoinExec#1", res
 
-    # 3: kernel + serving records diff through the same loader (the
-    # kn:/sv: families the regression gate mines)
-    def kn_sv_doc(probe_ms, p99_ms):
+    # 3: serving records diff through the same loader (the sv: family
+    # the regression gate mines)
+    def sv_doc(p99_ms):
         return {"backend": "cpu",
-                "kernel_timings_ms": {"probe_1m_pallas": probe_ms,
-                                      "compact_1m_pallas": 40.0},
                 "serving_latency_ms": {"c8_p99": p99_ms,
                                        "c8_mean": p99_ms / 2.0}}
     with tempfile.TemporaryDirectory() as td:
-        ka = os.path.join(td, "KERNELS_a.json")
-        kb = os.path.join(td, "KERNELS_b.json")
-        json.dump(kn_sv_doc(100.0, 800.0), open(ka, "w"))
-        json.dump(kn_sv_doc(30.0, 2400.0), open(kb, "w"))
-        res = diff_families(load_families(ka), load_families(kb))
-        assert res["kernels"]["improved"][0]["entry"] == \
-            "kn:probe_1m_pallas", res["kernels"]
+        va = os.path.join(td, "SERVING_a.json")
+        vb = os.path.join(td, "SERVING_b.json")
+        json.dump(sv_doc(800.0), open(va, "w"))
+        json.dump(sv_doc(2400.0), open(vb, "w"))
+        res = diff_families(load_families(va), load_families(vb))
         assert res["serving"]["regressed"][0]["entry"] == "sv:c8_p99", \
             res["serving"]
         assert abs(res["serving"]["regressed"][0]["ratio"] - 3.0) < 1e-9
@@ -302,11 +294,6 @@ def self_test() -> int:
     else:
         print("# self-test: committed MULTICHIP records absent, "
               "trajectory leg skipped", file=sys.stderr)
-    r11 = os.path.join(_ROOT, "KERNELS_r11.json")
-    if os.path.exists(r11):
-        fams = load_families(r11)
-        assert fams.get("kernels"), "KERNELS_r11 yields no kn: family"
-        assert all(k.startswith("kn:") for k in fams["kernels"])
     print("profile_diff self-test OK")
     return 0
 

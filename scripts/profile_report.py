@@ -80,90 +80,6 @@ def render_mesh_timeline(tl: dict, indent: str = "  ") -> list:
     return lines
 
 
-def kernel_section(registry: dict) -> list:
-    """Rendered lines for the Pallas kernel-tier metric families
-    (`tpu_kernel_dispatch_total` / `tpu_kernel_fallback_total`) found
-    in a compact registry snapshot — PR 11 added the metrics; this is
-    the offline report that surfaces them."""
-    disp = {k: v for k, v in (registry or {}).items()
-            if k.startswith("tpu_kernel_dispatch_total")}
-    fb = {k: v for k, v in (registry or {}).items()
-          if k.startswith("tpu_kernel_fallback_total")}
-    if not disp and not fb:
-        return []
-    lines = ["-- kernel tier (Pallas dispatch/fallback) --"]
-    for k, v in sorted(disp.items(), key=lambda kv: -kv[1]):
-        lines.append(f"  dispatch {k.split('{', 1)[-1].rstrip('}'):<40}"
-                     f" {v}")
-    for k, v in sorted(fb.items(), key=lambda kv: -kv[1]):
-        lines.append(f"  fallback {k.split('{', 1)[-1].rstrip('}'):<40}"
-                     f" {v}")
-    return lines
-
-
-def kernel_plan_section(meta: dict) -> list:
-    """Rendered per-query kernel-tier DECISIONS (PhysicalQuery.
-    kernel_plan(), embedded in the event-log meta when tracing is on
-    and the tier resolved): which operator elected which kernel and
-    why the sorted tier kept the rest."""
-    kp = (meta or {}).get("kernel_plan")
-    if not kp:
-        return []
-    return ["-- kernel tier decisions (this query) --"] + \
-        [f"  {line}" for line in kp]
-
-
-def try_bench_record(path: str):
-    """Parse a .json file as a bench final record (no multichip
-    timings) -> (per-suite query dict, full doc) or (None, None)."""
-    if path.endswith(".jsonl"):
-        return None, None
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return None, None
-    inner = doc.get("parsed") if isinstance(doc.get("parsed"), dict) \
-        else doc
-    if not isinstance(inner, dict):
-        return None, None
-    suites = {k: v for k, v in inner.items()
-              if k.endswith("_suite_queries") and isinstance(v, dict)}
-    if not suites and not inner.get("kernel_timings_ms"):
-        return None, None
-    return suites, inner
-
-
-def render_bench(path: str, suites: dict, inner: dict,
-                 as_json: bool) -> None:
-    reg = inner.get("registry") if isinstance(inner.get("registry"),
-                                              dict) else {}
-    if as_json:
-        out = {"log": path,
-               "kernel_metrics": {k: v for k, v in reg.items()
-                                  if k.startswith("tpu_kernel_")}}
-        if inner.get("kernel_timings_ms"):
-            out["kernel_timings_ms"] = inner["kernel_timings_ms"]
-        print(json.dumps(out))
-        return
-    print(f"### {path}")
-    print("== bench record ==")
-    meta = [f"{k}={inner[k]}" for k in ("backend", "suite",
-                                        "queries_measured") if k in inner]
-    if meta:
-        print("  " + " ".join(meta))
-    tim = inner.get("kernel_timings_ms")
-    if isinstance(tim, dict):
-        print("-- kernel A/B timings (pallas vs sorted) --")
-        for k in sorted(tim):
-            print(f"  {k:<44} {tim[k]:>10.1f} ms")
-    for line in kernel_section(reg):
-        print(line)
-    if not tim and not kernel_section(reg):
-        print("  (no kernel-tier data in this record)")
-    print()
-
-
 def try_heartbeat_log(path: str):
     """Parse a .jsonl file as a metrics-heartbeat log (obs/export.py
     Heartbeat) -> list of beat records, or None.  A supervised pool
@@ -299,12 +215,6 @@ def main(argv=None) -> int:
         if mc:
             render_multichip(path, mc, doc, args.mesh, args.json)
             continue
-        # bench final records (incl. --kernels A/B rounds): the suite
-        # summary + the tpu_kernel_* dispatch/fallback families
-        suites, inner = try_bench_record(path)
-        if inner is not None:
-            render_bench(path, suites, inner, args.json)
-            continue
         # pool heartbeat logs (supervisor + per-worker) share the dir
         # with query event logs; render their fleet summary instead of
         # failing them through the query-profile path
@@ -338,17 +248,10 @@ def main(argv=None) -> int:
                 print()
             continue
         if args.json:
-            out = {"log": path, **prof.to_dict()}
-            if prof.meta.get("kernel_plan"):
-                out["kernel_plan"] = prof.meta["kernel_plan"]
-            print(json.dumps(out))
+            print(json.dumps({"log": path, **prof.to_dict()}))
         else:
             print(f"### {path}")
             print(prof.render())
-            for line in kernel_plan_section(prof.meta):
-                print(line)
-            for line in kernel_section(prof.registry):
-                print(line)
             if args.mesh:
                 tl = prof.mesh_timeline()
                 if tl["exchanges"] or tl["skew_splits"]:

@@ -3,8 +3,8 @@
 A from-scratch framework with the capabilities of NVIDIA's RAPIDS Accelerator
 for Apache Spark (reference: /root/reference, v24.06.0-SNAPSHOT), re-designed
 for TPU hardware: JAX/XLA for the compute path (jit-traced expression trees,
-static-shape bucketed columnar batches, sort/segment-based aggregation,
-Pallas kernels for hot ops), `jax.sharding.Mesh` + shard_map collectives for
+static-shape bucketed columnar batches, sort/segment-based
+aggregation), `jax.sharding.Mesh` + shard_map collectives for
 distributed exchange, Arrow as the host/wire columnar format.
 
 Layer map (mirrors SURVEY.md §1):
@@ -15,7 +15,7 @@ Layer map (mirrors SURVEY.md §1):
   io/        - parquet/csv/json scans + writers (ref L5)
   shuffle/   - partitioners + multithreaded host shuffle + ICI exchange (ref L6)
   parallel/  - mesh management, distributed query steps (ref §2.10)
-  ops/       - the kernel library: the cuDF/JNI role, played by jnp/Pallas (ref L0)
+  ops/       - the kernel library: the cuDF/JNI role, played by jnp (ref L0)
 """
 
 __version__ = "0.1.0"

@@ -308,7 +308,6 @@ def materialize_needed(db: DeviceBatch, exprs, conf: TpuConf = DEFAULT_CONF
 
 
 def compact_thin(db: DeviceBatch, keep: jax.Array,
-                 conf: TpuConf = DEFAULT_CONF,
                  out_capacity: Optional[int] = None) -> DeviceBatch:
     """Compact a THIN batch: materialized columns move through the
     compaction order as usual; each deferred column is gathered ONCE,
@@ -318,13 +317,10 @@ def compact_thin(db: DeviceBatch, keep: jax.Array,
     `out_capacity` cuts the compaction order to its first rows, so every
     gather below runs at that capacity: for a caller that knows the kept
     rows fit (a split-plan seam, after its row-count sync)."""
-    from ..ops.filter import (compaction_order, grouped_take,
-                              pallas_compact_order)
+    from ..ops.filter import compaction_order, grouped_take
     ts = db.thin
     assert ts is not None
-    order = pallas_compact_order(keep, conf)
-    if order is None:
-        order = compaction_order(keep)
+    order = compaction_order(keep)
     count = jnp.sum(keep, dtype=jnp.int32)
     if out_capacity is not None:
         order = order[:out_capacity]

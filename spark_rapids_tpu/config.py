@@ -674,14 +674,6 @@ MAX_SORT_OPERANDS = conf(
     "backends whose sort compile is cheap.",
     checker=lambda v: None if v >= 2 else "must be >= 2")
 
-DENSE_AGG_VIA_SORT = conf(
-    "spark.rapids.tpu.sql.agg.denseDomainViaSort", False,
-    "Route bounded-domain group-bys (dictionary/boolean keys) through "
-    "the packed single-sort-lane kernel instead of the no-sort dense "
-    "bucket scatters — trades one cheap 2-operand sort (~5ms/1M) for "
-    "the direct segment scatters (~70ms/1M). Off keeps the dense "
-    "no-sort path; each is flip-testable against the other.")
-
 JOIN_DENSE_BUILD_VIA_SORT = conf(
     "spark.rapids.tpu.sql.join.denseBuildViaSort", True,
     "Build dense join direct-address tables (per-key offsets, "
@@ -698,8 +690,7 @@ JOIN_MATCHED_VIA_PRESENCE = conf(
     "the flag needs key existence only, so the build-sized sort + "
     "merge-rank behind the table never pays for itself (q21/q22-class "
     "anti joins against a 2M-row build drop ~10x on the cpu backend). "
-    "Off restores the sorted offs path (the all-scatter-free "
-    "configuration, with agg.denseDomainViaSort).")
+    "Off restores the sorted offs path.")
 
 JOIN_MATCHED_VIA_MERGE = conf(
     "spark.rapids.tpu.sql.join.matchedViaMerge", True,
@@ -960,101 +951,6 @@ SERVING_ADMIT_WORKING_SET_FACTOR = conf(
 
 
 # --------------------------------------------------------------------------
-# Hand-written Pallas kernel tier (ops/pallas/ — the libcudf-equivalent
-# layer; the sort-based kernels stay the portable fallback)
-# --------------------------------------------------------------------------
-
-PALLAS_ENABLED = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.enabled", False,
-    "Master switch for the hand-written Pallas kernel tier (ops/pallas/): "
-    "hash-probe joins (murmur3 open addressing instead of sorted-build + "
-    "merge-rank probes), bounded-domain segmented aggregation "
-    "(block-local accumulate + single-pass combine instead of sort or "
-    "scatter group-bys), and selection compaction (prefix-sum + rank "
-    "search instead of the keep-mask argsort). Off keeps every query on "
-    "the sort-based portable tier, bit-identical to main; on, each "
-    "kernel family still negotiates per-operator legality (single exact "
-    "key lane, domain bounds, backend support) and falls back to the "
-    "sort tier where it loses — dispatch/fallback decisions are counted "
-    "in the tpu_kernel_* metric families.", commonly_used=True)
-
-PALLAS_JOIN = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.join", "AUTO",
-    "Hash-probe join kernel family: open-addressing murmur3 table "
-    "(hash-ordered layout, duplicates consecutive) built once per build "
-    "side, probed by a Pallas kernel gridded over probe blocks — "
-    "replaces the sorted-build + merge-rank probe (two 2-operand sorts "
-    "of build+probe rows per probe op). AUTO enables it on every "
-    "backend (the interpreted kernel beats the sort path on the CPU "
-    "test mesh too); ON/OFF force. Requires kernels.pallas.enabled.",
-    checker=_enum_checker("AUTO", "ON", "OFF"))
-
-PALLAS_SEGAGG = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.segagg", "AUTO",
-    "Segmented-aggregation kernel family: group-bys whose packed key "
-    "domain fits kernels.pallas.segagg.maxDomain accumulate block-local "
-    "per-bucket partials (one-hot MXU matmuls for the sum/count family "
-    "— int64 sums ride exact split-f64 dot products — masked VPU "
-    "reductions for MIN/MAX/FIRST/LAST/ANY/EVERY) and combine once, "
-    "operating directly on dictionary codes / FOR-narrowed lanes with "
-    "no sort and no scatter. AUTO enables it only where Pallas "
-    "compiles natively (the TPU backend; XLA-CPU scatters are fast and "
-    "the interpreted kernel loses there); ON forces it everywhere "
-    "(tier-1 exercises the kernel bodies this way), OFF disables.",
-    checker=_enum_checker("AUTO", "ON", "OFF"))
-
-PALLAS_COMPACT = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.compact", "AUTO",
-    "Selection-compaction kernel family: filter/compaction order from a "
-    "blocked prefix sum + per-output-slot rank search (log2(capacity) "
-    "vectorized gathers) instead of the stable keep-mask argsort. AUTO "
-    "enables it on every backend; ON/OFF force. Requires "
-    "kernels.pallas.enabled.",
-    checker=_enum_checker("AUTO", "ON", "OFF"))
-
-PALLAS_INTERPRET = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.interpret", "AUTO",
-    "Run Pallas kernels through the interpreter (pl.pallas_call "
-    "interpret=True): the kernel bodies execute as discharged XLA ops "
-    "inside the same traced program, so non-TPU backends run the REAL "
-    "kernel logic — tier-1 and the CPU container exercise the actual "
-    "probe/accumulate/compact bodies, not a shadow implementation. "
-    "AUTO interprets on every backend without native Pallas lowering "
-    "(everything but TPU); ON forces interpretation even on TPU "
-    "(debugging); OFF never interprets (the tier disables itself "
-    "off-TPU).", checker=_enum_checker("AUTO", "ON", "OFF"))
-
-PALLAS_SEGAGG_MAX_DOMAIN = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.segagg.maxDomain", 1024,
-    "Largest packed key-domain product the block-accumulate segmented "
-    "aggregation kernel will hold as a live accumulator (VMEM bound: "
-    "domain x aggregate lanes x 8B per block); larger domains keep the "
-    "sort/scatter group-by paths.", checker=_positive)
-
-PALLAS_JOIN_DENSE_REPLACE = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.join.denseReplace", "AUTO",
-    "When the hash-probe kernel is elected and the join ALSO qualifies "
-    "for a dense direct-address table: AUTO replaces the dense table "
-    "only when the key span exceeds 4x the build capacity — the regime "
-    "where the dense build's span-sized offs sorts dominate; below it "
-    "the dense table's one-gather probes beat the hash walk (measured: "
-    "q4/q19/q22-class probe-bound joins regress ~1.3-1.5x under full "
-    "replacement on the cpu backend, while q3/q9-class span-heavy "
-    "builds win ~1.5-3x).  ON always replaces (scatter-free builds on "
-    "backends where dense tables land in slow S(1) buffers; the sort-"
-    "budget lint runs this way), OFF never does (the kernel only takes "
-    "the no-domain sorted-probe shape).",
-    checker=_enum_checker("AUTO", "ON", "OFF"))
-
-PALLAS_JOIN_MAX_BUILD = conf(
-    "spark.rapids.tpu.sql.kernels.pallas.join.maxBuildRows", 1 << 23,
-    "Largest build-side row capacity the hash-probe join kernel will "
-    "table (the open-addressing table holds ~3 slots per build row at "
-    "load factor 0.5 plus the overflow tail); larger builds keep the "
-    "sorted-lane fallback.", checker=_positive)
-
-
-# --------------------------------------------------------------------------
 # Compressed device-resident execution (ops/encodings.py): operators run
 # directly on dictionary codes and FOR-narrowed integer lanes instead of
 # decoding to full-width materialized columns first
@@ -1125,8 +1021,8 @@ HISTORY_DIR = conf(
     "(obs/history.py): every completed query appends one JSONL record — "
     "measured device wall, per-segment device ms, compile ms, source "
     "bytes, peak HBM reservation — keyed by the canonical plan "
-    "STRUCTURE (PR 7 constant-lifted structure key + resolved kernel "
-    "tier + leaf shape bucket), so a fresh process serves calibrated "
+    "STRUCTURE (PR 7 constant-lifted structure key + resolved encoding "
+    "policy + leaf shape bucket), so a fresh process serves calibrated "
     "cost estimates (obs/estimator.py, serving admission prediction) "
     "with zero re-measurement. Corrupt/truncated lines are tolerated "
     "on load; the file is byte/entry-capped with LRU compaction "

@@ -14,6 +14,7 @@ TPU-first deltas from the reference:
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 import jax
@@ -161,37 +162,61 @@ def holistic_pack_spec(key_cols, key_exprs, child):
 
 
 def _seg_knobs(conf):
-    """(scatter_free, max_sort_operands, dense_via_sort) statics for the
-    group-by trace builders — part of every jit cache key they shape."""
-    from ..config import (DENSE_AGG_VIA_SORT, MAX_SORT_OPERANDS,
-                          SEG_SCATTER_FREE)
+    """(scatter_free, max_sort_operands) statics for the group-by trace
+    builders — part of every jit cache key they shape."""
+    from ..config import MAX_SORT_OPERANDS, SEG_SCATTER_FREE
     if conf is None:
-        return True, 2, False
-    return (conf.get(SEG_SCATTER_FREE), conf.get(MAX_SORT_OPERANDS),
-            conf.get(DENSE_AGG_VIA_SORT))
-
-
-def _domains_as_pack(domains):
-    """Dense key domains (codes in [0, size)) as a packed-lane spec:
-    slot 0 stays the null slot, codes shift up by one."""
-    return tuple((0, size + 1) for size in domains)
+        return True, 2
+    return conf.get(SEG_SCATTER_FREE), conf.get(MAX_SORT_OPERANDS)
 
 
 #: the strategies whose program sorts its rows by key
 _SORT_STRATEGIES = ("packed_sort", "lexsort")
 
 
-def _strategy(num_keys: int, pallas_interp, domains, pack) -> str:
-    """The name of the aggregate program that the branches below
-    (_run_groupby, HashAggregate.partial_fused) build from these
-    elections; a keyless aggregate is a "reduce"."""
+@dataclasses.dataclass(frozen=True)
+class AggElection:
+    """One aggregate program, as both engines choose it (_run_groupby
+    for the eager one, HashAggregate.partial_fused for whole-plan):
+    `strategy` is the name `agg.strategy.*` counts; the other fields are
+    the statics that shape the trace, so the election itself is the
+    part of a jit cache key that tells two programs apart."""
+    strategy: str
+    domains: Optional[tuple]
+    pack: Optional[tuple]
+    scatter_free: bool
+    max_ops: int
+
+    def trace(self, key_info, specs, capacity: int):
+        """The keyed group-by trace of this election (a "reduce" has no
+        keys and is built by its callers from G.reduce_trace)."""
+        if self.domains is not None:
+            return G.dense_groupby_trace(list(self.domains), list(specs),
+                                         capacity)
+        return G.groupby_trace(list(key_info), list(specs), capacity,
+                               capacity, pack_spec=self.pack,
+                               scatter_free=self.scatter_free,
+                               max_sort_operands=self.max_ops)
+
+
+def elect_aggregate(num_keys: int, domains, pack, scatter_free: bool,
+                    max_ops: int) -> AggElection:
+    """THE choice of an aggregate's program from what its caller found:
+    no keys reduce; keys with a bounded domain (`domains`: dictionary
+    codes, booleans, under agg.denseDomainMax) take the dense group-by,
+    built from bucket-masked reductions up to G.MASKED_DOMAIN_MAX
+    buckets and from scatters above; the rest sort, on one packed lane
+    where `pack` covers every key, else lexicographically."""
     if not num_keys:
-        return "reduce"
-    if pallas_interp is not None:
-        return "pallas"
-    if domains is not None:
-        return "dense_masked" if G.dense_is_masked(domains) else "dense"
-    return "packed_sort" if G.all_keys_pack(pack, num_keys) else "lexsort"
+        strategy, domains, pack = "reduce", None, None
+    elif domains is not None:
+        domains, pack = tuple(domains), None
+        strategy = "dense_masked" if G.dense_is_masked(domains) \
+            else "dense"
+    else:
+        strategy = "packed_sort" if G.all_keys_pack(pack, num_keys) \
+            else "lexsort"
+    return AggElection(strategy, domains, pack, scatter_free, max_ops)
 
 
 def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
@@ -207,54 +232,17 @@ def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
         if encoding_policy(conf).enabled:
             count_dispatch("groupby_codes")
     info = tuple((c.dtype, True, str(c.data.dtype)) for c in key_cols)
-    scatter_free, max_ops, dense_sort = _seg_knobs(conf)
     domains = _dense_domains(key_cols, conf)
-    if domains is not None and dense_sort:
-        # flip knob: run the bounded domain through the packed
-        # single-sort-lane kernel instead of the no-sort bucket scatters
-        pack, domains = _domains_as_pack(domains), None
-    else:
-        pack = None if domains is not None \
-            else _key_pack_spec(key_cols, key_ranges)
-    # Pallas block-accumulate segmented aggregation (ops/pallas/segagg):
-    # any fully-bounded key tuple — dense domains or a complete pack —
-    # whose span product fits the block accumulator aggregates with no
-    # sort, no scatter and no row permutation at all
-    pallas_interp = None
-    full_pack = pack if (pack is not None and
-                         all(s is not None for s in pack)) else \
-        (_domains_as_pack(domains) if domains is not None else None)
-    if full_pack is not None and conf is not None:
-        total = 1
-        for _lo, span in full_pack:
-            total *= int(span)
-        from ..ops.pallas import elect_segagg
-        has_float_sum = any(s.kind == G.SUM and t.is_floating(s.dtype)
-                            for s in specs)
-        ptier = elect_segagg(conf, total, has_float_sum)
-        if ptier is not None:
-            pack, domains = full_pack, None
-            pallas_interp = ptier.interpret
+    pack = None if domains is not None \
+        else _key_pack_spec(key_cols, key_ranges)
+    choice = elect_aggregate(len(key_cols), domains, pack,
+                             *_seg_knobs(conf))
     sig = (info, tuple((s.kind, s.input_idx, s.dtype) for s in specs),
-           capacity, tuple(str(c.data.dtype) for c in agg_cols),
-           tuple(domains) if domains else None, pack, scatter_free,
-           max_ops, pallas_interp)
+           capacity, tuple(str(c.data.dtype) for c in agg_cols), choice)
     fn = _GROUPBY_CACHE.get(sig)
     if fn is None:
-        if pallas_interp is not None:
-            from ..ops.pallas.segagg import pallas_groupby_trace
-            fn = jax.jit(pallas_groupby_trace(pack, list(info),
-                                              list(specs), capacity,
-                                              capacity, pallas_interp))
-        elif domains is not None:
-            fn = jax.jit(G.dense_groupby_trace(list(domains), list(specs),
-                                               capacity))
-        else:
-            fn = jax.jit(G.groupby_trace(list(info), list(specs), capacity,
-                                         capacity, pack_spec=pack,
-                                         scatter_free=scatter_free,
-                                         max_sort_operands=max_ops))
-        _GROUPBY_CACHE[sig] = fn
+        fn = _GROUPBY_CACHE[sig] = jax.jit(
+            choice.trace(info, specs, capacity))
     out_keys, outs, num_groups = fn(
         tuple(c.data for c in key_cols),
         tuple(c.validity for c in key_cols),
@@ -266,8 +254,7 @@ def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
     # tracing the count is a Tracer and must stay on device
     if not isinstance(num_groups, jax.core.Tracer):
         num_groups = int(num_groups)
-    return key_cols, out_keys, outs, num_groups, _strategy(
-        len(key_cols), pallas_interp, domains, pack)
+    return key_cols, out_keys, outs, num_groups, choice.strategy
 
 
 def _run_reduce(agg_cols: List[DeviceColumn], specs: List[G.AggSpec],
@@ -490,33 +477,13 @@ class HashAggregate:
         pctx, hostvals, aux = _prepare(exprs_all, db, self.conf)
         spec_sig = tuple((s.kind, s.input_idx, str(s.dtype))
                          for s in self.update_specs)
-        scatter_free, max_ops, dense_sort = _seg_knobs(self.conf)
         dense_domains = self._fused_dense_domains(db) \
             if any(isinstance(e.dtype, (t.StringType, t.BooleanType))
                    for e in self.key_exprs) else None
-        pack = None
-        if dense_domains is not None and dense_sort:
-            pack, dense_domains = _domains_as_pack(dense_domains), None
-        elif dense_domains is None:
-            pack = _fused_pack_spec(self.key_exprs, self.key_ranges)
-        # Pallas block-accumulate election, mirroring _run_groupby
-        pallas_interp = None
-        full_pack = pack if (pack is not None and self.key_exprs and
-                             all(s is not None for s in pack)) else \
-            (_domains_as_pack(dense_domains)
-             if dense_domains is not None else None)
-        if full_pack is not None:
-            total = 1
-            for _lo, span in full_pack:
-                total *= int(span)
-            from ..ops.pallas import elect_segagg
-            has_float_sum = any(
-                s.kind == G.SUM and t.is_floating(s.dtype)
-                for s in self.update_specs)
-            ptier = elect_segagg(self.conf, total, has_float_sum)
-            if ptier is not None:
-                pack, dense_domains = full_pack, None
-                pallas_interp = ptier.interpret
+        pack = None if dense_domains is not None \
+            else _fused_pack_spec(self.key_exprs, self.key_ranges)
+        choice = elect_aggregate(len(self.key_exprs), dense_domains, pack,
+                                 *_seg_knobs(self.conf))
         has_sel = db.sel is not None
         from ..config import AGG_INPUT_NARROWING
         _narrow_on = self.conf.get(AGG_INPUT_NARROWING)
@@ -527,10 +494,7 @@ class HashAggregate:
             for e in self.input_exprs)
         key = _jit_key(exprs_all, db, aux, self.conf,
                        ("fpartial", spec_sig, len(conds),
-                        len(self.key_exprs),
-                        tuple(dense_domains) if dense_domains else None,
-                        pack, has_sel, narrow, scatter_free, max_ops,
-                        pallas_interp))
+                        len(self.key_exprs), has_sel, narrow, choice))
         fn = _JIT_CACHE.get(key)
         if fn is None:
             capacity = db.capacity
@@ -578,19 +542,7 @@ class HashAggregate:
                     kds.append(dv.data)
                     kvs.append(valid_or_true(dv.validity, capacity))
                     kinfo.append((e.dtype, True, str(dv.data.dtype)))
-                if pallas_interp is not None:
-                    from ..ops.pallas.segagg import pallas_groupby_trace
-                    gb = pallas_groupby_trace(pack, kinfo, specs,
-                                              capacity, capacity,
-                                              pallas_interp)
-                elif dense_domains is not None:
-                    gb = G.dense_groupby_trace(list(dense_domains), specs,
-                                               capacity)
-                else:
-                    gb = G.groupby_trace(kinfo, specs, capacity, capacity,
-                                         pack_spec=pack,
-                                         scatter_free=scatter_free,
-                                         max_sort_operands=max_ops)
+                gb = choice.trace(kinfo, specs, capacity)
                 return gb(tuple(kds), tuple(kvs), tuple(agg_data),
                           tuple(agg_valid), live)
 
@@ -602,8 +554,7 @@ class HashAggregate:
         out_keys, outs, ng = fn(_col_lanes(db),
                                 tuple(c.validity for c in db.columns),
                                 _num_rows_scalar(db.num_rows), aux, *extra)
-        self._note(_strategy(len(self.key_exprs), pallas_interp,
-                             dense_domains, pack), db.capacity)
+        self._note(choice.strategy, db.capacity)
         if not self.key_exprs:
             return outs if raw else self._reduce_outs_to_batch(outs)
         nconds = len(conds)

@@ -91,7 +91,7 @@ class CollectAggregateExec(PlanNode):
         info = tuple((c.dtype, True, str(c.data.dtype)) for c in key_cols)
         from .aggregate import _seg_knobs, holistic_pack_spec
         pack = holistic_pack_spec(key_cols, self.key_exprs, self.child)
-        _sf, max_ops, _ds = _seg_knobs(ctx.conf)
+        _sf, max_ops = _seg_knobs(ctx.conf)
 
         results = [None] * len(self.aggs)
         out_keys = n_groups = None

@@ -473,25 +473,16 @@ def plan_structure_key(root: PlanNode, conf: TpuConf) -> Optional[tuple]:
     if not walk(root):
         return None
     conf_sig = tuple(sorted((k, str(v)) for k, v in conf._raw.items()))
-    # kernel-tier discriminant: the RESOLVED Pallas tier (which depends
-    # on backend AUTO rules, not just the raw conf strings already in
-    # conf_sig) keys the executable, so cached programs compiled with
-    # hand-written kernels can never cross-load into a sort-tier
-    # session or vice versa (ops/pallas.tier_discriminant; None when
-    # the tier is fully off)
     from ..ops.encodings import encoding_discriminant
-    from ..ops.pallas import tier_discriminant
-    # encoded-execution discriminant mirrors the kernel tier's: the
-    # RESOLVED policy (AUTO rules included) keys the executable so
-    # encoded-representation programs never cross-load into a decoded
-    # session or vice versa; None when fully off keeps the key
-    # byte-identical to pre-encoding builds
+    # encoded-execution discriminant: the RESOLVED policy (which depends
+    # on AUTO rules, not just the raw conf strings already in conf_sig)
+    # keys the executable so encoded-representation programs never
+    # cross-load into a decoded session or vice versa; None when fully
+    # off keeps the key byte-identical to pre-encoding builds
     enc = encoding_discriminant(conf)
     if enc is None:
-        return (tuple(parts), conf_sig, jax.default_backend(),
-                tier_discriminant(conf))
-    return (tuple(parts), conf_sig, jax.default_backend(),
-            tier_discriminant(conf), enc)
+        return (tuple(parts), conf_sig, jax.default_backend())
+    return (tuple(parts), conf_sig, jax.default_backend(), enc)
 
 
 def _plan_anchors(root: PlanNode, pairs) -> Optional[list]:
@@ -1331,13 +1322,10 @@ def _resolve_at(db: DeviceBatch, cap: int, scope: str,
     `cap`, materialised columns taken at it, every deferred column
     gathered once from its lane source into compacted position."""
     from ..columnar.lanes import resolved_columns
-    from ..ops.pallas import elect_compact
     flat, spec = _flatten_batch(db)
     spec = _bare_spec(spec)
-    tier = elect_compact(conf, db.capacity)
     sig = (_spec_sig(spec),
-           tuple((tuple(a.shape), str(a.dtype)) for a in flat), cap, scope,
-           None if tier is None else tier.interpret)
+           tuple((tuple(a.shape), str(a.dtype)) for a in flat), cap, scope)
     fn = _SEAM_CACHE.get(sig)
     if fn is None:
         fn = _SEAM_CACHE[sig] = jax.jit(_seam_trace(spec, cap, scope, conf))

@@ -99,7 +99,7 @@ class DistinctAggregateExec(PlanNode):
         from .aggregate import _seg_knobs, holistic_pack_spec
         from .join import key_ref_names
         pack = holistic_pack_spec(key_cols, self.key_exprs, self.child)
-        scatter_free, max_ops, _ds = _seg_knobs(conf)
+        scatter_free, max_ops = _seg_knobs(conf)
         results: List = [None] * len(self.aggs)
         out_keys = n_groups = None
         for j, vcol in enumerate(val_cols):

@@ -596,37 +596,12 @@ class HashJoinExec(PlanNode):
                 pack_span, build_batch.capacity) else None
         else:
             domain = self._dense_domain(build_keys, build_batch.capacity)
-        # Pallas hash-probe tier: replaces the sorted-build + merge-rank
-        # search always, and the dense direct-address tables under the
-        # denseReplace policy (span-sized offs sorts dominate the dense
-        # build past ~4x the build rows; below it its one-gather probes
-        # win).  Single-exact-lane legality finishes inside BuildTable.
-        from ..config import JOIN_MATCHED_VIA_PRESENCE
-        from ..ops.pallas import count_fallback, elect_join
-        dense_span = None if domain is None \
-            else int(domain[1]) - int(domain[0]) + 1
-        via_presence = ctx.conf.get(JOIN_MATCHED_VIA_PRESENCE)
-        matched_only = self.join_type in (J.LEFT_SEMI, J.LEFT_ANTI)
-        if matched_only and domain is not None and via_presence:
-            # semi/anti over a dense domain: the probe needs a PRESENCE
-            # bitmap only (ops/join.py BuildTable.present — one bool
-            # scatter), which beats both the hash table and the sorted
-            # offs table regardless of span; skip the kernel election
-            pallas_tier = None
-            from ..ops.pallas import kernel_tier
-            if kernel_tier(ctx.conf).join:
-                count_fallback("hash_probe_join", "dense_matched")
-        else:
-            pallas_tier = elect_join(ctx.conf, build_batch.capacity,
-                                     dense_span=dense_span)
-        if pallas_tier is not None:
-            domain = None               # the hash table takes the join
-            ctx.bump("join_pallas_hash")
         unique = domain is not None and self._build_unique()
         if domain is not None:
             ctx.bump("join_dense_domain")
         from ..config import (JOIN_DENSE_BUILD_VIA_SORT,
-                              JOIN_MATCHED_VIA_MERGE)
+                              JOIN_MATCHED_VIA_MERGE,
+                              JOIN_MATCHED_VIA_PRESENCE)
         build = J.BuildTable(build_batch, build_keys, build_lanes,
                              domain=domain, unique=unique,
                              extra_valid=build_pre if build_conds else None,
@@ -634,8 +609,8 @@ class HashJoinExec(PlanNode):
                                  JOIN_DENSE_BUILD_VIA_SORT),
                              matched_via_merge=ctx.conf.get(
                                  JOIN_MATCHED_VIA_MERGE),
-                             matched_via_presence=via_presence,
-                             pallas_tier=pallas_tier)
+                             matched_via_presence=ctx.conf.get(
+                                 JOIN_MATCHED_VIA_PRESENCE))
         out_names = list(self.output_schema.names)
         # Sync-free probe-aligned path: a build side whose keys are unique
         # (exact plan statistics — dimension scans, group-by outputs) makes

@@ -9,7 +9,7 @@ grown into a first-class out-of-core tier (ROADMAP item 4):
     `Spillable` buckets (`runtime/memory.py`) — key-disjoint partitions
     make the union of per-bucket results EXACT, with the same output
     contracts as the resident path (each bucket finishes on the
-    existing sorted/segagg group-by tiers);
+    existing dense/sorted group-by paths);
   * the bucket fan-out derives from measured partial BYTES vs the
     out-of-core resident window (`exec/ooc.py`), not just the legacy
     row gate, so a wide-row aggregation degrades before the budget

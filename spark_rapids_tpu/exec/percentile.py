@@ -97,7 +97,7 @@ class PercentileAggregateExec(PlanNode):
         info = tuple((c.dtype, True, str(c.data.dtype)) for c in key_cols)
         from .aggregate import _seg_knobs, holistic_pack_spec
         pack = holistic_pack_spec(key_cols, self.key_exprs, self.child)
-        scatter_free, max_ops, _ds = _seg_knobs(conf)
+        scatter_free, max_ops = _seg_knobs(conf)
         results: List[Tuple] = [None] * len(self.aggs)
         out_keys = n_groups = None
         for j, vcol in enumerate(val_cols):
@@ -175,7 +175,7 @@ class PercentileAggregateExec(PlanNode):
             from .aggregate import _seg_knobs, holistic_pack_spec
             pack = holistic_pack_spec(key_cols, self.key_exprs,
                                       self.child)
-            scatter_free, max_ops, _ds = _seg_knobs(conf)
+            scatter_free, max_ops = _seg_knobs(conf)
             for j, vcol in enumerate(val_cols):
                 sig = ("sketch", info, DEFAULT_K, capacity,
                        str(vcol.data.dtype), pack, scatter_free,

@@ -131,7 +131,7 @@ class WindowExec(PlanNode):
         order_dirs = tuple((asc, nf) for _e, asc, nf in self.order_keys) \
             if has_value_range else ()
         from .aggregate import _seg_knobs
-        scatter_free, max_ops, _ds = _seg_knobs(ctx.conf)
+        scatter_free, max_ops = _seg_knobs(ctx.conf)
         key = ("window", s.capacity,
                tuple(sp.fingerprint() for sp, _f, _i in specs_frames),
                tuple(f.fp() for _s, f, _i in specs_frames),

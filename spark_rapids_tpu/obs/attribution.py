@@ -145,8 +145,6 @@ class ExplainAnalyzeReport:
     #: run (obs/estimator.py) — the predicted column next to measured;
     #: None when the history plane is off
     predicted: Optional[Dict[str, Any]] = None
-    #: node id -> resolved Pallas kernel-tier decision (kernel_plan())
-    kernel_tiers: Dict[str, str] = dataclasses.field(default_factory=dict)
     #: out-of-core tier activity of the profiled run (exec/ooc.py):
     #: per-op election/partition/byte/recursion counters from
     #: ctx.metrics `ooc.*` entries; {} when the tier never engaged
@@ -171,7 +169,6 @@ class ExplainAnalyzeReport:
                 "gathers": self.gathers,
                 "mesh_timeline": self.mesh_timeline,
                 "predicted": self.predicted,
-                "kernel_tiers": self.kernel_tiers,
                 "ooc": self.ooc,
                 "hbm": self.hbm}
 
@@ -279,14 +276,11 @@ def _flag_skew(segments: List[Dict[str, Any]]) -> None:
 def _render_tree(root, metrics: Dict[str, Any],
                  seg_by_node: Dict[str, Dict[str, Any]],
                  wall_ms: float,
-                 kernel_tiers: Optional[Dict[str, str]] = None,
                  pred_segments: Optional[Dict[str, float]] = None) -> str:
     """The annotated physical tree: every node with its measured per-node
     metrics, segment anchors with device time / % of wall / rows /
-    bytes / static cost / predicted-from-history ms, and the resolved
-    Pallas kernel-tier decision where one applies."""
+    bytes / static cost / predicted-from-history ms."""
     from ..exec.metrics import _child_nodes
-    kernel_tiers = kernel_tiers or {}
     pred_segments = pred_segments or {}
     lines: List[str] = []
 
@@ -326,9 +320,6 @@ def _render_tree(root, metrics: Dict[str, Any],
                 s += (f" | SKEW x{seg['cost_skew']:g} vs predicted "
                       f"(mis-fused?)")
             parts.append(s + ">")
-        kt = kernel_tiers.get(nid)
-        if kt is not None:
-            parts.append(f"[kernel: {kt}]")
         op_ms = metrics.get(f"{nid}.op_time_ms")
         rows = metrics.get(f"{nid}.output_rows")
         ann = []
@@ -381,18 +372,6 @@ def run_explain_analyze(pq, conf_overrides: Optional[dict] = None
     except Exception:                        # noqa: BLE001
         predicted = None
 
-    # resolved Pallas kernel-tier decision per node (PR 11 kernel_plan)
-    kernel_tiers: Dict[str, str] = {}
-    if pq.kind == "device":
-        try:
-            from ..plan.overrides import kernel_tier_decisions
-            for node, decision in kernel_tier_decisions(pq.root, pq.conf):
-                nid = getattr(node, "_node_id", None)
-                if nid:
-                    kernel_tiers[nid] = decision
-        except Exception:                    # noqa: BLE001
-            pass
-
     def _gather_totals() -> Dict[str, int]:
         out = {}
         for name, fam in (("gather_rows", GATHER_ROWS),
@@ -441,8 +420,7 @@ def run_explain_analyze(pq, conf_overrides: Optional[dict] = None
         pred_segments = {n: float(v) for n, v in
                          (predicted.get("segments") or {}).items()}
     tree = _render_tree(pq.root, ctx.metrics, seg_by_node,
-                        split["wall_ms"], kernel_tiers=kernel_tiers,
-                        pred_segments=pred_segments)
+                        split["wall_ms"], pred_segments=pred_segments)
     # out-of-core tier activity: the ctx.metrics `ooc.*` counters the
     # operators bump (exec/ooc.py) plus the query-rung escalation count
     ooc = {k[len("ooc."):]: v for k, v in ctx.metrics.items()
@@ -455,7 +433,7 @@ def run_explain_analyze(pq, conf_overrides: Optional[dict] = None
         wall_ms=split["wall_ms"], device_ms=round(device_ms, 3),
         gathers=gathers, mesh_timeline=profile.mesh_timeline(),
         metrics=dict(ctx.metrics), profile=profile,
-        predicted=predicted, kernel_tiers=kernel_tiers, hbm=hbm, ooc=ooc,
+        predicted=predicted, hbm=hbm, ooc=ooc,
         wall_breakdown=breakdown if breakdown.get("wall_ms") else {},
         attributed_wall_pct=None if wpct is None
         else round(wpct * 100, 1))

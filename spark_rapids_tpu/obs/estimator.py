@@ -14,7 +14,7 @@ seam wall + pad waste, obs/history.py overhead fields) — nonzero on
 Two bases, counted per call in `tpu_history_estimates_total`:
 
   * `exact_history` — the structure key (obs/history.py: PR 7 canonical
-    plan structure + kernel tier + shape bucket) hit the persistent
+    plan structure + encoding policy + shape bucket) hit the persistent
     store: the answer is the structure's decay-weighted measured
     history, per-segment device ms included.  Confidence grows with
     run count and is cut when the structure's own newest measurement

@@ -12,8 +12,8 @@ for data movement rather than per-query wall (Theseus, PAPERS.md).
 This module is that substrate:
 
   * `history_key(pq)` — the canonical identity of a query's *work*:
-    PR 7's constant-lifted `plan_structure_key` (literal values erased,
-    resolved Pallas kernel-tier discriminant included) plus the leaf
+    PR 7's constant-lifted `plan_structure_key` (literal values
+    erased, resolved encoding discriminant included) plus the leaf
     shape bucket, with observability-only conf keys (trace, eventLog,
     profile, metrics, history, serving, test) FILTERED OUT so an
     EXPLAIN ANALYZE run, a serving admission and a plain collect of the
@@ -92,7 +92,7 @@ def history_key(pq) -> Optional[str]:
 
 def compute_history_key(root, conf: TpuConf, kind: str) -> Optional[str]:
     """The structure digest for one physical root: canonical
-    plan_structure_key (kernel-tier discriminant included) + leaf shape
+    plan_structure_key (encoding discriminant included) + leaf shape
     bucket for device plans; a physical-tree digest for host plans."""
     neutral = _neutral_conf(conf)
     parts: List[Any] = [kind]

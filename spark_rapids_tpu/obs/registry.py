@@ -436,24 +436,6 @@ COMPILES_TOTAL = REGISTRY.counter(
     "Whole-plan XLA compile-cache outcomes (hit / miss).",
     ("outcome",))
 
-KERNEL_DISPATCH = REGISTRY.counter(
-    "tpu_kernel_dispatch_total",
-    "Operator dispatches onto the hand-written Pallas kernel tier "
-    "(ops/pallas/), by kernel family (hash_probe_join, segagg, "
-    "compact) and mode (compiled / interpret). Counted once per "
-    "trace on the whole-plan path, once per batch eagerly.",
-    ("kernel", "mode"))
-
-KERNEL_FALLBACK = REGISTRY.counter(
-    "tpu_kernel_fallback_total",
-    "Dispatches that consulted the enabled Pallas kernel tier but fell "
-    "back to the sort-based portable tier, by kernel family and reason "
-    "(multi_lane, dense_domain, dense_matched, build_too_large, "
-    "domain_too_large, float_exact, backend, oom). The 'oom' reason is "
-    "the chaos-visible recovery rung: a kernel-site OOM sheds the query "
-    "to the sort tier bit-identically instead of failing it.",
-    ("kernel", "reason"))
-
 ENCODED_DISPATCH = REGISTRY.counter(
     "tpu_encoded_dispatch_total",
     "Operator dispatches that stayed in the compressed domain "

@@ -39,8 +39,8 @@ encoded-execution layer behind ``spark.rapids.tpu.sql.encoded.*``:
     verdict mask by rank search — the bench.py --encodings A/B
     quantifies it against decode-first.
 
-Fallback-safety mirrors the Pallas tier (ops/pallas/): every encoded
-dispatch NEGOTIATES, fires the existing `kernel` chaos site, and an
+Fallback-safety: every encoded dispatch NEGOTIATES, fires the `kernel`
+chaos site (runtime/faults.py), and an
 injected OOM sheds the dispatch onto the decoded path bit-identically
 (`tpu_encoded_dispatch_total{outcome=oom_shed}`).  With
 ``encoded.execution.enabled=false`` no encoded path is consulted at all

@@ -77,26 +77,10 @@ def take_keys_valid(keys, keys_valid, extra, idx):
     return moved[:nk], out_kv, moved[nk + len(kv):]
 
 
-def pallas_compact_order(keep: jax.Array, conf: TpuConf):
-    """Elected Pallas compaction order for this keep-mask, or None on
-    the sorted tier (ops/pallas/compact.py — prefix sum + rank search
-    instead of the keep-mask argsort)."""
-    from .pallas import elect_compact
-    tier = elect_compact(conf, int(keep.shape[0]))
-    if tier is None:
-        return None
-    from .pallas.compact import compaction_order as pallas_order
-    return pallas_order(keep, tier.interpret)
-
-
 def _compact_trace(ncols: int, has_hi: Tuple[bool, ...],
-                   pallas_interpret=None, out_capacity=None):
+                   out_capacity=None):
     def run(datas, valids, his, keep):
-        if pallas_interpret is not None:
-            from .pallas.compact import compaction_order as pallas_order
-            order = pallas_order(keep, pallas_interpret)
-        else:
-            order = compaction_order(keep)
+        order = compaction_order(keep)
         count = jnp.sum(keep, dtype=jnp.int32)
         if out_capacity is not None:
             order = order[:out_capacity]
@@ -147,21 +131,16 @@ def compact_batch(db: DeviceBatch, keep: jax.Array,
         # sources into compacted position — one pass, no
         # materialize-then-compact double gather
         from ..columnar.lanes import compact_thin
-        db = compact_thin(db, keep, conf, out_capacity)
+        db = compact_thin(db, keep, out_capacity)
         if not sync:
             return db
         return shrink_to_rows(db, int(db.num_rows), conf)
     has_hi = tuple(c.data_hi is not None for c in db.columns)
-    from .pallas import elect_compact
-    tier = elect_compact(conf, db.capacity)
-    pallas_interpret = None if tier is None else tier.interpret
     sig = (db.num_columns, has_hi, db.capacity,
-           tuple(str(c.data.dtype) for c in db.columns),
-           pallas_interpret, out_capacity)
+           tuple(str(c.data.dtype) for c in db.columns), out_capacity)
     fn = _COMPACT_CACHE.get(sig)
     if fn is None:
-        fn = jax.jit(_compact_trace(db.num_columns, has_hi,
-                                    pallas_interpret, out_capacity))
+        fn = jax.jit(_compact_trace(db.num_columns, has_hi, out_capacity))
         _COMPACT_CACHE[sig] = fn
     if any(has_hi):
         zeros = jnp.zeros((db.capacity,), jnp.int64)

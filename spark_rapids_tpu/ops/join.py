@@ -201,8 +201,7 @@ class BuildTable:
                  extra_valid: Optional[jax.Array] = None,
                  dense_via_sort: bool = True,
                  matched_via_merge: bool = True,
-                 matched_via_presence: bool = True,
-                 pallas_tier=None):
+                 matched_via_presence: bool = True):
         self.batch = batch
         lanes = lanes_override if lanes_override is not None \
             else key_cols_lanes(key_cols)
@@ -212,22 +211,6 @@ class BuildTable:
         self.lanes = lanes
         self.key_valid = valid
         self.unique = unique
-        # Pallas hash-probe tier (ops/pallas/hashjoin.py): an elected
-        # tier arms the open-addressing murmur3 table for SINGLE exact
-        # lanes — replacing both the sorted-build + merge-rank probe
-        # and the dense direct-address tables (the hash build costs two
-        # row-sized sorts where dense offs/slot cost span-sized ones).
-        # Multi-lane composite hashes keep the sorted fallback: their
-        # candidate ranges need collision verification the run-length
-        # table contract cannot express.
-        self.pallas_tier = None
-        self._hash_table = None
-        if pallas_tier is not None:
-            if len(lanes) == 1:
-                self.pallas_tier = pallas_tier
-            else:
-                from .pallas import count_fallback
-                count_fallback("hash_probe_join", "multi_lane")
         # scatter-avoidance knobs (config.py JOIN_DENSE_BUILD_VIA_SORT /
         # JOIN_MATCHED_VIA_MERGE): dense tables from a sorted lane +
         # merge-rank, matched flags from merge-rank differences
@@ -374,19 +357,6 @@ class BuildTable:
     def capacity(self) -> int:
         return self.batch.capacity
 
-    @property
-    def hash_table(self):
-        """The armed Pallas hash table (built lazily, once per build
-        side), or None on the sorted tier."""
-        if self.pallas_tier is None:
-            return None
-        if self._hash_table is None:
-            from .pallas import hashjoin as HK
-            self._hash_table = HK.build_table(
-                self.lanes[0].astype(jnp.int64), self.key_valid,
-                self.pallas_tier.interpret)
-        return self._hash_table
-
 
 _PROBE_CACHE = {}
 
@@ -455,12 +425,6 @@ def probe_aligned(build: BuildTable, probe_lanes: List[jax.Array],
     the size a static fact instead."""
     assert len(probe_lanes) == 1 and len(build.lanes) == 1, \
         "probe_aligned requires exact single-lane keys"
-    if build.hash_table is not None:
-        from .pallas import hashjoin as HK
-        row, ok = HK.probe_first(build.hash_table,
-                                 probe_lanes[0].astype(jnp.int64),
-                                 probe_valid)
-        return jnp.maximum(row, 0), ok
     if build.slot is not None:
         lo, hi = build.domain
         sig = ("aligned_dense", build.span, probe_valid.shape[0], lo, hi)
@@ -508,11 +472,6 @@ def probe_matched_lazy(build: BuildTable, probe_lanes: List[jax.Array],
     only this flag, never the pairs).  Dense domains answer from the
     per-key counts (two gathers), no search and no build sort."""
     assert len(probe_lanes) == 1, "exact ranges require a single lane"
-    if build.hash_table is not None:
-        from .pallas import hashjoin as HK
-        return HK.probe_matched(build.hash_table,
-                                probe_lanes[0].astype(jnp.int64),
-                                probe_valid)
     if build.domain is not None and build.matched_via_presence:
         # presence bitmap, not the offs table: the flag needs key
         # EXISTENCE only, so the build-sized sort + merge-rank behind
@@ -560,16 +519,7 @@ def probe_matched_lazy(build: BuildTable, probe_lanes: List[jax.Array],
 def probe_counts(build: BuildTable, probe_lanes: List[jax.Array],
                  probe_valid: jax.Array):
     """-> (lo, counts, cum, total) ; total is a host int (one sync).
-    `lo` values are candidate-range starts in build.perm order (or
-    hash-table positions on the Pallas tier — expand_pairs resolves
-    whichever representation probe_counts produced)."""
-    if build.hash_table is not None and len(probe_lanes) == 1:
-        from .pallas import hashjoin as HK
-        first, counts, cum = HK.probe_counts(
-            build.hash_table, probe_lanes[0].astype(jnp.int64),
-            probe_valid)
-        total = int(cum[-1]) if cum.shape[0] else 0
-        return first, counts, cum, total
+    `lo` values are candidate-range starts in build.perm order."""
     if build.domain is not None and len(probe_lanes) == 1:
         dlo, dhi = build.domain
         sig = ("counts_dense", build.span, probe_valid.shape[0], dlo, dhi)
@@ -622,25 +572,6 @@ def expand_pairs(build: BuildTable, probe_lanes: List[jax.Array],
     cummax-ing forward — O(n) scatter+scan instead of a binary search
     per output slot (the log2(n) dependent gathers of searchsorted are
     the slowest access pattern on TPU)."""
-    if build.hash_table is not None and len(probe_lanes) == 1:
-        # Pallas tier: `lo` is the per-probe first TABLE position and a
-        # key's matches occupy consecutive slots, so expansion is a
-        # rank search + pure gathers (no ownership sorts); matched
-        # flags fall out of counts and interval marking
-        from .pallas import hashjoin as HK
-        true_total = total if total is not None \
-            else (int(cum[-1]) if cum.shape[0] else 0)
-        if true_total > out_cap:
-            raise ValueError(
-                f"join candidate pairs {true_total} exceed output "
-                f"capacity {out_cap}")
-        probe_idx, build_idx, ok = HK.expand_pairs(
-            build.hash_table, lo, counts, cum, out_cap,
-            jnp.int32(true_total))
-        probe_matched = probe_valid & (counts > 0)
-        build_matched = HK.build_matched_flags(
-            build.hash_table, lo, counts, build.capacity)
-        return probe_idx, build_idx, ok, probe_matched, build_matched
     # exact candidate ranges (single lane or dense domain) need no
     # per-pair verification against collisions, and probe_matched is just
     # counts>0 — skip one of the two segment reductions

@@ -1223,15 +1223,6 @@ class PhysicalQuery:
     def physical_tree(self) -> str:
         return self.root.tree_string()
 
-    def kernel_plan(self) -> List[str]:
-        """Static Pallas kernel-tier dispatch plan: one line per
-        candidate operator (`<Exec> -> pallas:<kernel>` /
-        `sorted:<reason>` / `runtime:<fact>`) — empty when the tier is
-        off or the plan runs on the host engine."""
-        if self.kind != "device":
-            return []
-        return kernel_tier_plan(self.root, self.conf)
-
     def fallback_reasons(self) -> List[str]:
         """Every tagger reason in the meta tree (depth-first) — the
         structured form of the '!Exec ... because ...' explain lines."""
@@ -1303,13 +1294,6 @@ class PhysicalQuery:
                 for name, t0, t1 in self.plan_phases:
                     tracer.add_span(name, "plan", t0, t1,
                                     parent=early.get("tpu.plan"))
-                if self.kind == "device":
-                    try:
-                        kp = self.kernel_plan()
-                        if kp:       # the resolved Pallas tier decisions
-                            tracer.meta["kernel_plan"] = kp
-                    except Exception:        # noqa: BLE001
-                        pass
             # an admission-time cost prediction (serving seeds
             # predicted.* into ctx.metrics before collect) rides the
             # trace + event log next to what actually happened
@@ -1858,9 +1842,6 @@ def apply_overrides(plan: L.LogicalPlan,
         from ..ops.encodings import encoding_policy
         if encoding_policy(conf).narrow_lanes:
             _negotiate_encoded(root)
-        if mode == "ALL":
-            for line in kernel_tier_plan(root, conf):
-                log.info(f"kernel-tier: {line}")
     phases.append(("plan.convert", t2, _time.perf_counter()))
     pq = PhysicalQuery(meta, kind, root, conf)
     pq.plan_phases = phases
@@ -2082,72 +2063,6 @@ def _negotiate_encoded(root) -> None:
     for nid, node in scans.items():
         node.encoded_cols = frozenset(node.output_schema.names) \
             if allowed[nid] else None
-
-
-def kernel_tier_decisions(root, conf: TpuConf) -> List[tuple]:
-    """Static Pallas kernel-tier dispatch decisions as (node, decision)
-    pairs in plan preorder — the structured form behind
-    `kernel_tier_plan` (the explain=ALL / bench lines) and the
-    per-node `kernel=` annotations EXPLAIN ANALYZE renders next to
-    each segment (obs/attribution.py).  Empty when the tier is off."""
-    from ..exec.adaptive import AdaptiveShuffledJoinExec
-    from ..exec.join import HashJoinExec
-    from ..exec.plan import FilterExec, HashAggregateExec
-    from ..ops.pallas import kernel_tier
-    tier = kernel_tier(conf)
-    out: List[tuple] = []
-    if not tier.any_enabled:
-        return out
-    seen = set()
-
-    def join_line(node) -> str:
-        if not tier.join:
-            return "sorted:join_family_off"
-        if not isinstance(node, HashJoinExec):
-            # the adaptive join picks its build side (and so its key
-            # shape) from measured inputs at run time
-            return "runtime:adaptive_build_side"
-        single = len(node.right_keys) == 1
-        packable = single or (isinstance(node, HashJoinExec) and
-                              node._range_pack_spec() is not None)
-        if not packable:
-            return "sorted:multi_lane"
-        return "pallas:hash_probe_join"
-
-    def walk(node):
-        if id(node) in seen:
-            return
-        seen.add(id(node))
-        if isinstance(node, (HashJoinExec, AdaptiveShuffledJoinExec)):
-            out.append((node, join_line(node)))
-        elif isinstance(node, HashAggregateExec):
-            if not tier.segagg:
-                out.append((node, "sorted:segagg_family_off"))
-            elif not node.key_exprs:
-                out.append((node, "sorted:no_keys"))
-            else:
-                out.append((node, "runtime:packed_domain_bound"))
-        elif isinstance(node, FilterExec):
-            out.append((node, "pallas:compact" if tier.compact
-                        else "sorted:compact_family_off"))
-        for c in node.children:
-            walk(c)
-
-    walk(root)
-    return out
-
-
-def kernel_tier_plan(root, conf: TpuConf) -> List[str]:
-    """Plan-level legality report for the Pallas kernel tier
-    (ops/pallas/): one line per candidate operator stating where it
-    will dispatch and, for the sort-tier outcomes, WHY — the static
-    half of the negotiation (batch-dependent facts like dictionary
-    domains and adaptive build-side swaps resolve at runtime and are
-    reported as `runtime:`).  Logged under explain=ALL when the tier
-    is on; bench.py --kernels and the tier tests read it through
-    PhysicalQuery.kernel_plan()."""
-    return [f"{type(node).__name__} -> {decision}"
-            for node, decision in kernel_tier_decisions(root, conf)]
 
 
 # ---------------------------------------------------------------------------

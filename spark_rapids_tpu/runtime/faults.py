@@ -149,18 +149,15 @@ SITES: Dict[str, str] = {
                 "exactly as if serving.deadlineMs had elapsed there, "
                 "and the ticket's whole device reservation is released "
                 "(DeviceCensus shows zero residual)",
-    "kernel": "Pallas kernel-tier dispatch (ops/pallas/) and encoded-"
-              "execution dispatch (ops/encodings.py) — fires each "
-              "time an operator elects a hand-written kernel or a "
-              "code-space/narrow-lane path, with the kernel family / "
-              "encoded site in the injected-fault record. Kind 'oom' "
-              "is caught by the dispatch gate itself: the operator "
-              "sheds to the sort-based portable tier (or the encoded "
-              "dispatch to the decoded tier) bit-identically "
-              "(tpu_kernel_fallback_total{reason=oom} / "
-              "tpu_encoded_dispatch_total{outcome=oom_shed}); 'fatal' "
+    "kernel": "Encoded-execution dispatch (ops/encodings.py) — fires "
+              "each time an operator elects a code-space/narrow-lane "
+              "path, with the encoded site in the injected-fault "
+              "record. Kind 'oom' is caught by the dispatch gate "
+              "itself: the dispatch sheds to the decoded tier "
+              "bit-identically "
+              "(tpu_encoded_dispatch_total{outcome=oom_shed}); 'fatal' "
               "surfaces as a classified FATAL_DEVICE crash dump whose "
-              "injected-fault record names the kernel",
+              "injected-fault record names the site",
 }
 
 KINDS = ("oom", "ioerror", "corrupt", "fatal", "error", "timeout",

@@ -95,24 +95,10 @@ def jaxpr_sort_operands(jaxpr) -> int:
 
 def jaxpr_sort_operand_total(jaxpr) -> int:
     """TOTAL operands across every `sort` equation — the whole-program
-    sort volume proxy.  The Pallas kernel tier exists to shrink this on
-    the join/filter-heavy tail (each replaced merge-rank probe was two
-    2-operand sorts over build+probe rows); its budget lint asserts the
-    q3/q9/q15-class programs emit strictly fewer sort operands with the
-    tier on."""
+    sort volume proxy (a merge-rank join probe is two 2-operand sorts
+    over build+probe rows)."""
     return sum(len(e.invars) for e in _iter_eqns(jaxpr)
                if e.primitive.name == "sort")
-
-
-def jaxpr_pallas_calls(jaxpr) -> int:
-    """Number of `pallas_call` equations — the hand-written kernel
-    dispatches actually embedded in the program (interpret-mode calls
-    included: the primitive is the same, only its lowering differs).
-    Note _iter_eqns recurses INTO kernel bodies via the equation's
-    jaxpr param, so sorts/scatters inside a kernel would still be
-    counted by the census walkers above."""
-    return sum(1 for e in _iter_eqns(jaxpr)
-               if e.primitive.name == "pallas_call")
 
 
 def _gather_sizes(eqn):
@@ -205,8 +191,7 @@ def plan_program_stats(physical, ctx=None) -> Dict:
             "gather_op_count": jaxpr_gather_count(jx),
             "gather_out_elems": jaxpr_gather_elems(jx),
             "decode_op_count": jaxpr_decode_count(jx),
-            "decode_out_elems": jaxpr_decode_elems(jx),
-            "pallas_call_count": jaxpr_pallas_calls(jx)}
+            "decode_out_elems": jaxpr_decode_elems(jx)}
 
 
 # ---------------------------------------------------------------------------

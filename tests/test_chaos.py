@@ -652,19 +652,10 @@ def test_serving_fault_kind_gates():
 
 
 # ---------------------------------------------------------------------------
-# kernel site: the Pallas tier's fallback rung (ISSUE 11)
+# kernel site: the encoded-execution dispatch gate (ops/encodings.py)
 # ---------------------------------------------------------------------------
 
-PALLAS_ON = {
-    "spark.rapids.tpu.sql.kernels.pallas.enabled": "true",
-    "spark.rapids.tpu.sql.kernels.pallas.segagg": "ON",
-    # tiny-scale fixtures: every span fits a dense table, so force
-    # the replacement the AUTO span policy reserves for big spans
-    "spark.rapids.tpu.sql.kernels.pallas.join.denseReplace": "ON",
-}
-
-
-def _pallas_join_df(s):
+def _join_df(s):
     rng = np.random.default_rng(21)
     fact = s.from_arrow(pa.table({
         "fk": pa.array(rng.integers(0, 40, 3000), pa.int64()),
@@ -676,57 +667,17 @@ def _pallas_join_df(s):
                      how="inner").sort(("v", True, True))
 
 
-def test_kernel_oom_sheds_to_sort_tier_bit_identical():
-    """An injected OOM at the kernel election is the shed signal: the
-    operator falls back onto the sort-based portable tier and the query
-    completes BIT-IDENTICAL — the fallback rung, observable as
-    tpu_kernel_fallback_total{reason=oom}."""
-    from spark_rapids_tpu.obs.registry import KERNEL_FALLBACK
-    clean, _s, _df = run_query(_pallas_join_df, PALLAS_ON)
-    base = KERNEL_FALLBACK.value(kernel="hash_probe_join", reason="oom")
-    chaos, s, _df = run_query(_pallas_join_df, PALLAS_ON,
-                              faults="kernel:oom:nth=1")
-    assert_identical(clean, chaos)
-    assert KERNEL_FALLBACK.value(kernel="hash_probe_join",
-                                 reason="oom") > base
-    assert get_injector(s.conf).log[0]["site"] == "kernel"
-    # the injected-fault record names the kernel that shed
-    assert get_injector(s.conf).log[0]["kernel"] == "hash_probe_join"
-
-
-def test_kernel_fatal_dump_names_kernel(tmp_path):
-    """kind 'fatal' at the kernel site surfaces as a classified
-    FATAL_DEVICE crash dump whose injected-fault record names the
-    kernel family that was dispatching."""
-    settings = {**PALLAS_ON,
-                "spark.rapids.tpu.test.faults": "kernel:fatal:nth=1",
-                "spark.rapids.tpu.coredump.path": str(tmp_path)}
-    s = TpuSession(settings)
-    with pytest.raises(FatalDeviceError) as ei:
-        _pallas_join_df(s).collect()
-    assert classify(ei.value) == FATAL_DEVICE
-    dump = json.load(open(ei.value.dump_path))
-    rec = dump["injected_faults"][0]
-    assert rec["site"] == "kernel" and rec["kind"] == "fatal"
-    assert rec["kernel"] in ("hash_probe_join", "segagg", "compact")
-
-
-def test_compile_and_execute_sites_fire_on_pallas_path():
-    """The pre-existing compile/execute recovery rungs still hold with
-    the kernel tier active: whole-plan compile OOM falls back (eager
-    re-run, kernels still on) and an execute OOM replays — both
-    bit-identical to the clean pallas run."""
-    wp = {**PALLAS_ON, "spark.rapids.tpu.sql.compile.wholePlan": "ON"}
-    clean, _s, _df = run_query(_pallas_join_df, wp)
+def test_compile_and_execute_sites_fire_on_whole_plan_join():
+    """The compile/execute recovery rungs on the engine the chip runs
+    (compile.wholePlan=ON, default conf otherwise): a whole-plan
+    compile OOM falls back (eager re-run) and an execute OOM replays —
+    both bit-identical to the clean whole-plan run."""
+    wp = {"spark.rapids.tpu.sql.compile.wholePlan": "ON"}
+    clean, _s, _df = run_query(_join_df, wp)
     for faults in ("compile:oom:nth=1", "execute:oom:nth=1"):
-        chaos, _s, _df = run_query(_pallas_join_df, wp, faults=faults)
+        chaos, s, _df = run_query(_join_df, wp, faults=faults)
         assert_identical(clean, chaos)
-
-
-def test_kernel_error_kind_propagates_as_query_error():
-    with pytest.raises(InjectedQueryError):
-        run_query(_pallas_join_df, PALLAS_ON,
-                  faults="kernel:error:nth=1")
+        assert fired_sites(s) == {faults.split(":")[0]}
 
 
 def _encoded_probe_df(s):
@@ -766,6 +717,26 @@ def test_kernel_oom_sheds_encoded_probe_to_decoded_tier():
     assert log[0]["site"] == "kernel"
     # the injected-fault record names the encoded dispatch that shed
     assert log[0]["kernel"] == "predicate_code"
+
+
+def test_kernel_fatal_dump_names_kernel(tmp_path):
+    """kind 'fatal' at the kernel site surfaces as a classified
+    FATAL_DEVICE crash dump whose injected-fault record names the
+    encoded dispatch that was electing."""
+    s = TpuSession({"spark.rapids.tpu.test.faults": "kernel:fatal:nth=1",
+                    "spark.rapids.tpu.coredump.path": str(tmp_path)})
+    with pytest.raises(FatalDeviceError) as ei:
+        _encoded_probe_df(s).collect()
+    assert classify(ei.value) == FATAL_DEVICE
+    dump = json.load(open(ei.value.dump_path))
+    rec = dump["injected_faults"][0]
+    assert rec["site"] == "kernel" and rec["kind"] == "fatal"
+    assert rec["kernel"] == "predicate_code"
+
+
+def test_kernel_error_kind_propagates_as_query_error():
+    with pytest.raises(InjectedQueryError):
+        run_query(_encoded_probe_df, faults="kernel:error:nth=1")
 
 
 # ---------------------------------------------------------------------------

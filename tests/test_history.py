@@ -2,7 +2,7 @@
 cost oracle — store round trips (in-process, cross-process, corrupt
 recovery), warm-suite calibration bound, static-cost fallback, serving
 admission prediction + calibration under concurrency, EXPLAIN ANALYZE's
-predicted column + kernel-tier annotations, and the history_report /
+predicted column, and the history_report /
 check_regression triage hooks."""
 import importlib.util
 import json
@@ -367,12 +367,11 @@ def test_serving_prediction_stamped_into_event_log(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# EXPLAIN ANALYZE: predicted column + kernel-tier decisions
+# EXPLAIN ANALYZE: predicted column
 # ---------------------------------------------------------------------------
 
-def test_explain_analyze_predicted_and_kernel_annotations(tmp_path):
-    s = _session(tmp_path, {
-        "spark.rapids.tpu.sql.kernels.pallas.enabled": "true"})
+def test_explain_analyze_predicted_annotation(tmp_path):
+    s = _session(tmp_path)
     t = _tbl(seed=17)
     df = _query(s, t)
     df.collect()                                # seed history (recorded)
@@ -382,32 +381,6 @@ def test_explain_analyze_predicted_and_kernel_annotations(tmp_path):
     assert rep.predicted["basis"] == "exact_history"
     text = rep.render()
     assert "predicted device" in text
-    # the kernel-tier decision annotates the owning node in the tree
-    assert rep.kernel_tiers, "no kernel-tier decisions on a pallas plan"
-    assert "[kernel: " in rep.tree
-    assert any(d.startswith(("pallas:", "sorted:", "runtime:"))
-               for d in rep.kernel_tiers.values())
-
-
-def test_event_log_carries_kernel_plan_meta(tmp_path):
-    """With tracing on and the Pallas tier resolved, the event log's
-    meta embeds kernel_plan() so profile_report renders per-query
-    kernel-tier decisions offline."""
-    log_dir = tmp_path / "events"
-    s = _session(tmp_path, {
-        "spark.rapids.tpu.eventLog.dir": str(log_dir),
-        "spark.rapids.tpu.sql.kernels.pallas.enabled": "true"})
-    _query(s, _tbl(seed=19)).collect()
-    logs = [p for p in os.listdir(log_dir) if p.endswith(".jsonl")]
-    assert logs
-    from spark_rapids_tpu.obs.tracer import read_event_log
-    metas = [read_event_log(str(log_dir / p)).meta for p in logs]
-    assert any(m.get("kernel_plan") for m in metas)
-    # and the offline report surfaces them
-    mod = _load_script("profile_report")
-    lines = mod.kernel_plan_section(
-        next(m for m in metas if m.get("kernel_plan")))
-    assert lines and "kernel tier decisions" in lines[0]
 
 
 # ---------------------------------------------------------------------------
@@ -432,7 +405,7 @@ def test_history_report_renders_real_store(tmp_path, capsys):
     assert "drift" in out
 
 
-def test_profile_diff_self_test_covers_kernels_and_serving(capsys):
+def test_profile_diff_self_test_covers_serving(capsys):
     mod = _load_script("profile_diff")
     assert mod.self_test() == 0
 

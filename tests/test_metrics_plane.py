@@ -659,7 +659,7 @@ def test_metrics_docs_cover_every_registered_family():
 
 
 def test_check_regression_gate(tmp_path, capsys):
-    """Exit 0 on the committed MULTICHIP_r*/KERNELS_r*/... trajectory; a
+    """Exit 0 on the committed MULTICHIP_r*/ENCODINGS_r*/... trajectory; a
     synthetic 2x slowdown of a per-query round exits non-zero
     (acceptance)."""
     mod = _load_script("check_regression")

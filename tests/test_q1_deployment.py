@@ -17,7 +17,7 @@ from spark_rapids_tpu.session import TpuSession, col
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _BENCH = os.path.join(_ROOT, "benchmarks")
 WHOLE = {"spark.rapids.tpu.sql.compile.wholePlan": "ON"}
-STRATEGIES = ("dense", "packed_sort", "lexsort", "pallas", "reduce")
+STRATEGIES = ("dense", "packed_sort", "lexsort", "reduce")
 
 
 @pytest.fixture

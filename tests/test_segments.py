@@ -223,22 +223,6 @@ def test_join_knobs_flip_same_results(knob, jt):
     assert run("true") == run("false")
 
 
-def test_dense_via_sort_flip_same_results():
-    tbl = pa.table({"k": pa.array(["x", "y", "x", None, "y", "z"] * 10),
-                    "v": pa.array(list(range(60)), pa.int64())})
-    from spark_rapids_tpu.plan.aggregates import Count, Max, Min, Sum
-
-    def run(val):
-        s = TpuSession(
-            {"spark.rapids.tpu.sql.agg.denseDomainViaSort": val})
-        return (s.from_arrow(tbl).group_by("k")
-                .agg((Sum(col("v")), "sv"), (Count(col("v")), "cv"),
-                     (Min(col("v")), "mn"), (Max(col("v")), "mx"))
-                .sort("k").collect().to_pydict())
-
-    assert run("true") == run("false")
-
-
 def test_max_sort_operands_flip_same_results():
     tbl = pa.table({"a": pa.array(RNG.integers(0, 4, 100), pa.int64()),
                     "b": pa.array(RNG.integers(0, 4, 100), pa.int64()),

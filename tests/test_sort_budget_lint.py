@@ -59,26 +59,10 @@ def test_scatter_free_outside_dense_groupby(suite_stats):
     """Group-by MIN/MAX, count-distinct, expand_pairs, window and
     inner/outer join paths emit zero scatters; only the dense-domain
     group-by and dense-matched semi/anti queries may carry them (the
-    two no-sort trades — flip-testable below)."""
+    two no-sort trades)."""
     dirty = {n: st["scatter_op_count"] for n, st in suite_stats.items()
              if st["scatter_op_count"] and n not in SCATTER_ALLOWED}
     assert not dirty, f"unexpected scatters: {dirty}"
-
-
-def test_dense_via_sort_makes_whole_suite_scatter_free(tables):
-    """Flipping agg.denseDomainViaSort + join.matchedViaPresence=false
-    removes the last scatters: bounded group-by domains run through the
-    packed single-sort-lane kernel, semi/anti matched flags go back to
-    the sorted offs table, and the full 22-query suite emits no scatter
-    at all — the all-scatter-free configuration stays available."""
-    s = TpuSession({"spark.rapids.tpu.sql.agg.denseDomainViaSort": "true",
-                    "spark.rapids.tpu.sql.join.matchedViaPresence":
-                        "false"})
-    for name in sorted(SCATTER_ALLOWED, key=lambda q: int(q[1:])):
-        q = tpch.QUERIES[name](s, tables).physical()
-        st = plan_program_stats(q)
-        assert st["scatter_op_count"] == 0, (name, st)
-        assert st["sort_operand_max"] <= 2, (name, st)
 
 
 def test_small_domain_dense_groupby_lowers_without_scatter(suite_stats,
@@ -120,54 +104,6 @@ def test_small_domain_dense_groupby_lowers_without_scatter(suite_stats,
     assert not G.dense_is_masked(wide)
     text = jax.jit(real(wide, specs, capacity)).lower(*shapes).as_text()
     assert "scatter" in text
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel-tier sort budget: the hash/accumulate kernels must keep
-# removing sorts from the join/agg-heavy tail (ISSUE 11)
-# ---------------------------------------------------------------------------
-
-# The attribution plane pinned the suite tail on these queries' sort-
-# lowered probe/aggregate segments; the kernel tier replaces merge-rank
-# probes, dense-table builds and packed group-by sorts, so their whole-
-# plan programs must emit strictly FEWER sort operands with it on.
-PALLAS_BUDGET_QUERIES = ("q3", "q9", "q15")
-
-PALLAS_ON = {
-    "spark.rapids.tpu.sql.kernels.pallas.enabled": "true",
-    "spark.rapids.tpu.sql.kernels.pallas.segagg": "ON",
-    # tiny-scale fixtures: every span fits a dense table, so force
-    # the replacement the AUTO span policy reserves for big spans
-    "spark.rapids.tpu.sql.kernels.pallas.join.denseReplace": "ON",
-}
-
-
-def test_pallas_tier_sort_operand_budget(tables, suite_stats):
-    """With the kernel tier on, q3/q9/q15 emit strictly fewer total
-    sort operands (and real pallas_call kernels), while the per-sort
-    width budget (<= 2 operands) still holds program-wide."""
-    on = TpuSession(PALLAS_ON)
-    for name in PALLAS_BUDGET_QUERIES:
-        st_off = suite_stats[name]
-        st_on = plan_program_stats(tpch.QUERIES[name](on, tables)
-                                   .physical())
-        assert st_on["sort_operand_total"] < \
-            st_off["sort_operand_total"], (name, st_on, st_off)
-        assert st_on["pallas_call_count"] > 0, (name, st_on)
-        assert st_on["sort_operand_max"] <= 2, (name, st_on)
-        assert st_off["pallas_call_count"] == 0, (name, st_off)
-
-
-def test_pallas_off_programs_identical_to_default(tables, suite_stats):
-    """kernels.pallas.enabled=false is the default: a session with the
-    conf explicitly off emits byte-equal program stats to the default
-    session (the bit-identical-plans half of the acceptance gate)."""
-    off = TpuSession(
-        {"spark.rapids.tpu.sql.kernels.pallas.enabled": "false"})
-    for name in PALLAS_BUDGET_QUERIES:
-        st = plan_program_stats(tpch.QUERIES[name](off, tables)
-                                .physical())
-        assert st == suite_stats[name], name
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +182,7 @@ def test_encoded_off_key_discriminant_is_neutral(tables):
     for name in ENCODED_BUDGET_QUERIES:
         q = tpch.QUERIES[name](off, tables).physical()
         key = plan_structure_key(q.root, off.conf)
-        assert key is None or len(key) == 4, name  # no 5th enc element
+        assert key is None or len(key) == 3, name  # no 4th enc element
 
         def walk(n):
             if isinstance(n, HostScanExec):
@@ -316,17 +252,3 @@ def test_ds_traceable_set_does_not_shrink(ds_suite_stats):
     broken = {n for n, st in ds_suite_stats.items()
               if st is None and n not in DS_UNTRACEABLE}
     assert not broken, f"queries no longer whole-plan traceable: {broken}"
-
-
-def test_dense_via_sort_oracle_match(tables):
-    """The dense->packed swap is a pure layout change: device results
-    must equal the CPU oracle exactly on the dense-domain queries."""
-    dev = TpuSession(
-        {"spark.rapids.tpu.sql.agg.denseDomainViaSort": "true"})
-    cpu = TpuSession({"spark.rapids.tpu.sql.enabled": "false"})
-    from spark_rapids_tpu.session import DataFrame
-    for name in ("q1", "q12", "q22"):
-        df = tpch.QUERIES[name](dev, tables)
-        got = df.collect().to_pydict()
-        want = DataFrame(df._plan, cpu).collect().to_pydict()
-        assert got == want, name
