@@ -731,7 +731,10 @@ COMPILE_BG_ENABLED = conf(
     "candidate programs for segment i+1 are speculatively AOT-compiled "
     "(lower().compile() over placeholder shapes) for the predicted "
     "seam output buckets, so the seam sync usually finds the next "
-    "program ready. Mispredicted candidates are dropped; injected "
+    "program ready. Only a seam the process has not seen speculates: "
+    "a warm collect finds every next segment's program in the "
+    "process-wide executable cache, submits nothing and waits for no "
+    "thread. Mispredicted candidates are dropped; injected "
     "`compile` faults from background tasks surface on the consuming "
     "thread with the same recovery ladder as inline compiles.")
 

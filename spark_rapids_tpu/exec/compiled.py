@@ -34,6 +34,7 @@ benchmark measures (BASELINE.md).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -522,6 +523,13 @@ def _anchors_match(anchors, root: PlanNode, pairs) -> bool:
 _PLAN_EXEC_CACHE: Dict[tuple, tuple] = {}
 _PLAN_EXEC_LOCK = threading.Lock()
 
+#: canonical key of a split plan's segment -> the capacity key its seam
+#: re-bucketed to the last time it ran (SplitCompiledPlan.collect):
+#: what `_speculate` asks the cache for before it submits anything.  A
+#: record lives no longer than the `_PLAN_EXEC_CACHE` entry under the
+#: same key (replaced or evicted, it goes), under the same lock.
+_SEAM_BUCKET_CACHE: Dict[tuple, tuple] = {}
+
 
 def _plan_cache_get(key, root, pairs):
     with _PLAN_EXEC_LOCK:
@@ -584,8 +592,28 @@ def _plan_cache_put(key, entry: tuple, conf: TpuConf) -> None:
     bound = conf.get(PLAN_CACHE_ENTRIES)
     with _PLAN_EXEC_LOCK:
         _PLAN_EXEC_CACHE[key] = entry
+        _SEAM_BUCKET_CACHE.pop(key, None)
         while len(_PLAN_EXEC_CACHE) > bound:
-            _PLAN_EXEC_CACHE.pop(next(iter(_PLAN_EXEC_CACHE)))
+            old = next(iter(_PLAN_EXEC_CACHE))
+            _PLAN_EXEC_CACHE.pop(old)
+            _SEAM_BUCKET_CACHE.pop(old, None)
+
+
+def _seam_bucket_put(key: Optional[tuple], bucket: tuple) -> None:
+    """Remember the bucket the seam of the segment cached under `key`
+    shrank to (nothing for an uncached segment: key None, or no entry)."""
+    if key is None:
+        return
+    with _PLAN_EXEC_LOCK:
+        if key in _PLAN_EXEC_CACHE:
+            _SEAM_BUCKET_CACHE[key] = bucket
+
+
+def _seam_bucket_get(key: Optional[tuple]) -> Optional[tuple]:
+    if key is None:
+        return None
+    with _PLAN_EXEC_LOCK:
+        return _SEAM_BUCKET_CACHE.get(key)
 
 
 #: Trace-time counters under this prefix count once per RUN of the
@@ -871,16 +899,24 @@ class CompiledPlan:
         self._leaf_overrides = {}
         if self._cache_key is None:
             self._cache_key = self._build_cache_key(flat_in, in_specs)
-        if self._cache_key is not None and pairs is not None:
-            anchors = _plan_anchors(self.root, pairs)
-            if anchors is not None:
-                from ..obs.registry import PLAN_CACHE
-                PLAN_CACHE.inc(outcome="miss")
-                _plan_cache_put(self._cache_key,
-                                (compiled, self._out_specs,
-                                 self._out_layout, self._host_metrics,
-                                 self._run_counts, self._cost, anchors),
-                                self.conf)
+        if pairs is not None:
+            self.file_program(pairs)
+
+    def file_program(self, pairs) -> None:
+        """Put the compiled program into the process-wide cache under
+        this plan's canonical key, anchored on `pairs`' host objects."""
+        if self._cache_key is None:
+            return
+        anchors = _plan_anchors(self.root, pairs)
+        if anchors is None:
+            return
+        from ..obs.registry import PLAN_CACHE
+        PLAN_CACHE.inc(outcome="miss")
+        _plan_cache_put(self._cache_key,
+                        (self._compiled, self._out_specs,
+                         self._out_layout, self._host_metrics,
+                         self._run_counts, self._cost, anchors),
+                        self.conf)
 
     def ensure_compiled(self, ctx: ExecContext) -> None:
         """Compile (or adopt a cached executable) without executing —
@@ -1362,6 +1398,9 @@ def _walk_nodes(n: PlanNode):
         yield from _walk_nodes(c)
 
 
+_SPLIT_PLAN_UIDS = itertools.count()
+
+
 class SplitCompiledPlan:
     """Segmented whole-plan execution: the plan splits at seam nodes
     where the live row count collapses (join subtrees under aggregates,
@@ -1383,6 +1422,13 @@ class SplitCompiledPlan:
         self.conf = conf
         self.seams = list(seams)            # innermost-first
         self.leaves = [DeviceResidentScanExec(s) for s in self.seams]
+        #: the compile service's task keys start with this: an id()
+        #: would come back with another plan once this one is collected
+        self._uid = next(_SPLIT_PLAN_UIDS)
+        #: [count of leaf swaps]: a speculative thunk reads it to see
+        #: whether the tree it traces is still the one it was submitted
+        #: for (a cell, so the thunk need not hold the plan)
+        self._tree_epoch = [0]
         self._parent_idx = []
         scope = list(self.seams[1:]) + [root]
         for seam, leaf, upper in zip(self.seams, self.leaves, scope):
@@ -1400,11 +1446,13 @@ class SplitCompiledPlan:
         seams[i], so the swap above it never changes what segment i
         traces.  A seam with several parents (shared subtree) swaps at
         every link."""
+        self._tree_epoch[0] += 1
         for links, leaf in zip(self._parent_idx, self.leaves):
             for parent, ci in links:
                 parent.children[ci] = leaf
 
     def _restore_leaves(self) -> None:
+        self._tree_epoch[0] += 1
         for links, seam in zip(self._parent_idx, self.seams):
             for parent, ci in links:
                 parent.children[ci] = seam
@@ -1420,7 +1468,7 @@ class SplitCompiledPlan:
             from ..runtime.compile_service import (background_enabled,
                                                    get_service)
             if background_enabled(ctx.conf):
-                task = get_service(ctx.conf).take((id(self), i, key))
+                task = get_service(ctx.conf).take((self._uid, i, key))
                 if task is not None:
                     try:
                         # the wait IS compile wall from the query's
@@ -1430,8 +1478,11 @@ class SplitCompiledPlan:
                         with ctx.tracer.span("compile.wait", "compile",
                                              segment=i):
                             plan = task.wait()
-                        progs[key] = plan
-                        ctx.bump("compile_background_used")
+                        # None: the thunk of an earlier collect of this
+                        # plan, started after its leaves were restored
+                        if plan is not None:
+                            progs[key] = plan
+                            ctx.bump("compile_background_used")
                     except TimeoutError:
                         plan = None      # hung pool: compile inline
         if plan is None:
@@ -1493,7 +1544,10 @@ class SplitCompiledPlan:
         """AOT-compile candidate programs for segment i+1 on the compile
         service while segment i executes — the seam sync then usually
         finds the next program ready instead of paying its compile on
-        the critical path."""
+        the critical path.  Only at a seam the process has not seen: a
+        seam that remembers its bucket (_SEAM_BUCKET_CACHE) and finds
+        that bucket's program in _PLAN_EXEC_CACHE submits nothing, and
+        _segment adopts the program when the sync has confirmed it."""
         nxt = i + 1
         if nxt > len(self.seams):
             return
@@ -1517,19 +1571,44 @@ class SplitCompiledPlan:
             return
         service = get_service(ctx.conf)
         conf = ctx.conf
-        for cap in self._candidate_caps(i, cap_in, conf):
+        # the bucket this seam shrank to last time is the one prediction
+        # worth making; the structural guesses are for a seam never seen
+        remembered = _seam_bucket_get(seg._cache_key)
+        caps = list(remembered) if remembered is not None \
+            else self._candidate_caps(i, cap_in, conf)
+        epoch, at_submit = self._tree_epoch, self._tree_epoch[0]
+        for cap in caps:
             key = (cap,)
             if key in self._programs[nxt]:
                 continue
             placeholder = [self._placeholder_batch(seam_out, cap)]
             plan = self._new_segment(
                 nxt, conf, leaf_overrides={id(self.leaves[i]): placeholder})
+            # leaves, lanes and key are read HERE, on the collecting
+            # thread, while the seam leaves stand in the tree: a thunk
+            # nobody waits for may start after collect has restored them
+            pairs = plan._leaf_batches(ctx)
+            flat_in, in_specs = plan._flatten_inputs(pairs)
+            plan._cache_key = plan._build_cache_key(flat_in, in_specs)
+            if remembered is not None and plan._cache_key is not None \
+                    and _plan_cache_get(plan._cache_key, plan.root,
+                                        pairs) is not None:
+                ctx.bump("compile_speculative_cached")
+                return
 
-            def thunk(plan=plan, conf=conf):
-                plan.aot_compile(ExecContext(conf))
+            def thunk(plan=plan, conf=conf, pairs=pairs, flat_in=flat_in,
+                      in_specs=in_specs):
+                if epoch[0] != at_submit:
+                    return None          # the tree moved on: trace nothing
+                plan.aot_compile(ExecContext(conf), flat_in, in_specs)
+                # filed under the submitter's key, or not at all: a
+                # trace that the restore overtook read another tree
+                if epoch[0] != at_submit:
+                    return None
+                plan.file_program(pairs)
                 return plan
 
-            service.submit((id(self), nxt, key), thunk)
+            service.submit((self._uid, nxt, key), thunk)
             ctx.bump("compile_speculative_submitted")
 
     @staticmethod
@@ -1595,6 +1674,7 @@ class SplitCompiledPlan:
                         outs, ctx, _node_scope(self.seams[i]))
                     leaf.batches = sliced
                     key = tuple(db.capacity for db in sliced)
+                    _seam_bucket_put(seg._cache_key, key)
                 for k, v in (("overhead.seam_count", 1),
                              ("overhead.seam_rows", rows),
                              ("overhead.seam_bytes",

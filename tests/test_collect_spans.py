@@ -111,9 +111,16 @@ def test_counters_repeat_over_warm_collects(shape):
         m = df.metrics()
         counts.append({k: m.get(k, 0) for k in (
             "host_syncs", "exec_dispatches", "overhead.seam_count",
-            "compile_speculative_submitted")})
+            "compile_speculative_submitted", "compile_speculative_cached",
+            "compile_background_used", "whole_plan_structure_hits")})
     assert counts[0] == counts[1], counts
-    assert counts[0]["exec_dispatches"] == (3 if shape == "seams" else 1)
+    seams = 2 if shape == "seams" else 0
+    assert counts[0]["exec_dispatches"] == seams + 1
+    # a warm collect adopts every program and speculates at no seam
+    assert counts[0]["whole_plan_structure_hits"] == seams + 1
+    assert counts[0]["compile_speculative_cached"] == seams
+    assert counts[0]["compile_speculative_submitted"] == 0
+    assert counts[0]["compile_background_used"] == 0
 
 
 @pytest.mark.parametrize("shape", list(SHAPES))

@@ -402,16 +402,35 @@ def _split_conf(extra=None):
         **(extra or {})})
 
 
-def _split_query(s):
-    n = 5000
-    t1 = pa.table({"k": (np.arange(n) % 50).astype(np.int64),
-                   "v": np.random.default_rng(0).random(n)})
-    t2 = pa.table({"k": np.arange(50, dtype=np.int64),
-                   "w": np.arange(50, dtype=np.float64)})
-    return (s.from_arrow(t1).join(s.from_arrow(t2), on="k")
-            .filter(col("v") > lit(0.5))
-            .group_by("k").agg((Sum(col("w")), "sw"))
+def _split_tables(n=5000):
+    return (pa.table({"k": (np.arange(n) % 50).astype(np.int64),
+                      "v": np.random.default_rng(0).random(n)}),
+            pa.table({"k": np.arange(50, dtype=np.int64),
+                      "w": np.arange(50, dtype=np.float64)}))
+
+
+def _split_query(s, tables=None, under_join=None):
+    """Three segments: the join, the aggregate's 50 groups, the sort.
+    `under_join`: the filter's literal, with the filter moved below the
+    join, so that the first seam's row count follows it (0.5 leaves
+    2,500 of 5,000 rows, the 4,096 bucket; 0.9 leaves 500: 1,024)."""
+    t1, t2 = tables or _split_tables()
+    left = s.from_arrow(t1)
+    if under_join is not None:
+        left = left.filter(col("v") > lit(under_join))
+    df = left.join(s.from_arrow(t2), on="k")
+    if under_join is None:
+        df = df.filter(col("v") > lit(0.5))
+    return (df.group_by("k").agg((Sum(col("w")), "sw"))
             .sort(("sw", False, False)).limit(10))
+
+
+def _same_answer(out, df) -> bool:
+    o = _oracle(df)
+    return out.column("k").to_pylist() == o.column("k").to_pylist() and \
+        all(abs(a - b) <= 1e-9 * max(1.0, abs(b))
+            for a, b in zip(out.column("sw").to_pylist(),
+                            o.column("sw").to_pylist()))
 
 
 def test_background_segment_compiles_are_adopted_and_correct():
@@ -422,11 +441,7 @@ def test_background_segment_compiles_are_adopted_and_correct():
     assert ctx.metrics.get("whole_plan_split_queries") == 1
     # downstream segments came from the background compile service
     assert ctx.metrics.get("compile_background_used", 0) >= 1
-    o = _oracle(df)
-    assert out.column("k").to_pylist() == o.column("k").to_pylist()
-    assert all(abs(a - b) < 1e-9 * max(1.0, abs(b))
-               for a, b in zip(out.column("sw").to_pylist(),
-                               o.column("sw").to_pylist()))
+    assert _same_answer(out, df)
 
 
 def test_concurrent_segment_traces_do_not_cross():
@@ -464,6 +479,216 @@ def test_background_disabled_still_correct():
     assert not ctx.metrics.get("compile_background_used")
     o = _oracle(df)
     assert out.column("k").to_pylist() == o.column("k").to_pylist()
+
+
+_SPEC = ("compile_speculative_submitted", "compile_speculative_cached",
+         "compile_background_used", "whole_plan_structure_hits",
+         "compile_cache_misses")
+
+
+def _collect_counts(df):
+    out = df.collect()
+    m = df.metrics()
+    return out, {k: m.get(k, 0) for k in _SPEC}
+
+
+#: what every collect of _split_query reads once its three programs are
+#: cached and both seams remember their bucket
+_WARM = {"compile_speculative_submitted": 0, "compile_speculative_cached": 2,
+         "compile_background_used": 0, "whole_plan_structure_hits": 3,
+         "compile_cache_misses": 0}
+
+
+def test_replanned_split_collects_speculate_only_when_cold():
+    """DataFrame.collect() plans anew, so each collect has a new
+    SplitCompiledPlan with no programs of its own.  The first collect of
+    a process speculates; from the second on every seam finds its next
+    segment's program in the process-wide cache, submits nothing to the
+    compile service and waits for no thread."""
+    from spark_rapids_tpu.testing import clear_compiled_caches
+    clear_compiled_caches()
+    s = _split_conf()
+    df = _split_query(s)
+    out, cold = _collect_counts(df)
+    assert cold["compile_speculative_submitted"] == 4    # 2 seams x 2 guesses
+    assert cold["compile_background_used"] == 2
+    assert cold["compile_speculative_cached"] == 0
+    assert _same_answer(out, df)
+    for _ in range(2):
+        out, warm = _collect_counts(df)
+        assert warm == _WARM
+        assert df.metrics()["whole_plan_split_queries"] == 1
+        assert _same_answer(out, df)
+    # a DataFrame built again over the same tables is the same traffic
+    # (a serving ticket, bench.py): it adopts as well
+    tables = _split_tables()
+    _collect_counts(_split_query(s, tables))
+    out, again = _collect_counts(_split_query(s, tables))
+    assert again == _WARM
+
+
+def _successor_keys():
+    """Keys of the cached programs that read a seam's output."""
+    from spark_rapids_tpu.exec import compiled as C
+    return [k for k in C._PLAN_EXEC_CACHE
+            if any(name == "DeviceResidentScanExec" for name, _ in k[1])]
+
+
+@pytest.mark.parametrize("case", [
+    "cold_process", "new_tables", "bucket_crossing", "evicted_entry",
+    "background_off", "compile_fault"])
+def test_speculation_keeps_todays_behaviour_off_the_warm_path(case):
+    """Where a seam has nothing to go by, or what it remembers no longer
+    holds, the collect behaves as it did before seams remembered: it
+    speculates (or compiles inline), and the answer is the oracle's."""
+    from spark_rapids_tpu.exec import compiled as C
+    from spark_rapids_tpu.testing import clear_compiled_caches
+    clear_compiled_caches()
+    conf = {
+        "background_off":
+            {"spark.rapids.tpu.compile.background.enabled": "false"},
+        # one candidate a seam, and inputs of one bucket (1,000 rows),
+        # so that the candidate is the program the seam waits for: hit
+        # 1 is segment 0's inline compile, hit 2 the background task
+        "compile_fault":
+            {"spark.rapids.tpu.compile.background.speculateBuckets": "1",
+             "spark.rapids.tpu.test.faults": "compile:oom:nth=2"},
+    }.get(case)
+    s = _split_conf(conf)
+    tables = _split_tables(1000 if case == "compile_fault" else 5000)
+    df = _split_query(s, tables,
+                      under_join=0.5 if case == "bucket_crossing" else None)
+    if case not in ("cold_process", "compile_fault"):
+        for _ in range(2):
+            _collect_counts(df)
+    if case == "new_tables":
+        # same shape, other host objects: the anchors differ
+        df = _split_query(s)
+    elif case == "bucket_crossing":
+        # a lifted literal keeps every key and moves the row count
+        # across a bucket boundary: 2,500 survivors -> 500
+        assert C._SEAM_BUCKET_CACHE and \
+            set(C._SEAM_BUCKET_CACHE.values()) == {(4096,), (1024,)}
+        df = _split_query(s, tables, under_join=0.9)
+    elif case == "evicted_entry":
+        gone = _successor_keys()
+        assert len(gone) >= 2
+        for k in gone:
+            C._PLAN_EXEC_CACHE.pop(k)
+    out, got = _collect_counts(df)
+    assert _same_answer(out, df)
+    want = {
+        # nothing remembered: both structural guesses at both seams
+        "cold_process": dict(compile_speculative_submitted=4,
+                             compile_background_used=2,
+                             compile_speculative_cached=0,
+                             whole_plan_structure_hits=0),
+        # no adoption across tables; segment 0's new entry took the
+        # old record with it, so both guesses again
+        "new_tables": dict(compile_speculative_submitted=4,
+                           compile_background_used=2,
+                           compile_speculative_cached=0,
+                           whole_plan_structure_hits=0),
+        # seam 0 finds the 4,096-row program and submits nothing; the
+        # sync says 1,024: segment 1 adopts the program that the cold
+        # collect's "full collapse" guess filed, and its seam, seen for
+        # the first time, speculates (one guess: both are 1,024)
+        "bucket_crossing": dict(compile_speculative_cached=1,
+                                compile_speculative_submitted=1,
+                                compile_background_used=1,
+                                compile_cache_misses=0,
+                                whole_plan_structure_hits=2),
+        # seam 0 remembers 1,024... and submits that bucket alone; the
+        # program it files replaces segment 1's entry and record, so
+        # seam 1 guesses twice
+        "evicted_entry": dict(compile_speculative_cached=0,
+                              compile_speculative_submitted=3,
+                              compile_background_used=2,
+                              whole_plan_structure_hits=1),
+        "background_off": dict(compile_speculative_submitted=0,
+                               compile_background_used=0,
+                               compile_speculative_cached=0,
+                               whole_plan_structure_hits=3),
+        # re-raised at the seam, on the collecting thread: the ladder
+        # answers with the eager engine
+        "compile_fault": dict(compile_speculative_submitted=1,
+                              compile_background_used=0),
+    }[case]
+    assert {k: got[k] for k in want} == want, got
+    if case == "compile_fault":
+        from spark_rapids_tpu.runtime.faults import get_injector
+        assert [(r["site"], r["hit"])
+                for r in get_injector(s.conf).log] == [("compile", 2)]
+        assert df.metrics().get("whole_plan_fallbacks", 0) >= 1
+        return
+    if case == "bucket_crossing":
+        # the record moved on
+        assert set(C._SEAM_BUCKET_CACHE.values()) == {(1024,)}
+    # and the collect after it is warm again
+    out, after = _collect_counts(df)
+    assert _same_answer(out, df)
+    if case == "background_off":
+        assert after == {**_WARM, "compile_speculative_cached": 0}
+    else:
+        assert after == _WARM
+
+
+@pytest.mark.parametrize("restore", ["before_the_thunk", "during_its_trace"])
+def test_late_speculative_thunk_files_nothing(monkeypatch, restore):
+    """A candidate nobody waits for may start after collect has put the
+    seams back into the tree, or be overtaken by that while it traces.
+    It used to trace the WHOLE plan from the base tables then and file
+    that program; now it sees that the tree moved on and files nothing
+    (and, started late, traces nothing)."""
+    from spark_rapids_tpu.exec import compiled as C
+    from spark_rapids_tpu.runtime.compile_service import CompileService
+    from spark_rapids_tpu.testing import clear_compiled_caches
+    clear_compiled_caches()
+    built, results, filed = [], [], []
+
+    def theirs(keys):
+        # a last-segment program over a 16,384-row input (other thunks
+        # file theirs meanwhile)
+        return [k for k in keys if not k[3] and k[2][0][0] == (16384,)]
+    real_build, real_submit = C.build_plan, CompileService.submit
+    real_aot = C.CompiledPlan.aot_compile
+
+    def build(root, ctx):
+        built.append(real_build(root, ctx))
+        return built[-1]
+
+    def overtaken(self, *args, **kw):
+        real_aot(self, *args, **kw)
+        built[-1]._restore_leaves()         # the collect ended meanwhile,
+        built[-1]._install_leaves()         # and the next one began
+
+    def submit(self, key, fn):
+        if key[1:] != (2, (16384,)):        # the last seam's "no collapse"
+            return real_submit(self, key, fn)
+        if restore == "before_the_thunk":
+            results.append(fn)              # run it after the collect
+        else:
+            before = set(C._PLAN_EXEC_CACHE)
+            monkeypatch.setattr(C.CompiledPlan, "aot_compile", overtaken)
+            results.append(fn())
+            monkeypatch.setattr(C.CompiledPlan, "aot_compile", real_aot)
+            filed.extend(theirs(set(C._PLAN_EXEC_CACHE) - before))
+        return real_submit(self, key, lambda: None)
+    monkeypatch.setattr(C, "build_plan", build)
+    monkeypatch.setattr(CompileService, "submit", submit)
+    s = _split_conf()
+    df = _split_query(s)
+    out, cold = _collect_counts(df)
+    assert cold["compile_speculative_submitted"] == 4 and len(results) == 1
+    if restore == "before_the_thunk":
+        before = set(C._PLAN_EXEC_CACHE)
+        results = [results[0]()]
+        filed.extend(theirs(set(C._PLAN_EXEC_CACHE) - before))
+    assert results == [None] and filed == []
+    # no program over the base tables under a last segment's key
+    assert not [k for k in C._PLAN_EXEC_CACHE if not k[3]
+                and any(name == "HostScanExec" for name, _ in k[1])]
+    assert _same_answer(out, df)
 
 
 def test_compile_service_dedupes_and_reraises():

@@ -487,8 +487,9 @@ def test_second_seam_keeps_its_speculative_program(monkeypatch):
     """A two-seam plan: every later segment runs the program that was
     compiled speculatively against the seam's DENSE output (no inline
     recompile for a drifted signature), and a second collect of the
-    same DataFrame finds the seam's own program in the module-level
-    cache: nothing is traced again."""
+    same DataFrame, planned anew, finds the seam's own program and all
+    three segments' in the module-level caches: nothing is traced
+    again, and nothing is submitted to the compile service."""
     from spark_rapids_tpu.exec import compiled as C
     traces = []
     real = C._seam_trace
@@ -504,19 +505,27 @@ def test_second_seam_keeps_its_speculative_program(monkeypatch):
     C._SEAM_CACHE.clear()
     s = TpuSession(SPLIT)
     df = SEAM_CASES["left_outer"][0](s)
-    for _collect in range(2):
+    C._PLAN_EXEC_CACHE.clear()
+    for warm in (False, True):
         ctx = ExecContext(s.conf)
         got = df.physical().collect(ctx)
         m = ctx.metrics
         assert not m.get("whole_plan_fallbacks")
         assert m["overhead.seam_lazy_count"] == 1
-        assert m.get("compile_background_used", 0) >= 1
         # three segments, each program obtained once: compiled inline
         # (a miss), in the background, or adopted from the structure
         # cache; a drifted speculative program would add a miss
         assert m.get("compile_cache_misses", 0) + \
             m.get("compile_background_used", 0) + \
             m.get("whole_plan_structure_hits", 0) == 3
+        if warm:
+            # both seams remember their bucket and find its program
+            assert m.get("whole_plan_structure_hits") == 3
+            assert m.get("compile_speculative_cached") == 2
+            assert not m.get("compile_speculative_submitted")
+            assert not m.get("compile_background_used")
+        else:
+            assert m.get("compile_background_used", 0) >= 1
     assert traces == [(df.physical().root.child.child._node_id, 1024)]
     assert len(C._SEAM_CACHE) == 1
     want = DataFrame(df._plan, TpuSession(CPU)).collect()
