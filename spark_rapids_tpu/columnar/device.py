@@ -284,7 +284,7 @@ def _arrow_column_to_device(arr: pa.Array, dt: t.DataType, capacity: int,
                                      policy)
 
     dictionary = None
-    hi = None
+    hi_np = None
     enc = None
     if isinstance(dt, t.StringType):
         if policy is not None and policy.dict_sort_scan:
@@ -311,9 +311,8 @@ def _arrow_column_to_device(arr: pa.Array, dt: t.DataType, capacity: int,
         if dt.is_wide:
             lanes = _decimal128_lanes(arr)
             data_np = _pad(lanes[:, 0].view(np.int64), capacity)
-            hi_np = _pad(lanes[:, 1].view(np.int64), capacity)
             # hi lane needs sign-correct padding of 0 which is fine (value 0)
-            hi = jnp.asarray(hi_np)
+            hi_np = _pad(lanes[:, 1].view(np.int64), capacity)
         else:
             lanes = _decimal128_lanes(arr)
             data_np = _pad(lanes[:, 0].view(np.int64), capacity)
@@ -343,7 +342,7 @@ def _arrow_column_to_device(arr: pa.Array, dt: t.DataType, capacity: int,
     # dtype promotion wherever full width is needed.  DOUBLE's int64
     # lane is a BITCAST (never narrowed); string codes stay int32.
     if (policy is not None and policy.narrow_lanes and narrow_ok and
-            enc is None and hi is None and n and
+            enc is None and hi_np is None and n and
             data_np.dtype.kind == "i" and
             not isinstance(dt, (t.DoubleType, t.StringType, t.NullType))):
         live = data_np[:n][validity_np[:n]]
@@ -358,8 +357,20 @@ def _arrow_column_to_device(arr: pa.Array, dt: t.DataType, capacity: int,
                 count_dispatch("narrow_upload")
 
     put = (lambda x: jax.device_put(x, device)) if device is not None else jnp.asarray
-    return DeviceColumn(put(data_np), put(validity_np), dt, dictionary, hi,
-                        enc=enc)
+    return DeviceColumn(put(data_np), put(validity_np), dt, dictionary,
+                        None if hi_np is None else put(hi_np), enc=enc)
+
+
+def _unsplit(device):
+    """Where a ragged column's lanes go when flat lanes go to `device`:
+    offsets (rows + 1) and value lanes (a bucket of their own) do not fit
+    a split of the rows, so under a NamedSharding they live whole on every
+    device of its mesh (GSPMD still partitions the flat columns around
+    them); anything else is taken as it is."""
+    if isinstance(device, jax.sharding.NamedSharding):
+        return jax.sharding.NamedSharding(device.mesh,
+                                          jax.sharding.PartitionSpec())
+    return device
 
 
 def _arrow_list_to_device(arr: pa.Array, dt: t.ArrayType, capacity: int,
@@ -368,6 +379,7 @@ def _arrow_list_to_device(arr: pa.Array, dt: t.ArrayType, capacity: int,
     """ListArray -> ragged device column: int32 offsets (row capacity+1)
     + flat values lane in its own bucket.  Null rows get empty spans so
     kernels never need the row validity to bound a segment."""
+    device = _unsplit(device)
     arr = arr.combine_chunks() if isinstance(arr, pa.ChunkedArray) else arr
     n = len(arr)
     if n:

@@ -206,36 +206,41 @@ def _bare_spec(spec):
             static_rows, origin, has_sel, thin)
 
 
-def _shard_batch(db: DeviceBatch, mesh) -> DeviceBatch:
-    """Place a batch's lanes row-sharded over the mesh (replicated when
-    the capacity doesn't divide the mesh)."""
-    from jax.sharding import NamedSharding, PartitionSpec
-    from ..parallel.mesh import SHARD_AXIS
-    n = mesh.devices.size
-    spec = PartitionSpec(SHARD_AXIS) if db.capacity % n == 0 \
-        else PartitionSpec()
-    sh = NamedSharding(mesh, spec)
-    rep = NamedSharding(mesh, PartitionSpec())
-    cols = []
-    for c in db.columns:
-        if c.offsets is not None:
-            # ragged columns: offsets (rows+1) and value lanes don't fit
-            # the row sharding — replicate; GSPMD still partitions the
-            # flat columns around them
-            cols.append(DeviceColumn(
-                jax.device_put(c.data, rep),
-                jax.device_put(c.validity, rep),
-                c.dtype, c.dictionary, None,
-                offsets=jax.device_put(c.offsets, rep),
-                elem_valid=jax.device_put(c.elem_valid, rep)))
-            continue
-        cols.append(DeviceColumn(
-            jax.device_put(c.data, sh),
-            jax.device_put(c.validity, sh),
-            c.dtype, c.dictionary,
-            None if c.data_hi is None
-            else jax.device_put(c.data_hi, sh)))
-    return DeviceBatch(cols, db.num_rows, db.names, db.origin_file)
+def _mesh_id(mesh) -> tuple:
+    """A mesh as a hashable key part: its axis and its devices."""
+    return (mesh.axis_names, tuple(d.id for d in mesh.devices.flat))
+
+
+def _mesh_sig(mesh, flat_in) -> tuple:
+    """The mesh part of a program's cache key: the mesh (axis, device
+    ids) and each input's partition spec, so a mesh program and a
+    one-chip program of the same plan never share an entry."""
+    from jax.sharding import NamedSharding
+    return (_mesh_id(mesh), tuple(
+        tuple(a.sharding.spec) if isinstance(a.sharding, NamedSharding)
+        else None for a in flat_in))
+
+
+def _unsplit_lanes(flat_in) -> int:
+    """Input lanes that are not split over the chips: whole on every
+    chip (`_upload_sharded`: a capacity the mesh does not divide, a
+    ragged column) or whole on one.  Read off the lanes' shardings where
+    they are launched, however they were placed."""
+    return sum(1 for a in flat_in
+               if a.ndim and a.sharding.is_fully_replicated)
+
+
+def _upload_sharded(hb, conf: TpuConf, enc_cols, mesh) -> DeviceBatch:
+    """One host batch onto the mesh, host -> shards in one step a lane
+    (no whole copy staged on chip 0): its rows split over the chips, or
+    whole on every chip where the capacity does not divide the mesh
+    (`to_device` keeps a ragged column's lanes whole either way)."""
+    from ..parallel.mesh import replicated, row_sharding
+    cap = bucket_capacity(max(hb.num_rows, 1), conf)
+    sh = row_sharding(mesh) if cap % mesh.devices.size == 0 \
+        else replicated(mesh)
+    return to_device(hb, conf, capacity=cap, encoded_cols=enc_cols,
+                     device=sh)
 
 
 #: key -> (weakref(table), device batches, nbytes); insertion order IS
@@ -256,35 +261,54 @@ _SCAN_UPLOAD_LOCK = threading.Lock()
 _TRACE_LOCK = threading.RLock()
 
 
-def _shared_scan_upload(node: HostScanExec, conf: TpuConf
+def _shared_scan_upload(node: HostScanExec, conf: TpuConf, mesh=None,
+                        ctx: Optional[ExecContext] = None
                         ) -> List[DeviceBatch]:
     """Upload a scan's batches once PER SOURCE TABLE (not per plan): every
     re-planned query over the same pyarrow table shares one device copy —
     the buffer-cache role for hot inputs (reference FileCache /
     spill-framework device tier).  Weakref-keyed so device memory is
     released with the table; LRU byte-capped by
-    spark.rapids.tpu.sql.scan.uploadCacheBytes."""
+    spark.rapids.tpu.sql.scan.uploadCacheBytes.
+
+    With `mesh` the copy is the row-sharded one, kept per table AND mesh
+    under the same rules (a one-chip session's copy of the table is
+    another entry; no unsharded copy is kept for the mesh's sake).
+    Placing it is `tpu.shard` (`overhead.shard_ms`) and its bytes are
+    `mesh.reshard_bytes` of the collect `ctx` that paid for it (a mesh
+    needs its `ctx`): a warm collect reads neither."""
     import weakref
     from ..config import SCAN_UPLOAD_CACHE_BYTES
     cap_bytes = conf.get(SCAN_UPLOAD_CACHE_BYTES)
     tbl = node._source_table
     enc_cols = getattr(node, "encoded_cols", None)
+
+    def upload():
+        if mesh is None:
+            return [to_device(hb, conf, encoded_cols=enc_cols)
+                    for hb in node.batches]
+        with CollectSpan(ctx, "shard", "overhead.shard_ms"):
+            dbs = [_upload_sharded(hb, conf, enc_cols, mesh)
+                   for hb in node.batches]
+        ctx.bump("mesh.reshard_bytes", sum(db.nbytes() for db in dbs))
+        return dbs
+
     if tbl is None or cap_bytes == 0:
-        return [to_device(hb, conf, encoded_cols=enc_cols)
-                for hb in node.batches]
+        return upload()
     # the encoded-upload form (sorted dictionaries, FOR-narrowed lanes —
     # ops/encodings.py) changes lane dtypes and dictionary order: plans
     # negotiated differently must never share a device copy
     from ..ops.encodings import encoding_discriminant
     key = (id(tbl), conf.batch_size_rows, encoding_discriminant(conf),
            None if enc_cols is None else tuple(sorted(enc_cols)))
+    if mesh is not None:
+        key += (_mesh_id(mesh),)
     with _SCAN_UPLOAD_LOCK:
         hit = _SCAN_UPLOAD_CACHE.pop(key, None)
         if hit is not None and hit[0]() is tbl:
             _SCAN_UPLOAD_CACHE[key] = hit          # re-insert: now MRU
             return hit[1]
-    dbs = [to_device(hb, conf, encoded_cols=enc_cols)
-           for hb in node.batches]
+    dbs = upload()
     try:
         ref = weakref.ref(tbl, lambda _r, k=key:
                           _SCAN_UPLOAD_CACHE.pop(k, None))
@@ -686,10 +710,8 @@ class CompiledPlan:
                 with ctx.tracer.span("upload", "transition"):
                     cached = retry_io(
                         ctx.conf, "h2d",
-                        lambda: _shared_scan_upload(node, ctx.conf))
-                    if self.mesh is not None:
-                        cached = [_shard_batch(db, self.mesh)
-                                  for db in cached]
+                        lambda: _shared_scan_upload(node, ctx.conf,
+                                                    self.mesh, ctx))
                 ctx.tracer.add_bytes(
                     "h2d_bytes", sum(hb.rb.nbytes for hb in node.batches))
                 node._device_cache = cached
@@ -819,9 +841,10 @@ class CompiledPlan:
     # -- compile + run -----------------------------------------------------
     def _build_cache_key(self, flat_in, in_specs) -> Optional[tuple]:
         """Canonical process-wide cache key, or None when this plan is
-        outside the cacheable envelope (mesh SPMD, uncovered node class,
-        lifting off)."""
-        if not self._lift or self.mesh is not None:
+        outside the cacheable envelope (uncovered node class, lifting
+        off).  A mesh plan's key ends in its mesh and each input's
+        partition spec; a one-chip plan's key has no such part."""
+        if not self._lift:
             return None
         skey = plan_structure_key(self.root, self.conf)
         if skey is None:
@@ -830,7 +853,10 @@ class CompiledPlan:
                           tuple(_spec_sig(spec) for spec in node_specs))
                          for node, node_specs in in_specs)
         input_sig = tuple((tuple(a.shape), str(a.dtype)) for a in flat_in)
-        return (skey, spec_sig, input_sig, self.seam)
+        if self.mesh is None:
+            return (skey, spec_sig, input_sig, self.seam)
+        return (skey, spec_sig, input_sig, self.seam,
+                _mesh_sig(self.mesh, flat_in))
 
     def _try_plan_cache(self, ctx: ExecContext, pairs, flat_in,
                         in_specs) -> bool:
@@ -1010,6 +1036,10 @@ class CompiledPlan:
         m["exec_dispatches"] = m.get("exec_dispatches", 0) + 1
         for k, n in self._run_counts.items():
             ctx.bump(k, n)
+        if self.mesh is not None:
+            m["mesh.devices"] = self.mesh.devices.size
+            m["mesh.replicated_lanes"] = _unsplit_lanes(flat_in)
+            m.setdefault("mesh.reshard_bytes", 0)    # a warm collect: 0
         # always-on measured working-set floor: the largest XLA
         # memory_analysis() footprint this query dispatched (args +
         # output + temp + code, captured at compile time — no conf

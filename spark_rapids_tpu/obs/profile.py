@@ -345,7 +345,10 @@ class QueryProfile:
                              float(ov.get("prepare_ms", 0.0)))
             cats.update({
                 "compile_ms": compile_ms,
-                "fetch_ms": float(ov.get("fetch_ms", 0.0)),
+                # a mesh's placement (tpu.shard) is an upload: with
+                # spans it lies in the `upload` transition, here too
+                "fetch_ms": float(ov.get("fetch_ms", 0.0))
+                + float(ov.get("shard_ms", 0.0)),
                 "shuffle_ms": 0.0,
                 "host_prep_ms": float(ov.get("host_prep_ms", 0.0)),
                 "prepare_ms": float(ov.get("prepare_ms", 0.0))

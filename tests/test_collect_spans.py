@@ -62,7 +62,7 @@ def _by_operator():
 
 #: key of every span whose own time adds up to the collect's wall
 ADDITIVE = ("plan_ms", "host_prep_ms", "prepare_ms", "speculate_ms",
-            "launch_ms", "seam_ms", "fetch_ms", "finish_ms",
+            "launch_ms", "shard_ms", "seam_ms", "fetch_ms", "finish_ms",
             "unattributed_ms")
 #: the spans a one-program collect opens, and those only a split one does
 COMMON = {"tpu.collect", "tpu.plan", "tpu.scope_enter", "tpu.prepare",
