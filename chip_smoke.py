@@ -30,11 +30,12 @@ it is never a fallback.
 The single-query leg times a KEPT `PhysicalQuery` (`dfq.physical()` once,
 then `q.collect()` again and again): its warm collects hold on to the
 compiled plan and to the plan nodes' device lanes.  `DataFrame.collect()`
-plans anew every time, so until ISSUE 32 a warm `--mesh` collect here (q6
-10.4 ms at SF1, PR 21) was NOT what `DataFrame.collect()` paid on a mesh:
-that traced, lowered and loaded the program again and put every lane onto
-the mesh again, in every collect.  The benchmark's cell `tpch-sf10.mesh4`
-times `DataFrame.collect()` itself.
+planned anew every time until ISSUE 33 (an unchanged DataFrame now keeps
+its `PhysicalQuery`, unpinned between collects), so until ISSUE 32 a warm
+`--mesh` collect here (q6 10.4 ms at SF1, PR 21) was NOT what
+`DataFrame.collect()` paid on a mesh: that traced, lowered and loaded the
+program again and put every lane onto the mesh again, in every collect.
+The benchmark's cell `tpch-sf10.mesh4` times `DataFrame.collect()` itself.
 
 The last stdout line is one JSON object:
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}

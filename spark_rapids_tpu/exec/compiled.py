@@ -328,6 +328,18 @@ def _shared_scan_upload(node: HostScanExec, conf: TpuConf, mesh=None,
     return dbs
 
 
+def release_scan_uploads(root: PlanNode) -> None:
+    """Unpin the device batches that `_leaf_batches` holds on the scans
+    under `root` for the length of a collect.  A plan kept between
+    collects (DataFrame.collect) asks the upload cache again at the next
+    one, which is that cache's LRU touch, so the cache's byte cap and
+    not the plan decides how long a table's device copy lives; an upload
+    the cache does not hold is made anew, as by a plan made anew."""
+    for node in _find_scans(root):
+        if isinstance(node, HostScanExec):
+            node._device_cache = None
+
+
 # ---------------------------------------------------------------------------
 # Constant-lifted canonical plan keys + the process-wide executable cache
 # ---------------------------------------------------------------------------
@@ -712,8 +724,7 @@ class CompiledPlan:
                         ctx.conf, "h2d",
                         lambda: _shared_scan_upload(node, ctx.conf,
                                                     self.mesh, ctx))
-                ctx.tracer.add_bytes(
-                    "h2d_bytes", sum(hb.rb.nbytes for hb in node.batches))
+                ctx.tracer.add_bytes("h2d_bytes", node.host_nbytes())
                 node._device_cache = cached
             pairs.append((node, cached))
         return pairs
@@ -980,6 +991,9 @@ class CompiledPlan:
                 if not self._try_plan_cache(ctx, pairs, flat_in, in_specs):
                     self.aot_compile(ctx, flat_in, in_specs, pairs)
         elif not self._fresh:
+            # a kept plan's own program: the facts of its trace, as a
+            # collect that adopts it from the process-wide cache has them
+            ctx.metrics.update(self._host_metrics)
             ctx.bump("compile_cache_hits")
         self._fresh = False
 
@@ -1364,7 +1378,8 @@ def _slice_batch(db: DeviceBatch, cap: int, n: int) -> DeviceBatch:
 
 #: signature -> the jitted program that resolves one seam batch's `sel`
 #: and `thin` at a shrunken capacity.  Module-level, as _COMPACT_CACHE
-#: is: a SplitCompiledPlan dies with its plan at every collect.
+#: is: shared by every plan over the same shapes, a DataFrame's kept
+#: one or a plan made for one collect.
 _SEAM_CACHE: Dict[tuple, object] = {}
 
 
@@ -1585,6 +1600,11 @@ class SplitCompiledPlan:
                                                get_service)
         if not background_enabled(ctx.conf):
             return
+        # the bucket this seam shrank to last time is the one prediction
+        # worth making; the structural guesses are for a seam never seen
+        remembered = _seam_bucket_get(seg._cache_key)
+        if remembered is not None and remembered in self._programs[nxt]:
+            return                       # a kept plan: its own program
         specs, layout = seg._out_specs, seg._out_layout
         if not specs or layout is None or len(specs) != 1:
             return                       # multi-batch seams: no prediction
@@ -1601,9 +1621,6 @@ class SplitCompiledPlan:
             return
         service = get_service(ctx.conf)
         conf = ctx.conf
-        # the bucket this seam shrank to last time is the one prediction
-        # worth making; the structural guesses are for a seam never seen
-        remembered = _seam_bucket_get(seg._cache_key)
         caps = list(remembered) if remembered is not None \
             else self._candidate_caps(i, cap_in, conf)
         epoch, at_submit = self._tree_epoch, self._tree_epoch[0]
@@ -1715,6 +1732,8 @@ class SplitCompiledPlan:
             out = last.collect(ctx)
         finally:
             self._restore_leaves()
+            for leaf in self.leaves:     # the seams' outputs go with the
+                leaf.batches = []        # collect, not with the plan
         ctx.bump("whole_plan_split_queries")
         return out
 

@@ -289,6 +289,7 @@ class HostScanExec(PlanNode):
         # device batches, and tracer stand-ins installed during jit trace
         self._device_cache = None
         self._trace_batches = None
+        self._host_nbytes: Optional[int] = None
         # columns approved for FOR-narrowed encoded upload by the
         # _negotiate_encoded legality pass (plan/overrides.py); None =
         # un-negotiated, lanes stay full width
@@ -301,6 +302,14 @@ class HostScanExec(PlanNode):
             else table.combine_chunks().to_batches()
         return cls([HostBatch(rb) for rb in rbs],
                    schema_to_struct(table.schema), source_table=table)
+
+    def host_nbytes(self) -> int:
+        """Bytes of the host batches, what an upload of this scan moves:
+        summed once (Arrow walks every buffer of every batch to answer),
+        because a plan kept between collects counts them at each."""
+        if self._host_nbytes is None:
+            self._host_nbytes = sum(hb.rb.nbytes for hb in self.batches)
+        return self._host_nbytes
 
     def keys_unique(self, names: Sequence[str]) -> bool:
         """Exact scan-time distinctness statistics (the role Delta/Iceberg

@@ -23,6 +23,14 @@ from .aggregates import AggregateFunction
 class LogicalPlan:
     """Base logical operator. Schema resolves lazily, children first."""
 
+    #: planning this node reads the node and the conf and nothing else
+    #: (relational algebra over in-memory Arrow tables), so a physical
+    #: plan made from it stands for it as long as the conf does.  A file
+    #: scan, a LogicalCache, a Python worker or UDF node reads the world
+    #: outside the plan and leaves this False: planned anew every collect
+    #: (PhysicalQuery.keepable).
+    self_contained = False
+
     def __init__(self, *children: "LogicalPlan"):
         self.children = list(children)
         self._schema: Optional[t.StructType] = None
@@ -69,6 +77,8 @@ class LogicalScan(LogicalPlan):
     """Leaf over an in-memory Arrow table (the InMemoryScan / LocalTableScan
     analogue).  File scans are LogicalFileScan (io/)."""
 
+    self_contained = True
+
     def __init__(self, table: pa.Table):
         super().__init__()
         self.table = table
@@ -82,6 +92,8 @@ class LogicalScan(LogicalPlan):
 
 
 class LogicalProject(LogicalPlan):
+    self_contained = True
+
     def __init__(self, exprs: Sequence, child: LogicalPlan,
                  names: Optional[Sequence[str]] = None):
         super().__init__(child)
@@ -99,6 +111,8 @@ class LogicalProject(LogicalPlan):
 
 
 class LogicalFilter(LogicalPlan):
+    self_contained = True
+
     def __init__(self, condition: E.Expression, child: LogicalPlan):
         super().__init__(child)
         self.condition = _as_expr(condition)
@@ -113,6 +127,8 @@ class LogicalFilter(LogicalPlan):
 class LogicalAggregate(LogicalPlan):
     """group-by keys + aggregate list.  keys may be arbitrary expressions;
     aggs are (AggregateFunction, output name) pairs."""
+
+    self_contained = True
 
     def __init__(self, keys: Sequence, aggs: Sequence[Tuple[AggregateFunction, str]],
                  child: LogicalPlan, key_names: Optional[Sequence[str]] = None):
@@ -139,6 +155,8 @@ class LogicalAggregate(LogicalPlan):
 class LogicalSort(LogicalPlan):
     """orders: sequence of (expr-or-name, ascending, nulls_first)."""
 
+    self_contained = True
+
     def __init__(self, orders: Sequence, child: LogicalPlan,
                  global_sort: bool = True):
         super().__init__(child)
@@ -164,6 +182,8 @@ class LogicalSort(LogicalPlan):
 
 
 class LogicalLimit(LogicalPlan):
+    self_contained = True
+
     def __init__(self, limit: int, child: LogicalPlan):
         super().__init__(child)
         self.limit = limit
@@ -178,6 +198,8 @@ class LogicalLimit(LogicalPlan):
 class LogicalJoin(LogicalPlan):
     """Equi-join on key expression pairs.  join_type: inner, left_outer,
     right_outer, full_outer, left_semi, left_anti, cross."""
+
+    self_contained = True
 
     _MIRROR = {"inner": "inner", "left_outer": "right_outer",
                "right_outer": "left_outer", "full_outer": "full_outer",
@@ -240,6 +262,8 @@ class LogicalSample(LogicalPlan):
     (seed, global row position) — deterministic for a given seed AND
     identical on the device and CPU paths."""
 
+    self_contained = True
+
     def __init__(self, fraction: float, seed: int, child: LogicalPlan):
         super().__init__(child)
         if not 0.0 <= fraction <= 1.0:
@@ -255,6 +279,8 @@ class LogicalSample(LogicalPlan):
 
 
 class LogicalUnion(LogicalPlan):
+    self_contained = True
+
     def __init__(self, *children: LogicalPlan):
         super().__init__(*children)
 
@@ -276,6 +302,8 @@ class LogicalRange(LogicalPlan):
 
 
 class LogicalExpand(LogicalPlan):
+    self_contained = True
+
     def __init__(self, projections: Sequence[Sequence], names: Sequence[str],
                  child: LogicalPlan):
         super().__init__(child)
@@ -292,6 +320,8 @@ class LogicalWindow(LogicalPlan):
     """Window functions over (partition keys, order keys).  window_exprs:
     (WindowFunctionSpec, output name) pairs appended to the child schema.
     See plan/window.py for specs."""
+
+    self_contained = True
 
     def __init__(self, window_exprs: Sequence, partition_keys: Sequence,
                  order_keys: Sequence, child: LogicalPlan):
@@ -360,6 +390,8 @@ class LogicalGenerate(LogicalPlan):
     """Generator (explode/posexplode) appending generated columns to the
     child's rows — reference GpuGenerateExec (GpuGenerateExec.scala:829).
     Runs on the CPU path by placement (array inputs; plan/collections.py)."""
+
+    self_contained = True
 
     def __init__(self, generator, child: LogicalPlan,
                  output_names: Sequence[str] = ()):

@@ -1198,6 +1198,11 @@ class PhysicalQuery:
         # (wrap/tag/convert), stamped by apply_overrides; the tracer
         # replays them as cat=plan spans at collect time
         self.plan_phases: List[tuple] = []
+        # planning read nothing but the logical plan and the conf (every
+        # node `self_contained`), and the conf arms no fault site, whose
+        # counters live on this plan's root: set once by apply_overrides,
+        # it is what lets a DataFrame collect through this plan again
+        self.keepable = False
         if kind == "device":
             # one id per node (`HashJoinExec#4`) for EXPLAIN, the
             # per-node metrics, every whole-plan segment and, through
@@ -1222,6 +1227,28 @@ class PhysicalQuery:
 
     def physical_tree(self) -> str:
         return self.root.tree_string()
+
+    def reusable(self, ctx: ExecContext) -> bool:
+        """Whether the collect that just ran through this plan under
+        `ctx` leaves it fit to run the next one: a whole-plan program
+        answered it and nothing else did.  The eager engine, an OOM
+        replay and the out-of-core tier keep state on the plan's nodes
+        that a plan made anew starts without."""
+        m = ctx.metrics
+        return bool(self.keepable
+                    and m.get("whole_plan_compiled_queries") == 1
+                    and not m.get("whole_plan_fallbacks")
+                    and not m.get("query_oom_replays")
+                    and not ctx.ooc_force)
+
+    def release(self) -> None:
+        """What a plan kept between collects lets go of: it holds no
+        device memory that a plan made anew would not hold (its scans'
+        uploads go back to the upload cache's keeping), and no planning
+        phase of a collect gone by for the tracer to replay."""
+        from ..exec.compiled import release_scan_uploads
+        release_scan_uploads(self.root)
+        self.plan_phases = []
 
     def fallback_reasons(self) -> List[str]:
         """Every tagger reason in the meta tree (depth-first) — the
@@ -1801,6 +1828,9 @@ def apply_overrides(plan: L.LogicalPlan,
     returned PhysicalQuery; the query tracer replays them as cat=plan
     spans so the profile shows planning cost next to execution."""
     import time as _time
+    from ..runtime.failure import faults_armed
+    keepable = not faults_armed(conf) and all(
+        node.self_contained for node in _walk(plan))
     phases = []
     t0 = _time.perf_counter()
     if conf.sql_enabled:
@@ -1845,6 +1875,7 @@ def apply_overrides(plan: L.LogicalPlan,
     phases.append(("plan.convert", t2, _time.perf_counter()))
     pq = PhysicalQuery(meta, kind, root, conf)
     pq.plan_phases = phases
+    pq.keepable = keepable
     return pq
 
 

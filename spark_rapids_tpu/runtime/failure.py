@@ -266,6 +266,15 @@ def install_fault_injection(root, conf: TpuConf) -> None:
     root._fatal_injected = True
 
 
+def faults_armed(conf: TpuConf) -> bool:
+    """Whether this conf arms any fault site: the chaos harness's rules
+    or the batch-count fatal injector, whose counter lives on the root
+    of the plan it was installed on (a plan collected again would go on
+    counting where a plan made anew starts from zero)."""
+    from .faults import get_injector
+    return bool(get_injector(conf).enabled or int(conf.get(INJECT_FATAL)))
+
+
 class FatalInjector:
     """Counts device batches; raises at the configured threshold."""
 

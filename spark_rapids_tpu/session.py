@@ -403,6 +403,13 @@ class DataFrame:
     def __init__(self, plan: L.LogicalPlan, session: TpuSession):
         self._plan = plan
         self._session = session
+        #: (the session conf it was planned under, the PhysicalQuery) of
+        #: the last collect() that left it fit for the next, which runs
+        #: through it again (QueryExecution's lazy `executedPlan`):
+        #: PhysicalQuery.reusable decides
+        self._kept: Optional[Tuple[TpuConf, PhysicalQuery]] = None
+        #: held by the one collect that may use and replace `_kept`
+        self._kept_lock = threading.Lock()
 
     # -- transformations ---------------------------------------------------
     def select(self, *exprs, names: Optional[Sequence[str]] = None
@@ -538,16 +545,41 @@ class DataFrame:
         return apply_overrides(self._plan, self._session.conf)
 
     def collect(self) -> pa.Table:
-        ctx = ExecContext(self._session.conf)
-        with CollectSpan(ctx, "collect", "overhead.unattributed_ms",
-                         whole_key="overhead.collect_ms"):
-            with CollectSpan(ctx, "plan", "overhead.plan_ms"):
-                q = self.physical()
-                ctx.conf = q.conf       # planning may have adjusted it
-            out = q.collect(ctx)
-            with CollectSpan(ctx, "finish", "overhead.finish_ms"):
-                self._last_ctx = ctx
-                self._session._record_query(ctx)
+        """Plan (or take the plan kept by the last collect), launch,
+        fetch.  An unchanged DataFrame keeps its physical plan, and with
+        it the compiled plan object and its programs: a warm collect
+        goes from here to the launch without planning or looking a
+        program up.  The program runs and its answer is fetched in every
+        collect; nothing is answered from a result cache."""
+        conf = self._session.conf       # TpuConf is immutable: a version
+        ctx = ExecContext(conf)
+        # one collect at a time runs through the kept plan (a split plan
+        # swaps seam leaves into its tree): a concurrent collect of this
+        # DataFrame plans anew, as ever, and keeps nothing
+        owner = self._kept_lock.acquire(blocking=False)
+        try:
+            with CollectSpan(ctx, "collect", "overhead.unattributed_ms",
+                             whole_key="overhead.collect_ms"):
+                with CollectSpan(ctx, "plan", "overhead.plan_ms"):
+                    kept = None
+                    if owner:
+                        kept, self._kept = self._kept, None
+                    if kept is not None and kept[0] is conf:
+                        q = kept[1]
+                        ctx.metrics["plan.reused"] = 1
+                    else:
+                        q = apply_overrides(self._plan, conf)
+                    ctx.conf = q.conf   # planning may have adjusted it
+                out = q.collect(ctx)
+                with CollectSpan(ctx, "finish", "overhead.finish_ms"):
+                    if owner and q.reusable(ctx):
+                        q.release()
+                        self._kept = (conf, q)
+                    self._last_ctx = ctx
+                    self._session._record_query(ctx)
+        finally:
+            if owner:
+                self._kept_lock.release()
         return out
 
     def metrics(self) -> Optional[dict]:

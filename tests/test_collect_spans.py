@@ -29,21 +29,26 @@ def _tbl(n=4000, seed=7):
                      "v": pa.array(rng.standard_normal(n))})
 
 
-def _seam_df(s, n=4000):
+def _tables(n=4000):
+    rng = np.random.default_rng(11)
+    return (_tbl(n), pa.table({"k2": pa.array(np.arange(8), pa.int64()),
+                               "w": pa.array(rng.standard_normal(8))}))
+
+
+def _seam_df(s, tables=None):
     """The two-seam shape of test_wall_breakdown.py: a sort over a join
     under an aggregate splits after the join and after the aggregate."""
-    rng = np.random.default_rng(11)
-    dim = pa.table({"k2": pa.array(np.arange(8), pa.int64()),
-                    "w": pa.array(rng.standard_normal(8))})
-    return (s.from_arrow(_tbl(n))
+    fact, dim = tables or _tables()
+    return (s.from_arrow(fact)
             .join(s.from_arrow(dim), left_on=["k"], right_on=["k2"])
             .group_by("k").agg((Sum(col("w")), "sw"), (Count(None), "c"))
             .sort(col("k")))
 
 
-def _q6_df(s, n=4000):
+def _q6_df(s, tables=None):
     """Filter under a global aggregate: one fused program, no seam."""
-    return (s.from_arrow(_tbl(n)).filter(col("v") > lit(0.0))
+    fact, _dim = tables or _tables()
+    return (s.from_arrow(fact).filter(col("v") > lit(0.0))
             .agg((Sum(col("v")), "sv")))
 
 
@@ -70,8 +75,13 @@ COMMON = {"tpu.collect", "tpu.plan", "tpu.scope_enter", "tpu.prepare",
 SPLIT_ONLY = {"tpu.speculate", "tpu.seam", "tpu.seam_wait"}
 
 
-def _spans_of(shape):
-    return COMMON | (SPLIT_ONLY if shape == "seams" else set())
+def _spans_of(shape, kept=False):
+    """`kept`: a DataFrame collected again runs through its kept plan; a
+    lone program then has nothing to prepare, a split plan still opens
+    the span around the lookup of each segment among its own."""
+    if shape == "seams":
+        return COMMON | SPLIT_ONLY
+    return COMMON - ({"tpu.prepare"} if kept else set())
 
 
 @pytest.mark.parametrize("shape", list(SHAPES))
@@ -101,24 +111,40 @@ def test_default_conf_fills_every_key_and_they_add_up(shape):
     assert ov["seam_wait_ms"] <= ov["seam_ms"] + 1e-9
 
 
+@pytest.mark.parametrize("frame", ["same", "rebuilt"])
 @pytest.mark.parametrize("shape", list(SHAPES))
-def test_counters_repeat_over_warm_collects(shape):
-    df = SHAPES[shape](TpuSession(WHOLE))
+def test_counters_repeat_over_warm_collects(shape, frame):
+    """`same`: one DataFrame collected again keeps its physical plan and
+    runs its own programs.  `rebuilt`: a DataFrame built again over the
+    same tables (a serving ticket, SQL text) plans anew and adopts every
+    program from the process-wide cache, as every collect did before
+    DataFrames kept their plans."""
+    s = TpuSession(WHOLE)
+    tables = _tables()
+    df = SHAPES[shape](s, tables)
     df.collect()                               # cold: upload + compile
     counts = []
     for _ in range(2):
+        if frame == "rebuilt":
+            df = SHAPES[shape](s, tables)
         df.collect()
         m = df.metrics()
         counts.append({k: m.get(k, 0) for k in (
             "host_syncs", "exec_dispatches", "overhead.seam_count",
             "compile_speculative_submitted", "compile_speculative_cached",
-            "compile_background_used", "whole_plan_structure_hits")})
+            "compile_background_used", "whole_plan_structure_hits",
+            "whole_plan_compiled_queries", "plan.reused")})
     assert counts[0] == counts[1], counts
     seams = 2 if shape == "seams" else 0
+    kept = frame == "same"
     assert counts[0]["exec_dispatches"] == seams + 1
-    # a warm collect adopts every program and speculates at no seam
-    assert counts[0]["whole_plan_structure_hits"] == seams + 1
-    assert counts[0]["compile_speculative_cached"] == seams
+    assert counts[0]["whole_plan_compiled_queries"] == 1
+    assert counts[0]["plan.reused"] == (1 if kept else 0)
+    # a warm collect runs its own programs, or adopts every one of them,
+    # and speculates at no seam
+    assert counts[0]["whole_plan_structure_hits"] == \
+        (0 if kept else seams + 1)
+    assert counts[0]["compile_speculative_cached"] == (0 if kept else seams)
     assert counts[0]["compile_speculative_submitted"] == 0
     assert counts[0]["compile_background_used"] == 0
 
@@ -127,7 +153,8 @@ def test_counters_repeat_over_warm_collects(shape):
 def test_spans_land_in_the_profiler_trace(shape, tmp_path):
     """Under jax.profiler the host plane holds every span of the table,
     inside the enclosing tpu.collect, which lies inside the caller's
-    own annotation; all carry the collect's `query` stat."""
+    own annotation; all carry the collect's `query` stat.  The traced
+    collects are the second and third of one DataFrame: its kept plan."""
     from jax.profiler import ProfileData
     df = SHAPES[shape](TpuSession(WHOLE))
     df.collect()
@@ -159,7 +186,8 @@ def test_spans_land_in_the_profiler_trace(shape, tmp_path):
         inside = [e for e in events
                   if e[0] not in ("collect:x", "tpu.collect")
                   and w0 <= e[1] and e[2] <= w1]
-        assert {e[0] for e in inside} == _spans_of(shape) - {"tpu.collect"}
+        assert {e[0] for e in inside} == \
+            _spans_of(shape, kept=True) - {"tpu.collect"}
         assert {e[3] for e in inside} == {query}
     # scripts/trace_by_operator.py reads the same file: each span's own
     # time, which adds up to the annotation's wall
@@ -167,7 +195,8 @@ def test_spans_land_in_the_profiler_trace(shape, tmp_path):
     _dev, annotations, seen = tool.load(files[0])
     host_events = [e for lines in seen["/host:CPU"].values() for e in lines]
     row = tool.host_by_span(host_events, annotations, [])["collect:x"]
-    assert row["collects"] == 2 and set(row["spans"]) == _spans_of(shape)
+    assert row["collects"] == 2 and \
+        set(row["spans"]) == _spans_of(shape, kept=True)
     assert row["in_spans_pct"] > 90.0 and row["children_pct"] > 50.0
     assert sum(own for own, _idle in row["spans"].values()) == \
         pytest.approx(row["wall_ms"] * row["in_spans_pct"] / 100.0, rel=1e-6)

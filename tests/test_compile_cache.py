@@ -483,7 +483,7 @@ def test_background_disabled_still_correct():
 
 _SPEC = ("compile_speculative_submitted", "compile_speculative_cached",
          "compile_background_used", "whole_plan_structure_hits",
-         "compile_cache_misses")
+         "compile_cache_misses", "plan.reused")
 
 
 def _collect_counts(df):
@@ -492,39 +492,45 @@ def _collect_counts(df):
     return out, {k: m.get(k, 0) for k in _SPEC}
 
 
-#: what every collect of _split_query reads once its three programs are
-#: cached and both seams remember their bucket
+#: what a collect of _split_query reads once its three programs are
+#: cached and both seams remember their bucket, when its DataFrame was
+#: built again and so plans anew
 _WARM = {"compile_speculative_submitted": 0, "compile_speculative_cached": 2,
          "compile_background_used": 0, "whole_plan_structure_hits": 3,
-         "compile_cache_misses": 0}
+         "compile_cache_misses": 0, "plan.reused": 0}
+#: and when the same DataFrame is collected again: its kept plan finds
+#: every segment among its own programs and asks no cache
+_KEPT = {**_WARM, "compile_speculative_cached": 0,
+         "whole_plan_structure_hits": 0, "plan.reused": 1}
+FRAMES = ["same", "rebuilt"]
 
 
-def test_replanned_split_collects_speculate_only_when_cold():
-    """DataFrame.collect() plans anew, so each collect has a new
-    SplitCompiledPlan with no programs of its own.  The first collect of
-    a process speculates; from the second on every seam finds its next
-    segment's program in the process-wide cache, submits nothing to the
-    compile service and waits for no thread."""
+@pytest.mark.parametrize("frame", FRAMES)
+def test_replanned_split_collects_speculate_only_when_cold(frame):
+    """The first collect of a process speculates.  From the second on a
+    DataFrame built again over the same tables (a serving ticket,
+    bench.py: a new SplitCompiledPlan with no programs of its own) finds
+    every seam's next segment in the process-wide cache, submits nothing
+    to the compile service and waits for no thread; the same DataFrame
+    collected again runs the programs its kept plan holds."""
     from spark_rapids_tpu.testing import clear_compiled_caches
     clear_compiled_caches()
     s = _split_conf()
-    df = _split_query(s)
+    tables = _split_tables()
+    df = _split_query(s, tables)
     out, cold = _collect_counts(df)
     assert cold["compile_speculative_submitted"] == 4    # 2 seams x 2 guesses
     assert cold["compile_background_used"] == 2
     assert cold["compile_speculative_cached"] == 0
+    assert cold["plan.reused"] == 0
     assert _same_answer(out, df)
     for _ in range(2):
+        if frame == "rebuilt":
+            df = _split_query(s, tables)
         out, warm = _collect_counts(df)
-        assert warm == _WARM
+        assert warm == (_KEPT if frame == "same" else _WARM)
         assert df.metrics()["whole_plan_split_queries"] == 1
         assert _same_answer(out, df)
-    # a DataFrame built again over the same tables is the same traffic
-    # (a serving ticket, bench.py): it adopts as well
-    tables = _split_tables()
-    _collect_counts(_split_query(s, tables))
-    out, again = _collect_counts(_split_query(s, tables))
-    assert again == _WARM
 
 
 def _successor_keys():
@@ -534,13 +540,16 @@ def _successor_keys():
             if any(name == "DeviceResidentScanExec" for name, _ in k[1])]
 
 
+@pytest.mark.parametrize("frame", FRAMES)
 @pytest.mark.parametrize("case", [
     "cold_process", "new_tables", "bucket_crossing", "evicted_entry",
     "background_off", "compile_fault"])
-def test_speculation_keeps_todays_behaviour_off_the_warm_path(case):
+def test_speculation_keeps_todays_behaviour_off_the_warm_path(case, frame):
     """Where a seam has nothing to go by, or what it remembers no longer
     holds, the collect behaves as it did before seams remembered: it
-    speculates (or compiles inline), and the answer is the oracle's."""
+    speculates (or compiles inline), and the answer is the oracle's.
+    `frame`: whether the collects that follow one another are of the same
+    DataFrame (its kept plan) or of one built again (plans anew)."""
     from spark_rapids_tpu.exec import compiled as C
     from spark_rapids_tpu.testing import clear_compiled_caches
     clear_compiled_caches()
@@ -556,25 +565,43 @@ def test_speculation_keeps_todays_behaviour_off_the_warm_path(case):
     }.get(case)
     s = _split_conf(conf)
     tables = _split_tables(1000 if case == "compile_fault" else 5000)
-    df = _split_query(s, tables,
-                      under_join=0.5 if case == "bucket_crossing" else None)
+
+    query = {"tables": tables,
+             "under_join": 0.5 if case == "bucket_crossing" else None}
+
+    def build():
+        return _split_query(s, **query)
+
+    def again(df):
+        return build() if frame == "rebuilt" else df
+    df = build()
     if case not in ("cold_process", "compile_fault"):
         for _ in range(2):
+            df = again(df)
             _collect_counts(df)
+    # the collect under test runs through a kept plan where it is of the
+    # DataFrame just collected: what the caches lost or the conf turned
+    # off does not reach the programs that plan holds
+    kept = frame == "same" and case in ("evicted_entry", "background_off")
     if case == "new_tables":
         # same shape, other host objects: the anchors differ
-        df = _split_query(s)
+        query["tables"] = _split_tables()
+        df = build()
     elif case == "bucket_crossing":
         # a lifted literal keeps every key and moves the row count
         # across a bucket boundary: 2,500 survivors -> 500
         assert C._SEAM_BUCKET_CACHE and \
             set(C._SEAM_BUCKET_CACHE.values()) == {(4096,), (1024,)}
-        df = _split_query(s, tables, under_join=0.9)
+        query["under_join"] = 0.9
+        df = build()
     elif case == "evicted_entry":
         gone = _successor_keys()
         assert len(gone) >= 2
         for k in gone:
             C._PLAN_EXEC_CACHE.pop(k)
+        df = again(df)
+    else:
+        df = again(df)
     out, got = _collect_counts(df)
     assert _same_answer(out, df)
     want = {
@@ -614,6 +641,8 @@ def test_speculation_keeps_todays_behaviour_off_the_warm_path(case):
         "compile_fault": dict(compile_speculative_submitted=1,
                               compile_background_used=0),
     }[case]
+    if kept:
+        want = _KEPT
     assert {k: got[k] for k in want} == want, got
     if case == "compile_fault":
         from spark_rapids_tpu.runtime.faults import get_injector
@@ -625,9 +654,12 @@ def test_speculation_keeps_todays_behaviour_off_the_warm_path(case):
         # the record moved on
         assert set(C._SEAM_BUCKET_CACHE.values()) == {(1024,)}
     # and the collect after it is warm again
+    df = again(df)
     out, after = _collect_counts(df)
     assert _same_answer(out, df)
-    if case == "background_off":
+    if frame == "same":
+        assert after == _KEPT
+    elif case == "background_off":
         assert after == {**_WARM, "compile_speculative_cached": 0}
     else:
         assert after == _WARM

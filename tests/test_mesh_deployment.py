@@ -105,27 +105,33 @@ def test_mesh_answers_equal_the_reference_and_the_one_chip_engine(
     assert "mesh.devices" not in one_chip.metrics()
 
 
+@pytest.mark.parametrize("frame", ["same", "rebuilt"])
 @pytest.mark.parametrize("query", ["q1", "q6"])
 def test_a_warm_mesh_collect_places_nothing_and_compiles_nothing(
-        bench, tables, mesh_conf, query, eight_devices):
-    """`DataFrame.collect()` plans anew every time: the second collect
-    finds its program under the mesh's key and its lanes in the per-table
-    cache."""
+        bench, tables, mesh_conf, query, frame, eight_devices):
+    """The second collect finds its lanes in the per-table cache, and its
+    program in the plan its DataFrame kept (`same`) or, where the
+    DataFrame was built again and plans anew, under the mesh's key in the
+    process-wide cache (`rebuilt`)."""
     module = getattr(bench, query)
     # a table of this test's own, so that the first collect is cold
     mine = {"lineitem": tables["lineitem"].slice(0)}
-    df = module.build(TpuSession(mesh_conf), mine)
+    session = TpuSession(mesh_conf)
+    df = module.build(session, mine)
     before = set(C._SCAN_UPLOAD_CACHE)
     first = df.collect()
     cold = df.metrics()
     assert cold["mesh.reshard_bytes"] > 0 and cold["overhead.shard_ms"] > 0
     assert cold.get("compile_cache_misses", 0) == 1
     assert cold.get("whole_plan_structure_hits", 0) == 0
+    if frame == "rebuilt":
+        df = module.build(session, mine)
     assert df.collect().equals(first)
     warm = df.metrics()
     assert warm["mesh.reshard_bytes"] == 0
     assert "overhead.shard_ms" not in warm
-    assert warm["whole_plan_structure_hits"] == 1
+    assert warm.get("plan.reused", 0) == (frame == "same")
+    assert warm.get("whole_plan_structure_hits", 0) == (frame == "rebuilt")
     assert warm.get("compile_cache_misses", 0) == 0
     assert warm["mesh.replicated_lanes"] == 0 and warm["mesh.devices"] == 4
     assert warm["exec_dispatches"] == 1 and warm["host_syncs"] == 1
@@ -187,23 +193,32 @@ def test_every_lane_is_sharded_four_ways_and_none_is_kept_whole(
             == {lane.shape[0] // 4}
 
 
+@pytest.mark.parametrize("frame", ["same", "rebuilt"])
 def test_a_one_chip_and_a_mesh_session_over_one_table_get_two_programs(
-        bench, tables, mesh_conf, eight_devices):
+        bench, tables, mesh_conf, frame, eight_devices):
+    """`rebuilt`: a new DataFrame every round, which plans anew and asks
+    the process-wide cache; `same`: from the second round on each
+    DataFrame runs the program its kept plan holds."""
     mine = {"lineitem": tables["lineitem"].slice(0)}
     want = bench.q6.reference(mine)
-    on_mesh = bench.q6.build(TpuSession(mesh_conf), mine)
-    one_chip = bench.q6.build(TpuSession(WHOLE), mine)
+    sessions = ((TpuSession(mesh_conf), 4), (TpuSession(WHOLE), None))
+    frames = [bench.q6.build(s, mine) for s, _devices in sessions]
     # a key names no table (the anchors do), so an earlier test's q6
     # programs would sit under the very keys this one files
     C._PLAN_EXEC_CACHE.clear()
     uploads = set(C._SCAN_UPLOAD_CACHE)
     for round_ in range(3):                  # interleaved: both stay warm
-        for df, devices in ((on_mesh, 4), (one_chip, None)):
+        for i, (session, devices) in enumerate(sessions):
+            df = frames[i] if frame == "same" \
+                else bench.q6.build(session, mine)
             answer = df.collect()
             assert bench.compare.table_gaps(answer, want) == (0, 0.0)
             m = df.metrics()
             assert m.get("mesh.devices") == devices
-            assert m.get("whole_plan_structure_hits", 0) == (round_ > 0)
+            warm = round_ > 0
+            assert m.get("plan.reused", 0) == (warm and frame == "same")
+            assert m.get("whole_plan_structure_hits", 0) == \
+                (warm and frame == "rebuilt")
             assert m.get("compile_cache_misses", 0) == (round_ == 0)
     new = list(C._PLAN_EXEC_CACHE)
     # a one-chip key is the four parts it has always been; the mesh's
