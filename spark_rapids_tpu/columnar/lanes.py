@@ -320,7 +320,7 @@ def compact_thin(db: DeviceBatch, keep: jax.Array,
     from ..ops.filter import compaction_order, grouped_take
     ts = db.thin
     assert ts is not None
-    order = compaction_order(keep)
+    order = compaction_order(keep, out_capacity)
     count = jnp.sum(keep, dtype=jnp.int32)
     if out_capacity is not None:
         order = order[:out_capacity]

@@ -132,6 +132,7 @@ class AdaptiveShuffledJoinExec(PlanNode):
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
         self.lazy_sel = False      # forwarded to the inner HashJoinExec
+        self.seam_lazy = False     # likewise (HashJoinExec.seam_lazy)
         # late-materialization allowance (plan/overrides.py
         # _negotiate_thin), forwarded to the inner HashJoinExec; the
         # mirror swap is invisible (thin state remaps through select)
@@ -201,7 +202,13 @@ class AdaptiveShuffledJoinExec(PlanNode):
                 run_u = self._side_unique(self.right_keys, self.right)
                 lun_u = self._side_unique(self.left_keys, self.left)
                 if run_u != lun_u:
-                    if lun_u and lbytes <= 8 * max(rbytes, 1):
+                    if ctx.traced:
+                        # inside a whole-plan program a build side with
+                        # duplicate keys would size its pairs on the
+                        # host (ROADMAP C10): the unique side builds,
+                        # whatever the sizes
+                        swap = lun_u
+                    elif lun_u and lbytes <= 8 * max(rbytes, 1):
                         swap = True
                     elif run_u and rbytes <= 8 * max(lbytes, 1):
                         swap = False
@@ -216,6 +223,7 @@ class AdaptiveShuffledJoinExec(PlanNode):
                                  self.left),
                     probe_conds=right_conds, build_conds=left_conds)
                 join.lazy_sel = self.lazy_sel
+                join.seam_lazy = self.seam_lazy
                 join.thin_payload = self.thin_payload
                 self._maybe_bloom(join, jt, left_stage,
                                   max(rbytes, 1), lbytes, ctx)
@@ -234,6 +242,7 @@ class AdaptiveShuffledJoinExec(PlanNode):
                                  self.right.output_schema, self.right),
                     probe_conds=left_conds, build_conds=right_conds)
                 join.lazy_sel = self.lazy_sel
+                join.seam_lazy = self.seam_lazy
                 join.thin_payload = self.thin_payload
                 self._maybe_bloom(join, self.join_type, right_stage,
                                   max(lbytes, 1), rbytes, ctx)

@@ -186,6 +186,9 @@ class AggElection:
     pack: Optional[tuple]
     scatter_free: bool
     max_ops: int
+    #: a `packed_sort` may leave each group at its run's last row, under
+    #: a mask, where its aggregates allow it (G.in_place_supported)
+    in_place: bool = False
 
     def trace(self, key_info, specs, capacity: int):
         """The keyed group-by trace of this election (a "reduce" has no
@@ -196,11 +199,12 @@ class AggElection:
         return G.groupby_trace(list(key_info), list(specs), capacity,
                                capacity, pack_spec=self.pack,
                                scatter_free=self.scatter_free,
-                               max_sort_operands=self.max_ops)
+                               max_sort_operands=self.max_ops,
+                               in_place=self.in_place)
 
 
 def elect_aggregate(num_keys: int, domains, pack, scatter_free: bool,
-                    max_ops: int) -> AggElection:
+                    max_ops: int, in_place: bool = False) -> AggElection:
     """THE choice of an aggregate's program from what its caller found:
     no keys reduce; keys with a bounded domain (`domains`: dictionary
     codes, booleans, under agg.denseDomainMax) take the dense group-by,
@@ -216,13 +220,16 @@ def elect_aggregate(num_keys: int, domains, pack, scatter_free: bool,
     else:
         strategy = "packed_sort" if G.all_keys_pack(pack, num_keys) \
             else "lexsort"
-    return AggElection(strategy, domains, pack, scatter_free, max_ops)
+    return AggElection(strategy, domains, pack, scatter_free, max_ops,
+                       in_place and strategy == "packed_sort")
 
 
 def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
                  specs: List[G.AggSpec], live, capacity: int,
-                 key_ranges=None, conf=None):
-    """-> (key_cols, out_keys, outs, num_groups, strategy)."""
+                 key_ranges=None, conf=None, in_place: bool = False):
+    """-> (key_cols, out_keys, outs, num_groups, strategy, sel): `sel` is
+    None, or with `in_place` the mask of the rows that hold a group each
+    (G.packed_groupby_trace)."""
     key_cols = [ensure_unique_dict(c) for c in key_cols]
     if conf is not None and any(c.dictionary is not None for c in key_cols):
         # dictionary group keys aggregate UNDECODED (codes hash/pack/
@@ -236,14 +243,14 @@ def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
     pack = None if domains is not None \
         else _key_pack_spec(key_cols, key_ranges)
     choice = elect_aggregate(len(key_cols), domains, pack,
-                             *_seg_knobs(conf))
+                             *_seg_knobs(conf), in_place=in_place)
     sig = (info, tuple((s.kind, s.input_idx, s.dtype) for s in specs),
            capacity, tuple(str(c.data.dtype) for c in agg_cols), choice)
     fn = _GROUPBY_CACHE.get(sig)
     if fn is None:
         fn = _GROUPBY_CACHE[sig] = jax.jit(
             choice.trace(info, specs, capacity))
-    out_keys, outs, num_groups = fn(
+    out_keys, outs, num_groups, *sel = fn(
         tuple(c.data for c in key_cols),
         tuple(c.validity for c in key_cols),
         tuple(c.data for c in agg_cols),
@@ -254,7 +261,8 @@ def _run_groupby(key_cols: List[DeviceColumn], agg_cols: List[DeviceColumn],
     # tracing the count is a Tracer and must stay on device
     if not isinstance(num_groups, jax.core.Tracer):
         num_groups = int(num_groups)
-    return key_cols, out_keys, outs, num_groups, choice.strategy
+    return (key_cols, out_keys, outs, num_groups, choice.strategy,
+            sel[0] if sel else None)
 
 
 def _run_reduce(agg_cols: List[DeviceColumn], specs: List[G.AggSpec],
@@ -269,18 +277,23 @@ def _run_reduce(agg_cols: List[DeviceColumn], specs: List[G.AggSpec],
               tuple(c.validity for c in agg_cols), live)
 
 
-def check_agg_buffers_supported(aggs) -> None:
+def check_agg_inputs_single_lane(input_exprs, db: DeviceBatch) -> None:
     """Decimal buffers ride the single int64 unscaled lane (sums whose
     true value exceeds int64 null out — ops/decimal.py module docs).  Only
-    two-lane 128-bit HOST inputs are rejected; plan-time tagging does this
-    too (aggregates.py unsupported_reasons) — fail fast for direct API
+    a two-lane 128-bit HOST input is rejected, and the batch itself says
+    which a wide column is (`data_hi`): a wide decimal that an operator
+    below computed on the device has one lane and aggregates like any
+    other.  Plan-time tagging keeps the host ones off the device
+    (aggregates.py unsupported_reasons) — this fails fast for direct API
     users."""
-    for fn, _name in aggs:
-        child = getattr(fn, "child", None)
-        if child is not None and E._consumes_wide_host(child):
+    for e in input_exprs:
+        ref = E.plain_ref(e)
+        if ref is not None and isinstance(ref.dtype, t.DecimalType) \
+                and ref.dtype.is_wide \
+                and db.column_by_name(ref.name).data_hi is not None:
             raise NotImplementedError(
-                f"128-bit host decimal input to {fn.name} not supported "
-                "on device")
+                f"128-bit host decimal input {ref.name} to an aggregate "
+                "not supported on device")
 
 
 def _storage_zeros(dt: t.DataType, capacity: int):
@@ -315,7 +328,6 @@ class HashAggregate:
         # dominant group-by cost at big buckets (~390ms for one 8M int64
         # lane), so halving its width is material
         self._input_ranges_by_expr = input_ranges or {}
-        check_agg_buffers_supported(self.aggs)
         # flatten buffers
         self.update_specs: List[G.AggSpec] = []
         self.merge_specs: List[G.AggSpec] = []
@@ -384,6 +396,7 @@ class HashAggregate:
         `live` (optional bool mask) lets an upstream filter fuse into the
         aggregation: filtered rows simply never contribute — no compaction
         (= no TPU row gather) between filter and agg."""
+        check_agg_inputs_single_lane(self.input_exprs, db)
         key_batch = evaluate_projection(self.key_exprs, self.key_names, db,
                                         self.conf) if self.key_exprs else None
         agg_in = evaluate_projection(
@@ -398,11 +411,57 @@ class HashAggregate:
             outs = _run_reduce(agg_cols, self.update_specs, live, db.capacity)
             self._note("reduce", db.capacity)
             return self._reduce_outs_to_batch(outs)
-        key_cols, out_keys, outs, n_groups, strategy = _run_groupby(
-            key_batch.columns, agg_cols, self.update_specs, live,
-            db.capacity, key_ranges=self.key_ranges, conf=self.conf)
-        self._note(strategy, db.capacity)
-        return self._groupby_outs_to_batch(key_cols, out_keys, outs, n_groups)
+        return self._update(key_batch.columns, agg_cols, live, db.capacity)
+
+    def _update(self, key_cols, agg_cols, live, capacity: int,
+                in_place: bool = False) -> DeviceBatch:
+        key_cols, out_keys, outs, n_groups, strategy, sel = _run_groupby(
+            key_cols, agg_cols, self.update_specs, live, capacity,
+            key_ranges=self.key_ranges, conf=self.conf, in_place=in_place)
+        self._note(strategy, capacity)
+        return self._groupby_outs_to_batch(key_cols, out_keys, outs,
+                                           n_groups, sel)
+
+    # ---- one update over the stacked input (whole-plan traces) ----
+
+    def project_inputs(self, db: DeviceBatch, live) -> DeviceBatch:
+        """The keys and the aggregates' inputs of one batch, evaluated
+        and left where they are under the selection vector `live`: what
+        `update_stacked` stacks."""
+        check_agg_inputs_single_lane(self.input_exprs, db)
+        nk = len(self.key_exprs)
+        names = list(self.key_names) + [
+            f"_in{i}" for i in range(len(self.input_exprs))]
+        out = evaluate_projection(
+            list(self.key_exprs) + list(self.input_exprs), names, db,
+            self.conf)
+        cols = list(out.columns[:nk]) + self._narrow_cols(out.columns[nk:])
+        return DeviceBatch(cols, jnp.sum(live, dtype=jnp.int32), names,
+                           db.origin_file, sel=live)
+
+    def partials_keep_capacity(self, projected: DeviceBatch) -> bool:
+        """Whether a partial aggregate of `projected` (project_inputs)
+        would come out at its input's capacity when its group count is a
+        value of the program: it sorts its rows by key (no dense domain)
+        and states no bound on its groups.  Such partials reduce nothing
+        that is static, so merging them sorts every row twice."""
+        key_cols = [ensure_unique_dict(c)
+                    for c in projected.columns[:len(self.key_exprs)]]
+        return _dense_domains(key_cols, self.conf) is None \
+            and self._static_group_bound(key_cols) is None
+
+    def update_stacked(self, projected: List[DeviceBatch],
+                       in_place: bool = False) -> DeviceBatch:
+        """ONE update aggregation over the stacked `project_inputs` of
+        every input batch, in place of a partial a batch and their
+        merge (keys + buffer columns, as `partial` returns them).
+        `in_place`: the consumer reads liveness as a mask, so a sorted
+        aggregate may leave each group where its run ended, under a
+        selection vector (G.packed_groupby_trace)."""
+        stacked = concat_batches(projected, self.conf, masked=True)
+        nk = len(self.key_exprs)
+        return self._update(stacked.columns[:nk], stacked.columns[nk:],
+                            stacked.row_mask(), stacked.capacity, in_place)
 
     def can_fuse_filter(self, db: "Optional[DeviceBatch]" = None) -> bool:
         """Whether the whole map side (filter mask + projections + update
@@ -473,6 +532,7 @@ class HashAggregate:
             # ragged kernels assume prefix liveness (see evaluator)
             from ..ops.batch_ops import ensure_prefix
             db = ensure_prefix(db, self.conf)
+        check_agg_inputs_single_lane(self.input_exprs, db)
         exprs_all = list(conds) + self.key_exprs + self.input_exprs
         pctx, hostvals, aux = _prepare(exprs_all, db, self.conf)
         spec_sig = tuple((s.kind, s.input_idx, str(s.dtype))
@@ -637,7 +697,7 @@ class HashAggregate:
                                merged.capacity)
             self._note("reduce", merged.capacity)
             return self._reduce_outs_to_batch(outs)
-        key_cols, out_keys, outs, n_groups, strategy = _run_groupby(
+        key_cols, out_keys, outs, n_groups, strategy, _sel = _run_groupby(
             key_cols, buf_cols, self.merge_specs, merged.row_mask(),
             merged.capacity, key_ranges=self.key_ranges, conf=self.conf)
         self._note(strategy, merged.capacity)
@@ -690,7 +750,8 @@ class HashAggregate:
                 return None
         return bound
 
-    def _groupby_outs_to_batch(self, key_cols, out_keys, outs, n_groups):
+    def _groupby_outs_to_batch(self, key_cols, out_keys, outs, n_groups,
+                               sel=None):
         cols = []
         for (kd, kv), kc in zip(out_keys, key_cols):
             cols.append(DeviceColumn(kd, kv, kc.dtype, kc.dictionary,
@@ -699,7 +760,10 @@ class HashAggregate:
         for (data, valid), spec in zip(outs, self.update_specs):
             cols.append(DeviceColumn(data.astype(_storage_zeros(
                 spec.dtype, 1).dtype), valid, spec.dtype))
-        db = DeviceBatch(cols, n_groups, self.key_names + self._buffer_names())
+        db = DeviceBatch(cols, n_groups,
+                         self.key_names + self._buffer_names(), sel=sel)
+        if sel is not None:
+            return db          # groups in place, under the mask
         if isinstance(n_groups, int):
             return shrink_to_rows(db, n_groups, self.conf)
         # lazy group count: shrink by the static key-domain bound instead

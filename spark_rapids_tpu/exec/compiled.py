@@ -441,6 +441,8 @@ def _node_extras(node) -> tuple:
     if jt is not None:
         extras.append(("join", jt, getattr(node, "lazy_sel", None),
                        getattr(node, "thin_payload", None)))
+    if getattr(node, "seam_lazy", False):
+        extras.append("seam_lazy")      # _hand_masks_to_seam
     names = getattr(node, "names", None) or getattr(node, "key_names", None)
     if names is not None:
         extras.append(tuple(names))
@@ -652,13 +654,19 @@ def _seam_bucket_get(key: Optional[tuple]) -> Optional[tuple]:
         return _SEAM_BUCKET_CACHE.get(key)
 
 
-#: Trace-time counters under this prefix count once per RUN of the
+#: Trace-time counters under these prefixes count once per RUN of the
 #: program: an aggregate's strategy, capacity and merged batches
-#: (exec/aggregate.py `_note`, HashAggregateExec), decided while its
-#: node is traced into the program.  Every other host number of a trace
-#: is a fact of the plan, copied into `ctx.metrics` when the program is
-#: compiled or adopted.
-_PER_RUN_PREFIX = "agg."
+#: (exec/aggregate.py `_note`, HashAggregateExec), the expressions over
+#: a wide decimal computed on the device (counted by the planner, kept
+#: on the nodes) and the largest static build bound of a join
+#: (exec/join.py), decided while the node is traced into the program.
+#: Every other host number of a trace is a fact of the plan, copied into
+#: `ctx.metrics` when the program is compiled or adopted.
+_PER_RUN_PREFIX = ("agg.", "expr.wide_decimal_device",
+                   "join.build_bound_rows")
+#: ... of which these read the largest value among the collect's
+#: programs, not the sum over them
+_PER_RUN_MAX = frozenset({"join.build_bound_rows"})
 
 
 class CompiledPlan:
@@ -781,6 +789,14 @@ class CompiledPlan:
             trace_ctx = _trace_context(ctx)
             try:
                 outs = list(self.root.execute(trace_ctx))
+                # expressions over a wide decimal computed on the device,
+                # as the planner counted them on this program's nodes
+                wide = sum(getattr(n, "wide_decimal_exprs", 0)
+                           for n in {id(n): n for n in
+                                     [self.root, *_walk_nodes(self.root)]
+                                     }.values())
+                if wide:
+                    trace_ctx.bump("expr.wide_decimal_device", wide)
             finally:
                 if lit_ids:
                     set_literal_bindings(None)
@@ -1049,7 +1065,10 @@ class CompiledPlan:
         # when no profiled decomposition exists for this run
         m["exec_dispatches"] = m.get("exec_dispatches", 0) + 1
         for k, n in self._run_counts.items():
-            ctx.bump(k, n)
+            if k in _PER_RUN_MAX:
+                m[k] = max(m.get(k, 0), n)
+            else:
+                ctx.bump(k, n)
         if self.mesh is not None:
             m["mesh.devices"] = self.mesh.devices.size
             m["mesh.replicated_lanes"] = _unsplit_lanes(flat_in)
@@ -1251,7 +1270,7 @@ def _trace_context(ctx: ExecContext) -> ExecContext:
     # fault injection under jit tracing would bake a synthetic failure
     # into the compiled program; chaos targets the runtime layers only
     raw[TEST_FAULTS.key] = ""
-    return ExecContext(TpuConf(raw))
+    return ExecContext(TpuConf(raw), traced=True)
 
 
 # errors that mean "this plan needs host decisions" — not bugs
@@ -1303,6 +1322,12 @@ def _find_split_seams(root: PlanNode, conf=None) -> List[PlanNode]:
     """Innermost-first seam nodes where live row counts collapse but
     static bucket capacities do not:
 
+      0. the build sides, down one path under seam 1, that are real work
+         and whose static capacity is over the sub-partition gate
+         (_oversized_builds): where the eager join asks the host for the
+         build's row count, the whole-plan path splits, so that the
+         count is a host number and the join is traced at the bucket of
+         the live rows;
       1. the input of the topmost aggregate (after its fused-filter
          chain) when it is real work (a join subtree, not a bare scan) —
          selective joins + fused filters typically leave a small
@@ -1345,8 +1370,82 @@ def _find_split_seams(root: PlanNode, conf=None) -> List[PlanNode]:
     while isinstance(source, FilterExec):
         source = source.child
     if not isinstance(source, (HostScanExec, DeviceResidentScanExec)):
+        seams.extend(_oversized_builds(source, c))
         seams.append(source)
     seams.append(agg)
+    return seams
+
+
+def _is_join(n: PlanNode) -> bool:
+    from .adaptive import AdaptiveShuffledJoinExec
+    from .join import HashJoinExec
+    return isinstance(n, (HashJoinExec, AdaptiveShuffledJoinExec))
+
+
+def _capacity_bound(n: PlanNode, conf: TpuConf, compacted: set) -> int:
+    """Rows of capacity that `n`'s output batches have in a whole-plan
+    trace, from what is static: a scan's buckets, the row bound a node
+    states, a sorted aggregate's stacked partials, a join's probe side
+    (its output is probe-aligned under a selection mask; an adaptive
+    join may take either side for the probe).  A node in `compacted` is
+    a seam: its output is re-bucketed to its live rows, and counts as
+    nothing here."""
+    from .plan import HashAggregateExec
+    if id(n) in compacted:
+        return 0
+    if isinstance(n, (HostScanExec, DeviceResidentScanExec)):
+        return sum(bucket_capacity(max(b.num_rows, 1), conf)
+                   if isinstance(n, HostScanExec) else b.capacity
+                   for b in n.batches)
+    bound = n.row_upper_bound()
+    if bound is not None:
+        return bucket_capacity(max(int(bound), 1), conf)
+    below = [_capacity_bound(c, conf, compacted) for c in n.children]
+    if _is_join(n):
+        return below[0] if n.join_type in ("left_semi", "left_anti") \
+            else max(below)
+    if isinstance(n, HashAggregateExec):
+        return bucket_capacity(max(below[0], 1), conf)
+    return sum(below)
+
+
+def _oversized_builds(source: PlanNode, conf: TpuConf) -> List[PlanNode]:
+    """Innermost-first: build sides (a join's right child) on one path
+    down from `source`, each inside the one before, that are real work
+    (a join or an aggregate below, not a scan under filters) and whose
+    static capacity is over the eager join's sub-partition gate
+    (exec/join.py `subpartition_row_gate`).  There the eager join reads
+    the build's row count on the host; a whole-plan program cannot, so
+    the plan splits under the join instead (ROADMAP C1).  The path goes
+    down into a build side that is real work, else into the probe side;
+    the bounds are taken bottom-up, a seam below counting as nothing."""
+    from .join import subpartition_row_gate
+
+    def real_work(n: PlanNode) -> bool:
+        from .plan import HashAggregateExec
+        return _is_join(n) or isinstance(n, HashAggregateExec) \
+            or any(real_work(c) for c in n.children)
+
+    path: List[PlanNode] = []          # candidate build sides, outermost first
+    node = source
+    while node.children:
+        if not _is_join(node):
+            if len(node.children) != 1:
+                break
+            node = node.children[0]
+        elif real_work(node.children[1]):
+            node = node.children[1]
+            path.append(node)
+        else:
+            node = node.children[0]
+    gate = subpartition_row_gate(conf)
+    seams: List[PlanNode] = []
+    compacted: set = set()
+    for build in reversed(path):
+        if _capacity_bound(build, conf, compacted) > gate:
+            seams.append(build)
+            compacted.add(id(build))
+            build.build_side_seam = True      # SplitCompiledPlan.collect
     return seams
 
 
@@ -1416,6 +1515,22 @@ def _resolve_at(db: DeviceBatch, cap: int, scope: str,
     return _rebuild_batch(fn(flat), out_spec, 0)[0]
 
 
+def _hand_masks_to_seam(seam: PlanNode) -> None:
+    """The filters at the top of a seam's segment, under projections
+    alone, and the join or the aggregate under them hand their keep-mask
+    to the seam as a selection vector (`seam_lazy` on FilterExec,
+    HashJoinExec, HashAggregateExec): _shrink resolves it after the
+    row-count sync, at the bucket of the live rows, where the operator
+    would have compacted every column at the capacity of its input."""
+    from .plan import FilterExec, HashAggregateExec, ProjectExec
+    node = seam
+    while isinstance(node, (FilterExec, ProjectExec)):
+        node.seam_lazy = True          # a projection hands any mask on
+        node = node.child
+    if _is_join(node) or isinstance(node, HashAggregateExec):
+        node.seam_lazy = True          # its matched rows, or run ends
+
+
 def _swap_child(root: PlanNode, old: PlanNode, new: PlanNode):
     """EVERY (parent, index) link to `old` under `root`; caller mutates
     + restores.  Plan-level CSE (plan/overrides._dedupe_agg_twins) can
@@ -1466,6 +1581,8 @@ class SplitCompiledPlan:
         self.root = root
         self.conf = conf
         self.seams = list(seams)            # innermost-first
+        for seam in self.seams:
+            _hand_masks_to_seam(seam)
         self.leaves = [DeviceResidentScanExec(s) for s in self.seams]
         #: the compile service's task keys start with this: an id()
         #: would come back with another plan once this one is collected
@@ -1715,6 +1832,11 @@ class SplitCompiledPlan:
                 # re-bucket per batch, the dominant fixed cost of split
                 # plans on small inputs — overhead.seam_* feeds
                 # wall_breakdown(), the history plane, and the seam gate
+                if getattr(self.seams[i], "build_side_seam", False):
+                    # the bound the join above would have been traced at
+                    ctx.metrics["join.build_bound_rows"] = max(
+                        ctx.metrics.get("join.build_bound_rows", 0),
+                        sum(db.capacity for db in outs))
                 with CollectSpan(ctx, "seam", "overhead.seam_ms",
                                  cat="transition"):
                     sliced, rows = self._shrink(

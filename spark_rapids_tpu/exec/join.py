@@ -84,6 +84,14 @@ def join_column_range(join_type: str, left, right, name):
     return None
 
 
+def subpartition_row_gate(conf) -> int:
+    """Build-side rows over which the eager join re-partitions both sides
+    into sub-joins (GpuSubPartitionHashJoin's role): two target batches.
+    The whole-plan path splits its plan under a join whose build side is
+    bounded over it (exec/compiled.py _oversized_builds)."""
+    return 2 * conf.batch_size_rows
+
+
 def _join_partition_ids(key_cols: List[DeviceColumn], db: DeviceBatch,
                         num_buckets: int, salt: int = 0) -> jax.Array:
     """Bucket ids from join-key columns; value-stable across sides and
@@ -118,6 +126,12 @@ class HashJoinExec(PlanNode):
         # post-pass) lets this join emit a selection vector instead of
         # compacting its output
         self.lazy_sel = False
+        # seam_lazy: this join's output reaches a whole-plan seam through
+        # projections and filters only (exec/compiled.py
+        # _hand_masks_to_seam); the seam resolves a selection vector at
+        # the bucket of the live rows, so inside the traced program the
+        # join emits one as for a mask-aware parent
+        self.seam_lazy = False
         # LATE MATERIALIZATION (columnar/lanes.py): output column names
         # the parent pipeline allows to ride as row-id lanes instead of
         # gathered payloads.  None = disabled; set by the overrides
@@ -318,7 +332,17 @@ class HashJoinExec(PlanNode):
         from ..config import HASH_SUBPARTITION_FALLBACK
         from . import ooc as O
         build_rows_bound = sum(b.capacity for b in right_batches)
-        if ctx.conf.get(HASH_SUBPARTITION_FALLBACK):
+        # the largest static bound a build side of this collect had
+        ctx.metrics["join.build_bound_rows"] = max(
+            ctx.metrics.get("join.build_bound_rows", 0), build_rows_bound)
+        # Inside a whole-plan program the live row count is a value of
+        # the program, and the sub-partition / out-of-core route below is
+        # not one a program can take (its buckets are sized on the host;
+        # spill and re-partitioning run on the eager engine: ROADMAP C3).
+        # So a traced join decides from what is static and asks the host
+        # for nothing: it builds in-program at the bound, whatever the
+        # bound.
+        if ctx.conf.get(HASH_SUBPARTITION_FALLBACK) and not ctx.traced:
             # Oversized build side: re-hash-partition BOTH sides into
             # independent sub-joins (GpuSubPartitionHashJoin.scala:32) —
             # equal keys hash to the same bucket on both sides, so the
@@ -329,13 +353,13 @@ class HashJoinExec(PlanNode):
             # legacy 2-target-batch row gate kept as the floor and the
             # escalated/forced context tripping unconditionally.
             policy = O.ooc_policy(ctx)
-            rows_trip = build_rows_bound > 2 * ctx.conf.batch_size_rows
+            rows_trip = build_rows_bound > subpartition_row_gate(ctx.conf)
             bytes_trip = policy.bytes_trip(
                 sum(b.nbytes() for b in right_batches))
             if rows_trip or bytes_trip or policy.force:
                 build_rows = sum(int(b.num_rows) for b in right_batches)
                 build_bytes = sum(O.batch_bytes(b) for b in right_batches)
-                if build_rows > 2 * ctx.conf.batch_size_rows or \
+                if build_rows > subpartition_row_gate(ctx.conf) or \
                         policy.bytes_trip(build_bytes) or policy.force:
                     yield from self._sub_partition_join(
                         right_batches, left_src, build_conds, probe_conds,
@@ -567,6 +591,7 @@ class HashJoinExec(PlanNode):
                      ctx: ExecContext, build_conds=(), probe_conds=()
                      ) -> Iterator[DeviceBatch]:
         raw_pos = self._raw_key_positions()
+        lazy_sel = self.lazy_sel or (self.seam_lazy and ctx.traced)
         build_keys = self._key_cols(build_batch, self.right_keys, raw_pos,
                                     ctx)
         # fused build-side filters: rows failing them never match and
@@ -688,7 +713,7 @@ class HashJoinExec(PlanNode):
                             cum, out_cap, total)
                 keep = matched if self.join_type == J.LEFT_SEMI \
                     else pre & ~matched
-                if self.lazy_sel or (transparent and pb.thin is not None):
+                if lazy_sel or (transparent and pb.thin is not None):
                     # mask-aware parent (aggregation live mask / another
                     # join's probe liveness) or a thin stream: skip the
                     # compaction — row gathers are the dominant device
@@ -748,7 +773,7 @@ class HashJoinExec(PlanNode):
                         yield out if pb.sel is None else DeviceBatch(
                             out.columns, pb.num_rows, out_names,
                             sel=pb.sel, thin=thin)
-                    elif self.lazy_sel or thin is not None:
+                    elif lazy_sel or thin is not None:
                         yield DeviceBatch(out.columns,
                                           jnp.sum(pre, dtype=jnp.int32),
                                           out_names, sel=pre, thin=thin)
@@ -759,7 +784,7 @@ class HashJoinExec(PlanNode):
                                         pb.num_rows, out_names, thin=thin)
                     keep = ok & pre
                     if self.join_type == J.INNER and \
-                            (self.lazy_sel or thin is not None):
+                            (lazy_sel or thin is not None):
                         yield DeviceBatch(pairs.columns,
                                           jnp.sum(keep, dtype=jnp.int32),
                                           out_names, sel=keep, thin=thin)

@@ -97,6 +97,10 @@ class ExecContext:
     # OOM ladder / proactive election / serving admission; every
     # eligible hash join and aggregation then runs spill-partitioned
     ooc_force: bool = False
+    # True for the context a whole-plan program is traced under
+    # (exec/compiled.py _trace_context): row counts may be values of the
+    # program, and no route that needs the host or the budget exists
+    traced: bool = False
     # cooperative cancellation (serving deadlines / graceful drain):
     # absolute time.monotonic() deadline (0 = none) and an optional
     # threading.Event — checkpoint() raises past either
@@ -495,6 +499,13 @@ class FilterExec(PlanNode):
     def __init__(self, condition: E.Expression, child: PlanNode):
         super().__init__(child)
         self.condition = condition.bind(child.output_schema)
+        # set where the filter's output reaches a whole-plan seam through
+        # projections and filters only (exec/compiled.py
+        # _hand_masks_to_seam): the seam resolves a selection vector at
+        # the bucket of the live rows, so inside the traced program the
+        # mask is handed over and nothing is compacted at the input's
+        # capacity
+        self.seam_lazy = False
 
     @property
     def output_schema(self) -> t.StructType:
@@ -535,6 +546,12 @@ class FilterExec(PlanNode):
                     continue
             else:
                 keep = compute_predicate(self.condition, db, ctx.conf)
+                if self.seam_lazy and ctx.traced and not any(
+                        c.offsets is not None for c in db.columns):
+                    yield DeviceBatch(list(db.columns),
+                                      jnp.sum(keep, dtype=jnp.int32),
+                                      db.names, db.origin_file, sel=keep)
+                    continue
             # lazy row count: downstream device ops keep running sync-free
             yield compact_batch(db, keep, ctx.conf)
 
@@ -632,8 +649,11 @@ class HashAggregateExec(PlanNode):
         self.key_exprs = [e.bind(schema) for e in key_exprs]
         self.key_names = list(key_names)
         self.aggs = [(fn.bind(schema), name) for fn, name in aggs]
-        from .aggregate import check_agg_buffers_supported
-        check_agg_buffers_supported(self.aggs)
+        # set where this aggregate's output reaches a whole-plan seam
+        # through projections and filters only (exec/compiled.py
+        # _hand_masks_to_seam): inside the traced program its groups may
+        # then stay where their runs ended, under a selection vector
+        self.seam_lazy = False
 
     @property
     def output_schema(self) -> t.StructType:
@@ -739,6 +759,16 @@ class HashAggregateExec(PlanNode):
         # skip the compact: the mask evaluates as its own program.
         source, conds = self._strip_filters(True)
         policy = O.ooc_policy(ctx)
+        # Inside a whole-plan program a group count is a value of the
+        # program: a partial aggregate that sorts its rows comes out at
+        # its input's capacity however few groups it found, and the
+        # merge sorts all of it again (merged as the batches come, as
+        # below, at twice the capacity every time: 4M rows x 2^14 after
+        # 15 batches).  There the batches' keys and inputs are stacked
+        # as they come and aggregated ONCE (HashAggregate.update_stacked);
+        # decided on the first batch.
+        stack = ctx.traced and bool(self.key_exprs)
+        stacked: List[DeviceBatch] = []
         partials: List[DeviceBatch] = []
         partial_bytes = 0
         oocagg: "OutOfCoreAggregator | None" = None
@@ -765,6 +795,18 @@ class HashAggregateExec(PlanNode):
                 db = materialize_refs(
                     db, list(conds) + list(self.key_exprs) +
                     list(agg.input_exprs), ctx.conf)
+            if stack:
+                from .evaluator import compute_predicate
+                live = db.row_mask()
+                for c in conds:
+                    live = live & compute_predicate(c, db, ctx.conf)
+                projected = agg.project_inputs(db, live)
+                stack = bool(stacked) or \
+                    agg.partials_keep_capacity(projected)
+                if stack:
+                    stacked.append(projected)
+                    ctx.bump("agg.partial_batches")
+                    continue
             if agg.can_fuse_filter(db):
                 p = agg.partial_fused(db, conds)
             else:
@@ -820,6 +862,9 @@ class HashAggregateExec(PlanNode):
             # results() owns the cleanup sweep (idempotent closes), so a
             # LIMIT above this aggregation leaks no spill files
             yield from oocagg.results()
+            return
+        if stacked:
+            yield agg.final(agg.update_stacked(stacked, self.seam_lazy))
             return
         if not seen:
             if self.key_exprs:

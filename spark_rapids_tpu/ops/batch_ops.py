@@ -159,15 +159,24 @@ def ensure_prefix(db: DeviceBatch, conf: TpuConf = DEFAULT_CONF
 
 
 def concat_batches(batches: List[DeviceBatch],
-                   conf: TpuConf = DEFAULT_CONF) -> DeviceBatch:
+                   conf: TpuConf = DEFAULT_CONF,
+                   masked: bool = False) -> DeviceBatch:
     """Concatenate device batches (same schema) into one bucketed batch.
 
     Batches with host-known counts concatenate tightly (layout decisions
     on host).  If ANY count is lazy (a device scalar / tracer), the lazy
     path concatenates full-capacity lanes and compacts live rows to the
     front on device — zero host syncs, at the cost of padding up to the
-    capacity sum."""
+    capacity sum.  `masked`: the caller reads liveness as a mask
+    (`row_mask()`: a sorted group-by does); where every count is lazy and
+    nothing is deferred, the lanes are stacked as they are, selection
+    vectors included, and come back under one — no compaction, which is
+    an argsort and a gather a column over the whole capacity sum."""
     assert batches, "concat of zero batches"
+    if masked and all(b.thin is None and not isinstance(b.num_rows, int)
+                      for b in batches):
+        return batches[0] if len(batches) == 1 \
+            else _concat_batches_lazy(batches, conf, compact=False)
     batches = [ensure_prefix(b, conf) for b in batches]
     if len(batches) == 1:
         return batches[0]
@@ -217,11 +226,13 @@ def concat_batches(batches: List[DeviceBatch],
                        merge_origin(b.origin_file for b in batches))
 
 
-def _concat_batches_lazy(batches: List[DeviceBatch],
-                         conf: TpuConf) -> DeviceBatch:
+def _concat_batches_lazy(batches: List[DeviceBatch], conf: TpuConf,
+                         compact: bool = True) -> DeviceBatch:
     """Sync-free concat: stack full-capacity lanes, then compact live rows
-    to the front on device (ops/filter.py).  Capacities are host facts, so
-    the output shape is static; the row count stays a device scalar."""
+    to the front on device (ops/filter.py), or leave them where they are
+    under a selection vector (`compact` false).  Capacities are host
+    facts, so the output shape is static; the row count stays a device
+    scalar."""
     from ..columnar.device import merge_origin
     from .filter import compact_batch
     cap_total = sum(b.capacity for b in batches)
@@ -262,9 +273,11 @@ def _concat_batches_lazy(batches: List[DeviceBatch],
                                      jnp.concatenate(valid_parts),
                                      dt, unified, hi))
     total = sum(jnp.int32(b.num_rows) for b in batches)
-    db = DeviceBatch(out_cols, total, names,
-                     merge_origin(b.origin_file for b in batches))
-    return compact_batch(db, keep, conf)
+    origin = merge_origin(b.origin_file for b in batches)
+    if not compact:
+        return DeviceBatch(out_cols, total, names, origin, sel=keep)
+    return compact_batch(DeviceBatch(out_cols, total, names, origin), keep,
+                         conf)
 
 
 def shrink_to_capacity(db: DeviceBatch, row_bound: int,

@@ -10,9 +10,17 @@ no int128; this engine's decimal story (columnar/device.py):
     widens to arrow decimal128.  Values beyond int64's ~9.2e18 unscaled
     range null out where Spark's 128-bit math would succeed — a documented
     deviation (docs/compatibility.md analogue) the same spirit as the
-    reference's float-ordering notes.  Host columns that *arrive* wider
-    than int64 (true 128-bit data) are not computed on device (tagged,
-    CPU fallback).
+    reference's float-ordering notes.  Such a result is consumable by the
+    operators above it like any int64 lane: comparisons (`having sum(x) >
+    300`: the literal cast to the common decimal, exact), arithmetic,
+    casts, further aggregates.  Host columns that *arrive* wider than
+    int64 (true 128-bit data: a scan, or the output of an operator the
+    planner placed on the CPU) carry a second (hi) lane and are not
+    computed on device (tagged, CPU fallback).  Which of the two a
+    reference reads is decided from where the column comes from in the
+    plan (plan/overrides.py `PlanMeta.wide_host_columns`, which marks the
+    references through `mark_device_decimals`), never from the type; at
+    run time the batch says the same (`DeviceColumn.data_hi`).
 
 Spark result-type rules (DecimalPrecision, allowPrecisionLoss=true):
   add/sub: s = max(s1,s2);          p = max(p1-s1, p2-s2) + s + 1

@@ -249,7 +249,8 @@ def _segment_minmax_float_sorted(vals, valid_live, boundary, ends_c,
 
 def sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary, starts_c,
                        ends_c, group_live, num_segments: int,
-                       capacity: int, scatter_free: bool):
+                       capacity: int, scatter_free: bool,
+                       abutting: bool = False, riders=None):
     """Aggregate outputs over SORTED runs — the one implementation both
     the packed and the generic sort-segment group-bys share.
 
@@ -259,7 +260,12 @@ def sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary, starts_c,
     blocked segmented scan + boundary gather / stacked-cumsum diff
     (ops/segments.py) — zero jax.ops.segment_* scatters in the emitted
     program; without it the legacy segment (scatter) reductions run, so
-    the two modes are flip-comparable under one knob."""
+    the two modes are flip-comparable under one knob.
+
+    `abutting`, `riders`: seg_sums_sorted's (the packed group-by's runs
+    abut, and its key lane rides the sums' gather at the run ends).
+    With `riders` (a list of int64 lanes) the result is (outs, the lanes
+    read at `ends_c`)."""
     iota = jnp.arange(capacity, dtype=jnp.int32)
     big = jnp.int32(capacity)
     seg_ids = None
@@ -285,12 +291,16 @@ def sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary, starts_c,
     int_lanes, int_slots, f64_lanes, f64_slots = _queue_sum_lanes(
         agg_specs, spec_vls, s_live)
     int_out = f64_out = None
+    rode = None
     if int_lanes:
         if scatter_free:
             # stacked cumsum + two boundary gathers; int64 wraparound
             # cancels in the diff (exact whenever the group sum fits
             # int64 — segment_sum's own contract)
-            int_out = seg_sums_sorted(int_lanes, starts_c, ends_c)
+            int_out = seg_sums_sorted(int_lanes, starts_c, ends_c,
+                                      abutting, riders or ())
+            if riders:
+                int_out, rode = int_out
         else:
             int_out = jax.ops.segment_sum(
                 jnp.stack(int_lanes, axis=1), seg(),
@@ -372,7 +382,9 @@ def sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary, starts_c,
         else:
             raise ValueError(f"unknown agg kind {spec.kind}")
         outs.append((data, out_valid))
-    return outs
+    if riders is None:
+        return outs
+    return outs, rode if rode is not None else [r[ends_c] for r in riders]
 
 
 def _packed_key_lane(keys, keys_valid, pack_spec):
@@ -398,7 +410,8 @@ def _packed_key_lane(keys, keys_valid, pack_spec):
 
 
 def packed_groupby_trace(pack_spec, key_lanes_info, agg_specs,
-                         num_segments, capacity, scatter_free=True):
+                         num_segments, capacity, scatter_free=True,
+                         in_place=False):
     """All-keys-packed group-by: ONE sort lane, NO scatters for the
     sum/count family, group keys decoded arithmetically.
 
@@ -424,7 +437,23 @@ def packed_groupby_trace(pack_spec, key_lanes_info, agg_specs,
     segment_sum semantics.  MIN/MAX, ignore-null FIRST/LAST, ANY/EVERY
     and f64 sums run through the same scatter-free sorted-run layer
     (sorted_agg_outputs): segmented scans gathered at run ends, so the
-    whole program emits ZERO scatters when `scatter_free` holds."""
+    whole program emits ZERO scatters when `scatter_free` holds.
+
+    Two realisations take the row gathers out as well (on the chip a
+    gather over the capacity costs more than the sort: PERF.md section
+    6, PR 35):
+
+      * the payload sort: where the aggregates read ONE input lane of at
+        most 32 bits, the lane and its validity ride the sort as its
+        second operand, (value << 1 | valid) in an int64, in the place of
+        the row ids; nothing is permuted afterwards;
+      * `in_place` (in_place_supported): every run's result is left at
+        the run's LAST row, where a segmented scan has it, and the
+        function returns a fourth value, the mask of those rows, for the
+        caller to hand on as a selection vector.  No group starts are
+        sorted to the front and nothing is gathered at the run ends; the
+        groups are compacted by whoever needs them dense (a whole-plan
+        seam, at the bucket of the rows that are left by then)."""
     spans = [s[1] for s in pack_spec]
     los = [s[0] for s in pack_spec]
     strides = []
@@ -435,18 +464,74 @@ def packed_groupby_trace(pack_spec, key_lanes_info, agg_specs,
     strides.reverse()
     total = tot
     key_dt = jnp.int32 if total < (1 << 31) - 1 else jnp.int64
+    need = sorted({s.input_idx for s in agg_specs if s.input_idx >= 0})
+    in_place = in_place and in_place_supported(agg_specs)
+
+    def decode_keys(pk, alive):
+        """Keys decode from the packed value — zero key gathers."""
+        out_keys = []
+        for (dt, _hv, lane_dt), lo, span, stride in zip(
+                key_lanes_info, los, spans, strides):
+            slot = (pk // jnp.int64(stride)) % jnp.int64(span)
+            data = (slot - 1 + jnp.int64(lo)).astype(jnp.dtype(lane_dt))
+            out_keys.append((data, (slot > 0) & alive))
+        return out_keys
 
     def run(keys, keys_valid, agg_data, agg_valid, live):
         packed = _packed_key_lane(keys, keys_valid, pack_spec)
         skey = jnp.where(live, packed, jnp.int64(total)).astype(key_dt)
         iota = jnp.arange(capacity, dtype=jnp.int32)
-        skey_s, perm = jax.lax.sort((skey, iota), num_keys=1,
-                                    is_stable=True)
+        valid_of = {i: jnp.ones((capacity,), bool) if agg_valid[i] is None
+                    else agg_valid[i] for i in need}
+        payload = len(need) == 1 and _rides_the_sort(agg_data[need[0]])
+        if not need:
+            skey_s, perm = jnp.sort(skey), None
+        elif payload:
+            d = agg_data[need[0]]
+            ride = (d.astype(jnp.int64) << 1) \
+                | valid_of[need[0]].astype(jnp.int64)
+            skey_s, ride_s = jax.lax.sort((skey, ride), num_keys=1,
+                                          is_stable=True)
+        else:
+            skey_s, perm = jax.lax.sort((skey, iota), num_keys=1,
+                                        is_stable=True)
         s_live = skey_s < jnp.asarray(total, key_dt)
         count = jnp.sum(live, dtype=jnp.int32)
         boundary = jnp.concatenate(
             [jnp.ones((1,), bool), skey_s[1:] != skey_s[:-1]]) & s_live
         num_groups = jnp.sum(boundary, dtype=jnp.int32)
+
+        s_in = {}
+        if payload:
+            s_in[need[0]] = ((ride_s >> 1).astype(d.dtype),
+                             (ride_s & 1).astype(bool) & s_live)
+        elif need:
+            # permute agg inputs once, stacked by dtype class; an integer
+            # lane's validity rides in the lane's own dtype, so that the
+            # two are rows of ONE gathered matrix (a TPU gather pays per
+            # gathered row, not per lane)
+            from .filter import grouped_take
+            lanes = []
+            for i in need:
+                lanes.append(agg_data[i])
+                lanes.append(valid_of[i].astype(agg_data[i].dtype)
+                             if jnp.issubdtype(agg_data[i].dtype,
+                                               jnp.integer)
+                             else valid_of[i])
+            moved = grouped_take(lanes, perm)
+            for j, i in enumerate(need):
+                s_in[i] = (moved[2 * j],
+                           moved[2 * j + 1].astype(bool) & s_live)
+        spec_vls = [s_in[spec.input_idx] if spec.input_idx >= 0
+                    else (None, s_live) for spec in agg_specs]
+
+        if in_place:
+            is_end = jnp.concatenate(
+                [skey_s[1:] != skey_s[:-1], jnp.ones((1,), bool)]) & s_live
+            outs = _in_place_outputs(agg_specs, spec_vls, s_live, boundary,
+                                     is_end)
+            return (decode_keys(skey_s.astype(jnp.int64), is_end), outs,
+                    num_groups, is_end)
 
         # group start positions, compacted to the front by a SINGLE-lane
         # sort (scatter-free segment_min)
@@ -459,44 +544,80 @@ def packed_groupby_trace(pack_spec, key_lanes_info, agg_specs,
         ends_c = jnp.clip(jnp.minimum(nexts - 1, count - 1), 0,
                           capacity - 1)
 
-        # keys decode from the packed value — zero key gathers
-        pk = skey_s[starts_c].astype(jnp.int64)
-        out_keys = []
-        for (dt, _hv, lane_dt), lo, span, stride in zip(
-                key_lanes_info, los, spans, strides):
-            slot = (pk // jnp.int64(stride)) % jnp.int64(span)
-            data = (slot - 1 + jnp.int64(lo)).astype(jnp.dtype(lane_dt))
-            out_keys.append((data, (slot > 0) & group_live))
-
-        # permute agg inputs once, stacked by dtype class
-        from .filter import grouped_take
-        need = sorted({s.input_idx for s in agg_specs if s.input_idx >= 0})
-        lanes = []
-        for i in need:
-            v = agg_valid[i]
-            lanes.append(agg_data[i])
-            lanes.append(jnp.ones((capacity,), bool) if v is None else v)
-        moved = grouped_take(lanes, perm) if lanes else []
-        s_in = {}
-        for j, i in enumerate(need):
-            s_in[i] = (moved[2 * j], moved[2 * j + 1] & s_live)
-
-        spec_vls = []
-        for spec in agg_specs:
-            if spec.input_idx >= 0:
-                spec_vls.append(s_in[spec.input_idx])
-            else:
-                spec_vls.append((None, s_live))
-
         # ---- every aggregate kind through the shared sorted-run layer
         # (scatter-free segmented scans + boundary gathers by default;
-        # the knob flips back to segment scatters for A/B comparison)
-        outs = sorted_agg_outputs(agg_specs, spec_vls, s_live, boundary,
-                                  starts_c, ends_c, group_live,
-                                  num_segments, capacity, scatter_free)
-        return out_keys, outs, num_groups
+        # the knob flips back to segment scatters for A/B comparison).
+        # Live rows sort to the front (dead ones carry the key `total`),
+        # so the runs abut; the key lane rides the sums' gather at the
+        # run ends (a run's last key is its first)
+        outs, (pk,) = sorted_agg_outputs(
+            agg_specs, spec_vls, s_live, boundary, starts_c, ends_c,
+            group_live, num_segments, capacity, scatter_free,
+            abutting=True, riders=[skey_s.astype(jnp.int64)])
+        return decode_keys(pk, group_live), outs, num_groups
 
     return run
+
+
+def _rides_the_sort(lane) -> bool:
+    """An aggregate's input lane of at most 32 bits: it and its validity
+    fit an int64 beside each other (packed_groupby_trace's payload
+    sort)."""
+    return jnp.issubdtype(lane.dtype, jnp.integer) \
+        and lane.dtype.itemsize <= 4
+
+
+def in_place_supported(agg_specs) -> bool:
+    """Whether every aggregate has a result that a segmented scan leaves
+    at its run's last row: sums and counts, and the min / max of a lane
+    that is not floating point (those take a NaN-aware reduction)."""
+    return all(spec.kind in (SUM, COUNT, COUNT_ALL)
+               or (spec.kind in (MIN, MAX)
+                   and not t.is_floating(spec.dtype)
+                   and not isinstance(spec.dtype, (t.BooleanType,
+                                                   t.StringType)))
+               for spec in agg_specs)
+
+
+def _in_place_outputs(agg_specs, spec_vls, s_live, boundary, is_end):
+    """sorted_agg_outputs for the `in_place` form: per spec (data,
+    valid) at the rows' own length, meaningful at `is_end` rows (valid is
+    False elsewhere).  Sums and counts are ONE stacked segmented scan a
+    dtype class; int64 sums wrap as segment_sum's do."""
+    int_lanes, int_slots, f64_lanes, f64_slots = _queue_sum_lanes(
+        agg_specs, spec_vls, s_live)
+    int_run = blocked_seg_scan(jnp.stack(int_lanes, axis=1), boundary,
+                               jnp.add) if int_lanes else None
+    f64_run = blocked_seg_scan(jnp.stack(f64_lanes, axis=1), boundary,
+                               jnp.add) if f64_lanes else None
+
+    def sum_of(key, is_float):
+        return (f64_run[:, f64_slots[key]] if is_float
+                else int_run[:, int_slots[key]])
+
+    outs = []
+    for si, spec in enumerate(agg_specs):
+        d, vl = spec_vls[si]
+        if spec.kind in (COUNT, COUNT_ALL):
+            outs.append((sum_of(("cnt", si), False), is_end))
+            continue
+        out_valid = (sum_of(("vc", spec.input_idx), False) > 0) & is_end
+        if spec.kind == SUM:
+            data = sum_of(("sum", si), t.is_floating(spec.dtype))
+        else:
+            cd = compute_view(d, spec.dtype)
+            is_min = spec.kind == MIN
+            info = np.iinfo(np.dtype(cd.dtype))
+            ident = jnp.asarray(info.max if is_min else info.min, cd.dtype)
+            data = blocked_seg_scan(jnp.where(vl, cd, ident), boundary,
+                                    jnp.minimum if is_min else jnp.maximum)
+        outs.append((data, out_valid))
+    return outs
+
+
+#: rows one sorted group-by program can address: its permutation and its
+#: group starts are int32 lanes, and `capacity` itself is their sentinel
+_MAX_ROW_IDS = (1 << 31) - 1
 
 
 def all_keys_pack(pack_spec, num_keys: int) -> bool:
@@ -514,7 +635,7 @@ def all_keys_pack(pack_spec, num_keys: int) -> bool:
 
 def groupby_trace(key_lanes_info, agg_specs, num_segments, capacity,
                   pack_spec=None, scatter_free=True,
-                  max_sort_operands=2):
+                  max_sort_operands=2, in_place=False):
     """Build the traced groupby fn for jit.
 
     key_lanes_info: list of (dtype, has_validity, lane_dtype_str) — static.
@@ -528,17 +649,29 @@ def groupby_trace(key_lanes_info, agg_specs, num_segments, capacity,
     Returns fn(keys_data, keys_valid, agg_data, agg_valid, live) ->
       (perm_keys (data, valid) per key, agg outs (data, valid) per spec,
        num_groups scalar)
+    and, where `in_place` is asked for and the all-keys-packed trace
+    can give it (packed_groupby_trace, in_place_supported), a fourth
+    value: the mask of the rows that hold a group each.
 
     `live` is an arbitrary row mask, NOT a prefix count: a filter feeding an
     aggregation passes its keep-mask directly, so filtered rows die inside
     the (sorted) segment reduce and no gather/compaction ever runs — row
     gathers are the expensive op on TPU, masked VPU work is nearly free.
     """
+    if capacity > _MAX_ROW_IDS:
+        # a ValueError is no trace-fallback error: the collect ends with
+        # this reason, not on another engine at the same capacity
+        raise ValueError(
+            f"sorted group-by over {capacity:,} rows of capacity: a row "
+            f"id is an int32, so one program sorts at most "
+            f"{_MAX_ROW_IDS:,} rows; partial results must be merged at "
+            "the sum of their capacities, not re-merged batch by batch")
     packed_idx = {i for i, s in enumerate(pack_spec or []) if s is not None}
     if all_keys_pack(pack_spec, len(key_lanes_info)):
         return packed_groupby_trace(pack_spec, key_lanes_info,
                                     agg_specs, num_segments, capacity,
-                                    scatter_free=scatter_free)
+                                    scatter_free=scatter_free,
+                                    in_place=in_place)
 
     def key_sort_lanes(keys, keys_valid):
         """[(lanes...)] for sorting/boundaries: packed keys collapse into

@@ -444,23 +444,27 @@ def probe_aligned(build: BuildTable, probe_lanes: List[jax.Array],
     if fn is None:
         bcap = build.capacity
 
-        def run(perm, sorted_hash, valid_count, b_lanes, b_key_valid,
-                p_lanes, p_valid):
+        def run(perm, sorted_hash, valid_count, p_lanes, p_valid):
             h = composite_hash(p_lanes)
             lo = _merge_rank(sorted_hash, h, side="left")
             in_range = lo < valid_count
             pos = jnp.clip(lo, 0, bcap - 1)
-            build_idx = jnp.take(perm, pos).astype(jnp.int32)
-            ok = p_valid & in_range & \
-                (jnp.take(sorted_hash, pos) == h)
-            for bl, pl in zip(b_lanes, p_lanes):
-                ok = ok & (jnp.take(bl, build_idx) == pl)
-            ok = ok & jnp.take(b_key_valid, build_idx)
-            return build_idx, ok
+            # the candidate's hash and its build row as ONE gathered
+            # matrix: a TPU gather pays per gathered row, and this one is
+            # as long as the probe.  With a single lane the hash IS the
+            # key (composite_hash), so its equality is the key's; and the
+            # sorted order holds exactly the valid-key rows before
+            # `valid_count` (BuildTable._sort), so a candidate in range
+            # has a valid key: no second and third gather through
+            # `build_idx` to say the same again
+            cand = jnp.take(
+                jnp.stack([sorted_hash, perm.astype(jnp.uint64)], axis=1),
+                pos, axis=0)
+            ok = p_valid & in_range & (cand[:, 0] == h)
+            return cand[:, 1].astype(jnp.int32), ok
         fn = jax.jit(run)
         _PROBE_CACHE[sig] = fn
     return fn(build.perm, build.sorted_hash, build.valid_count,
-              tuple(build.lanes), build.key_valid,
               tuple(probe_lanes), probe_valid)
 
 

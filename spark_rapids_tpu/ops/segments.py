@@ -125,15 +125,35 @@ def seg_reduce_sorted(vals: jax.Array, boundary: jax.Array,
 
 
 def seg_sums_sorted(lanes: Sequence[jax.Array], starts_c: jax.Array,
-                    ends_c: jax.Array) -> jax.Array:
+                    ends_c: jax.Array, abutting: bool = False,
+                    riders: Sequence[jax.Array] = ()):
     """(num_segments, k) per-segment sums of int lanes over sorted runs:
     ONE stacked blocked cumsum + two boundary gathers.  int64
     wraparound cancels in the diff, so this is exact whenever the
-    segment sum fits int64 — segment_sum's own contract."""
+    segment sum fits int64 — segment_sum's own contract.
+
+    `abutting`: the caller states that every live run starts where the
+    run before it ended (slot g's start - 1 is slot g-1's end, slot 0
+    starts at row 0; slots past the live runs hold anything).  The
+    cumsum below a run is then the cumsum AT the end of the run before
+    it, a shift of what is gathered anyway: one gather, not two (a TPU
+    gather pays per gathered row).  `riders`: int64 lanes of the rows'
+    length to read at `ends_c` in the same gather; with riders the
+    result is (sums, [rider at ends_c, ...])."""
     cs = blocked_cumsum(jnp.stack(list(lanes), axis=1))
-    hi = cs[ends_c]
-    lo = jnp.where((starts_c > 0)[:, None],
-                   cs[jnp.maximum(starts_c - 1, 0)], 0)
+    k = cs.shape[1]
+    if riders:
+        cs = jnp.concatenate(
+            [cs] + [r.astype(cs.dtype)[:, None] for r in riders], axis=1)
+    at_ends = cs[ends_c]
+    hi = at_ends[:, :k]
+    if abutting:
+        lo = jnp.concatenate([jnp.zeros((1, k), hi.dtype), hi[:-1]])
+    else:
+        lo = jnp.where((starts_c > 0)[:, None],
+                       cs[jnp.maximum(starts_c - 1, 0)][:, :k], 0)
+    if riders:
+        return hi - lo, [at_ends[:, k + j] for j in range(len(riders))]
     return hi - lo
 
 

@@ -321,6 +321,10 @@ class Expression:
 # ---------------------------------------------------------------------------
 
 class ColumnRef(Expression):
+    #: set by the planner (`mark_device_decimals`) on a bound reference to
+    #: a wide decimal that an operator below computed on the device
+    device_computed = False
+
     def __init__(self, name: str):
         self.name = name
         self.children = ()
@@ -536,14 +540,41 @@ def _as_decimal(dt: t.DataType) -> t.DecimalType:
     return D.integral_as_decimal(dt)
 
 
+def plain_ref(e: Expression) -> Optional["ColumnRef"]:
+    """The column reference that `e` is, bare or under an alias, else
+    None."""
+    inner = e.children[0] if isinstance(e, Alias) else e
+    return inner if isinstance(inner, ColumnRef) else None
+
+
 def _consumes_wide_host(e: Expression) -> bool:
     """True when `e` reads a wide (p>18) decimal straight off a host column:
     those carry a (lo, hi) two-lane representation the single-lane kernels
     cannot consume.  Device-COMPUTED wide results are single-lane int64 and
-    are fine (ops/decimal.py module docs)."""
-    inner = e.children[0] if isinstance(e, Alias) else e
-    return isinstance(inner, ColumnRef) and \
-        isinstance(inner.dtype, t.DecimalType) and inner.dtype.is_wide
+    are fine (ops/decimal.py module docs).  Which of the two a column is
+    follows from where it comes from in the plan, not from its type: the
+    planner marks the references it has traced to an operator that
+    computes on the device (`mark_device_decimals`); a reference nobody
+    marked is taken for a host column."""
+    ref = plain_ref(e)
+    return ref is not None and isinstance(ref.dtype, t.DecimalType) \
+        and ref.dtype.is_wide and not ref.device_computed
+
+
+def mark_device_decimals(e: Expression, device_names) -> int:
+    """Mark every reference under the bound tree `e` to a column named in
+    `device_names` (wide decimals that an operator below computes on the
+    device: one int64 unscaled lane) as consumable, and return how many
+    expressions of the tree read such a column directly (or through an
+    alias)."""
+    ref = plain_ref(e)
+    if ref is not None:
+        if ref.name in device_names:
+            ref.device_computed = True
+        return 0
+    n = sum(mark_device_decimals(c, device_names) for c in e.children)
+    refs = (plain_ref(c) for c in e.children)
+    return n + any(r is not None and r.device_computed for r in refs)
 
 
 def _cast_dev(v, src: t.DataType, dst: t.DataType):

@@ -404,6 +404,12 @@ def _host_to_device(node: "H.HostNode") -> PlanNode:
     return H.HostToDeviceExec(node)
 
 
+def _wide_decimal_names(schema: t.StructType) -> frozenset:
+    return frozenset(f.name for f in schema.fields
+                     if isinstance(f.data_type, t.DecimalType)
+                     and f.data_type.is_wide)
+
+
 class BaseMeta:
     def __init__(self, conf: TpuConf):
         self.conf = conf
@@ -499,10 +505,16 @@ class PlanMeta(BaseMeta):
         self.children = [wrap_plan(c, conf, self) for c in node.children]
         self.expr_metas: List[ExprMeta] = []
         self.agg_metas: List[AggMeta] = []
+        #: (bound expression, index of the child whose output it reads)
+        self._expr_inputs: List[Tuple[E.Expression, int]] = []
+        #: expressions of this node that read a wide decimal which an
+        #: operator below computes on the device (set when tagged)
+        self.wide_decimal_device = 0
 
     # -- wrap helpers ------------------------------------------------------
     def _wrap_exprs(self, exprs: Sequence[E.Expression],
-                    schema: t.StructType) -> List[E.Expression]:
+                    schema: t.StructType, child: int = 0
+                    ) -> List[E.Expression]:
         bound = []
         for e in exprs:
             try:
@@ -511,8 +523,55 @@ class PlanMeta(BaseMeta):
                 self.will_not_work(f"cannot bind {e!r}: {exc}")
                 continue
             self.expr_metas.append(ExprMeta(b, self.conf))
+            self._expr_inputs.append((b, child))
             bound.append(b)
         return bound
+
+    # -- where a wide decimal comes from ------------------------------------
+    def wide_host_columns(self) -> frozenset:
+        """The output columns of this node that are decimals wider than
+        18 digits AND reach a device consumer as the two-lane (lo, hi)
+        host value: whatever a scan or an operator placed on the CPU
+        produces, and what a device operator hands through of such a
+        column.  Every other wide decimal was computed by a device
+        operator: one int64 unscaled lane (ops/decimal.py).  Valid once
+        this node is tagged."""
+        wide = _wide_decimal_names(self.node.schema)
+        if not wide or not self.can_replace:
+            return wide
+        return wide & self._wide_host_through(wide)
+
+    def _wide_host_through(self, wide: frozenset) -> frozenset:
+        """For a node placed on the device: which of its `wide` output
+        columns it hands through from a two-lane input.  A node that
+        does not say is taken to hand through all of them."""
+        return wide
+
+    def _child_wide_host(self) -> frozenset:
+        return frozenset().union(
+            *(c.wide_host_columns() for c in self.children))
+
+    @staticmethod
+    def _wide_host_refs(exprs, names, below: frozenset) -> frozenset:
+        """Of the outputs `names`, those whose expression is a plain
+        (possibly aliased) reference to a column in `below`."""
+        refs = ((E.plain_ref(e), name) for e, name in zip(exprs, names))
+        return frozenset(name for ref, name in refs
+                         if ref is not None and ref.name in below)
+
+    def _mark_device_decimals(self) -> None:
+        """Tell this node's bound expressions which of the wide decimals
+        they read were computed on the device (children are tagged by
+        now, so their placement is known)."""
+        device_names: Dict[int, frozenset] = {}
+        for b, child in self._expr_inputs:
+            names = device_names.get(child)
+            if names is None:
+                meta = self.children[child]
+                names = device_names[child] = _wide_decimal_names(
+                    meta.node.schema) - meta.wide_host_columns()
+            if names:
+                self.wide_decimal_device += E.mark_device_decimals(b, names)
 
     # -- tagging -----------------------------------------------------------
     def tag(self):
@@ -536,6 +595,7 @@ class PlanMeta(BaseMeta):
                     self.will_not_work(
                         f"output column {f.name}: type "
                         f"{f.data_type.simple_string} not supported")
+        self._mark_device_decimals()
         for em in self.expr_metas:
             em.tag()
             for r in em.reasons:
@@ -553,7 +613,12 @@ class PlanMeta(BaseMeta):
     def convert(self) -> Tuple[str, object]:
         """Returns ("device", PlanNode) or ("host", HostNode)."""
         if self.can_replace and not self.conf.explain_only:
-            return "device", self.to_device()
+            node = self.to_device()
+            if self.wide_decimal_device:
+                # kept with the node; a whole-plan program counts what
+                # its nodes hold (`expr.wide_decimal_device`)
+                node.wide_decimal_exprs = self.wide_decimal_device
+            return "device", node
         return "host", self.to_host()
 
     def to_device(self) -> PlanNode:
@@ -609,6 +674,10 @@ class ProjectMeta(PlanMeta):
         super().__init__(node, conf, parent)
         self.bound = self._wrap_exprs(node.exprs, node.child.schema)
 
+    def _wide_host_through(self, wide):
+        return self._wide_host_refs(self.node.exprs, self.node.names,
+                                    self._child_wide_host())
+
     def to_device(self):
         return ProjectExec(self.node.exprs, self.node.names,
                            self._device_child())
@@ -622,6 +691,9 @@ class FilterMeta(PlanMeta):
     def __init__(self, node, conf, parent):
         super().__init__(node, conf, parent)
         self._wrap_exprs([node.condition], node.child.schema)
+
+    def _wide_host_through(self, wide):
+        return self._child_wide_host()
 
     def to_device(self):
         return FilterExec(self.node.condition, self._device_child())
@@ -649,6 +721,21 @@ class AggregateMeta(PlanMeta):
             self.agg_metas.append(AggMeta(b, self.conf))
             if b.child is not None:
                 self.expr_metas.append(ExprMeta(b.child, self.conf))
+                self._expr_inputs.append((b.child, 0))
+
+    def _wide_host_through(self, wide):
+        # a key is handed through; what an aggregate function returns
+        # was computed here
+        return self._wide_host_refs(self.node.keys, self.node.key_names,
+                                    self._child_wide_host())
+
+    def _mark_device_decimals(self):
+        super()._mark_device_decimals()
+        # an aggregate over a plain reference consumes it itself
+        for am in self.agg_metas:
+            ref = am.fn.child is not None and E.plain_ref(am.fn.child)
+            if ref and ref.device_computed:
+                self.wide_decimal_device += 1
 
     def tag_self(self):
         # group keys must be single flat device lanes: ragged/nested
@@ -726,6 +813,9 @@ class SortMeta(PlanMeta):
         super().__init__(node, conf, parent)
         self._wrap_exprs([e for e, _, _ in node.orders], node.child.schema)
 
+    def _wide_host_through(self, wide):
+        return self._child_wide_host()
+
     def tag_self(self):
         schema = self.node.child.schema
         for e, _asc, _nf in self.node.orders:
@@ -751,6 +841,9 @@ class SortMeta(PlanMeta):
 
 
 class LimitMeta(PlanMeta):
+    def _wide_host_through(self, wide):
+        return self._child_wide_host()
+
     def to_device(self):
         # Limit directly above a global Sort collapses into TopN
         # (reference GpuTopN, limit.scala): per-batch sort+cut keeps the
@@ -778,8 +871,11 @@ class JoinMeta(PlanMeta):
 
     def __init__(self, node, conf, parent):
         super().__init__(node, conf, parent)
-        self._wrap_exprs(node.left_keys, node.left.schema)
-        self._wrap_exprs(node.right_keys, node.right.schema)
+        self._wrap_exprs(node.left_keys, node.left.schema, child=0)
+        self._wrap_exprs(node.right_keys, node.right.schema, child=1)
+
+    def _wide_host_through(self, wide):
+        return self._child_wide_host()
 
     def tag_self(self):
         if self.node.join_type not in self._DEVICE_TYPES:
@@ -2120,6 +2216,31 @@ def generate_supported_ops() -> str:
     for cls, rule in sorted(_AGG_RULES.items(), key=lambda kv: kv[0].__name__):
         lines.append(f"| {cls.__name__} | "
                      f"{', '.join(sorted(rule.input_sig.tags))} |")
+    lines += ["", "## DECIMAL128: what runs on the device", "",
+              "DECIMAL128 in the tables above is a decimal wider than 18 "
+              "digits. Whether an operator over one runs on the device "
+              "follows from where the column comes from in the plan "
+              "(`PlanMeta.wide_host_columns`), not from its type:", "",
+              "* **computed on the device** (an aggregate's sum, an "
+              "arithmetic result, by an operator that is itself placed "
+              "on the device): one int64 unscaled lane. Comparisons, "
+              "arithmetic, casts and aggregates over it run on the "
+              "device, exactly; a value past int64's unscaled range is "
+              "null where Spark's 128-bit arithmetic would hold it "
+              "(ops/decimal.py), and a comparison with null is null. "
+              "`having sum(l_quantity) > 300` over decimal(12,2) is "
+              "this case. A collect counts such expressions in "
+              "`expr.wide_decimal_device`.",
+              "* **arrives from the host** (a scan, or the output of an "
+              "operator that was placed on the CPU, handed through "
+              "filters, projections of plain references, sorts, limits "
+              "and joins): the two-lane (lo, hi) value. It is scanned, "
+              "handed through, sorted on and fetched on the device; "
+              "anything that would compute over it falls back to the "
+              "CPU with `128-bit host decimal lane not consumable on "
+              "device`. Group-by keys, window order keys, "
+              "count(DISTINCT) and collect_list/collect_set of any "
+              "DECIMAL128 stay on the CPU."]
     lines += ["", "## TPC-DS tranche status", "",
               "First tranche of the TPC-DS corpus "
               "(spark_rapids_tpu/tpcds.py QUERIES); every registered "

@@ -308,16 +308,27 @@ def test_seam_resolves_lazily_at_shrunken_bucket(case, monkeypatch):
     assert not m.get("whole_plan_fallbacks")
     assert m["overhead.seam_count"] == 2 and m["exec_dispatches"] == 3
     # seam 0 (the join chain's output) is lazy; seam 1 (the aggregate's
-    # dense output) is sliced
-    assert m["overhead.seam_lazy_count"] == 1
+    # dense output) is sliced, except where the aggregate sorts on one
+    # packed key lane (`semi`: grouped by the integer p1): it then leaves
+    # each group at its run's last row, under a mask that the seam
+    # resolves like any other (PR 35)
+    in_place = case == "semi"
+    assert m["overhead.seam_lazy_count"] == 1 + in_place
     assert m["overhead.seam_capacity_rows"] > m["overhead.seam_rows"]
-    (db, cap, scope, out), = resolved
+    (db, cap, scope, out) = resolved[0]
+    assert len(resolved) == 1 + in_place
+    if in_place:
+        assert resolved[1][2] == q.root.child._node_id
+        assert resolved[1][0].sel is not None
+        resolved_rows = resolved[1][0].capacity
+    else:
+        resolved_rows = 0
     assert scope == q.root.child.child._node_id
     assert db.sel is not None
     assert (db.thin is None) == (extra == OFF)
     assert out.sel is None and out.thin is None
     assert all(c.capacity == cap for c in out.columns)
-    assert db.capacity == m["overhead.seam_capacity_rows"]
+    assert db.capacity + resolved_rows == m["overhead.seam_capacity_rows"]
     if case == "nothing_collapses":
         assert cap == db.capacity
     else:

@@ -330,9 +330,9 @@ def test_manifest_of_the_mesh_cell(bench):
                 "types"):
         assert found.config[key] == q1_config[key], key
     assert set(found.config["guarantees"]) == set(q1_config["guarantees"])
-    # one of five cells asks for four chips
-    assert [w["chips"] for w in bench.manifest.benchmark()["workloads"]] \
-        == [1, 1, 1, 1, 4]
+    # one of the cells asks for four chips
+    assert [w["chips"] for w in bench.manifest.benchmark()["workloads"]
+            if w["chips"] != 1] == [4]
 
 
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
