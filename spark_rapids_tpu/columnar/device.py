@@ -36,6 +36,7 @@ re-designed for XLA's compilation model instead of translated:
 from __future__ import annotations
 
 import dataclasses
+from contextlib import nullcontext
 from typing import List, Optional, Sequence
 
 import jax
@@ -45,6 +46,7 @@ import pyarrow as pa
 
 from .. import types as t
 from ..config import TpuConf, DEFAULT_CONF
+from ..obs.tracer import CollectSpan
 from .host import HostBatch, dtype_to_arrow
 
 
@@ -510,7 +512,8 @@ def _device_column_to_arrow(col: DeviceColumn, num_rows: int,
     return pa.array(data, arrow_type, mask=~valid)
 
 
-def to_host(db: DeviceBatch, fetch_rows: Optional[int] = None) -> HostBatch:
+def to_host(db: DeviceBatch, fetch_rows: Optional[int] = None,
+            ctx=None) -> HostBatch:
     """Bring a batch to host.
 
     ONE D2H round trip for the row count and every lane of every column
@@ -522,14 +525,33 @@ def to_host(db: DeviceBatch, fetch_rows: Optional[int] = None) -> HostBatch:
     carrying 1,760 live rows is 25 MB padded and 42 KB live).  Ragged
     value lanes are sliced via the (host-known) offsets
     bound only when the whole column is fetched, because the value count
-    of a row prefix is itself device data."""
-    n, fetched = _fetch_lanes(db, fetch_rows)
+    of a row prefix is itself device data.
+
+    ctx: the collect's ExecContext, whose `tpu.fetch_wait` and
+    `tpu.arrow_build` spans then time the round trip and the build."""
+    n, fetched = _fetch_lanes(db, fetch_rows, ctx)
     if fetch_rows is not None:
         n = min(n, fetch_rows)
-    return _build_host_batch(db, n, fetched)
+    return _build_host_batch(db, n, fetched, ctx)
 
 
-def _fetch_lanes(db: DeviceBatch, fetch_rows: Optional[int]):
+def _fetch_span(ctx, name: str, device_wait: bool = False):
+    """A split `tpu.<name>` span of the enclosing `tpu.fetch`
+    (`overhead.<name>_ms`); nothing without a collect's context."""
+    if ctx is None:
+        return nullcontext()
+    return CollectSpan(ctx, name, f"overhead.{name}_ms", cat="transition",
+                       split=True, device_wait=device_wait)
+
+
+def _device_get(tree, ctx):
+    """A blocking device-to-host read: it waits for the program that
+    writes `tree`, then copies (`tpu.fetch_wait`)."""
+    with _fetch_span(ctx, "fetch_wait", device_wait=True):
+        return jax.device_get(tree)
+
+
+def _fetch_lanes(db: DeviceBatch, fetch_rows: Optional[int], ctx=None):
     """device_get count + lanes in one round trip; lanes prefix-sliced to
     fetch_rows when given.  Returns (clamped live count, fetched lists)."""
     if db.sel is not None or db.thin is not None:
@@ -550,19 +572,22 @@ def _fetch_lanes(db: DeviceBatch, fetch_rows: Optional[int]):
                     c, data=c.data[:h], validity=c.validity[:h],
                     data_hi=None if c.data_hi is None else c.data_hi[:h]))
         cols = sl
-    n_f, fetched = jax.device_get(
+    n_f, fetched = _device_get(
         (db.num_rows, [(c.data, c.validity, c.data_hi, c.offsets,
-                        c.elem_valid) for c in cols]))
+                        c.elem_valid) for c in cols]), ctx)
     return int(n_f), fetched        # TRUE count (may exceed fetch_rows)
 
 
-def _build_host_batch(db: DeviceBatch, n: int, fetched) -> HostBatch:
-    arrays = [_device_column_to_arrow(c, n, f)
-              for c, f in zip(db.columns, fetched)]
-    schema = pa.schema([pa.field(n, a.type) for n, a in zip(db.names, arrays)])
-    if not arrays:
-        return HostBatch(pa.RecordBatch.from_pydict({}))
-    return HostBatch(pa.RecordBatch.from_arrays(arrays, schema=schema))
+def _build_host_batch(db: DeviceBatch, n: int, fetched,
+                      ctx=None) -> HostBatch:
+    with _fetch_span(ctx, "arrow_build"):
+        arrays = [_device_column_to_arrow(c, n, f)
+                  for c, f in zip(db.columns, fetched)]
+        schema = pa.schema([pa.field(n, a.type)
+                            for n, a in zip(db.names, arrays)])
+        if not arrays:
+            return HostBatch(pa.RecordBatch.from_pydict({}))
+        return HostBatch(pa.RecordBatch.from_arrays(arrays, schema=schema))
 
 
 # Result-fetch head default: one speculative round trip ships the count
@@ -572,8 +597,7 @@ def _build_host_batch(db: DeviceBatch, n: int, fetched) -> HostBatch:
 
 
 def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
-                       conf: Optional[TpuConf] = None,
-                       metrics: Optional[dict] = None) -> HostBatch:
+                       ctx=None) -> HostBatch:
     """Bring a RESULT batch to host in as few syncs and bytes as it needs.
 
     The live rows of every operator output are a front prefix of the
@@ -586,32 +610,34 @@ def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
         count + a RESULT_HEAD_ROWS prefix together; a second trip only
         when the result is genuinely bigger than the head.
 
-    Each trip is a blocking device-to-host read: `metrics["host_syncs"]`
-    counts them."""
+    Each trip is a blocking device-to-host read: the `ctx`'s
+    `host_syncs` counts them, its `tpu.fetch_wait` times them and its
+    `tpu.arrow_build` the Arrow build (`ctx` None: the session's default
+    conf, nothing counted)."""
     from ..config import (DEFAULT_CONF, RESULT_BOUND_FETCH_FACTOR,
                           RESULT_HEAD_ROWS)
-    conf = conf or DEFAULT_CONF
+    conf = DEFAULT_CONF if ctx is None else ctx.conf
     head_rows = conf.get(RESULT_HEAD_ROWS)
     bound_factor = conf.get(RESULT_BOUND_FETCH_FACTOR)
     cap = db.capacity
 
     def trip():
-        if metrics is not None:
-            metrics["host_syncs"] = metrics.get("host_syncs", 0) + 1
+        if ctx is not None:
+            ctx.bump("host_syncs")
 
     trip()
     if isinstance(db.num_rows, int):
-        return to_host(db, fetch_rows=min(db.num_rows, cap))
+        return to_host(db, min(db.num_rows, cap), ctx)
     if any(c.offsets is not None for c in db.columns):
         # ragged value lanes aren't prefix-sliceable by a row bound (the
         # value count of a prefix is device data).  A small static bound
         # fetches exactly-sized in one trip; otherwise the cheap scalar
         # count goes first so an all-padding bucket never ships lanes
         if bound is not None and bound < cap:
-            return to_host(db, fetch_rows=bound)
-        n = int(jax.device_get(db.num_rows))
+            return to_host(db, bound, ctx)
+        n = int(_device_get(db.num_rows, ctx))
         trip()
-        return to_host(db, fetch_rows=max(n, 0) if n < cap else None)
+        return to_host(db, max(n, 0) if n < cap else None, ctx)
     # a small static bound buys an exact one-trip fetch; a loose bound
     # (dense-domain group counts can reach 4M) must not defeat the head
     # protocol, so past boundFactor x the head size we speculate instead
@@ -620,13 +646,13 @@ def fetch_result_batch(db: DeviceBatch, bound: Optional[int] = None,
     else:
         head = min(cap, head_rows)
     if head >= cap:
-        return to_host(db)
-    n, fetched = _fetch_lanes(db, head)
+        return to_host(db, None, ctx)
+    n, fetched = _fetch_lanes(db, head, ctx)
     if n <= head:
-        return _build_host_batch(db, n, fetched)
+        return _build_host_batch(db, n, fetched, ctx)
     # result larger than the head: pay the second, exactly-sized trip
     trip()
-    return to_host(db, fetch_rows=n)
+    return to_host(db, n, ctx)
 
 
 def empty_device_batch(schema: t.StructType, conf: TpuConf = DEFAULT_CONF) -> DeviceBatch:

@@ -1002,10 +1002,11 @@ class CompiledPlan:
             return self._launch(ctx)
 
     def _launch(self, ctx: ExecContext) -> List[DeviceBatch]:
-        import time as _time
         from ..config import PROFILE_SEGMENTS
-        pairs = self._leaf_batches(ctx)
-        flat_in, in_specs = self._flatten_inputs(pairs)
+        with CollectSpan(ctx, "launch_inputs", "overhead.launch_inputs_ms",
+                         split=True):
+            pairs = self._leaf_batches(ctx)
+            flat_in, in_specs = self._flatten_inputs(pairs)
 
         if self._compiled is None:
             with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
@@ -1042,9 +1043,12 @@ class CompiledPlan:
                     mrec.skipped += 1
                     ctx.bump("memattr_census_skipped")
                     mrec = None
-        t0 = _time.perf_counter()
-        with ctx.tracer.span("execute", "execute",
-                             root=self.root.name()):
+        # the program call (`overhead.enqueue_ms`): the dispatch wall, or
+        # the device wall where profiling syncs; the performance-history
+        # plane's per-structure measured-cost feed (obs/history.py)
+        with CollectSpan(ctx, "enqueue", "overhead.enqueue_ms",
+                         cat="execute", split=True,
+                         device_wait=prof) as call:
             try:
                 flat_res = self._compiled(flat_in)
             except TypeError:
@@ -1058,13 +1062,7 @@ class CompiledPlan:
                 # the sync that turns dispatch wall into DEVICE wall;
                 # profiling-only — the default path stays async
                 jax.block_until_ready(flat_res)
-        t1 = _time.perf_counter()
-        # always-on program-execution wall (device wall when profiling
-        # syncs, the dispatch floor otherwise): the performance-history
-        # plane's per-structure measured-cost feed (obs/history.py)
         m = ctx.metrics
-        m["exec_device_ms"] = m.get("exec_device_ms", 0.0) \
-            + (t1 - t0) * 1e3
         # always-on dispatch count: the overhead plane (and the history
         # feed) multiplies it by the measured per-backend dispatch floor
         # when no profiled decomposition exists for this run
@@ -1091,11 +1089,13 @@ class CompiledPlan:
 
         outs = []
         i = 0
-        for spec in self._out_specs:
-            db, i = _rebuild_batch(flat_res, spec, i)
-            outs.append(db)
+        with CollectSpan(ctx, "launch_outputs", "overhead.launch_outputs_ms",
+                         split=True):
+            for spec in self._out_specs:
+                db, i = _rebuild_batch(flat_res, spec, i)
+                outs.append(db)
         if prof:
-            self._record_segment(ctx, t0, t1, outs, mrec, pairs)
+            self._record_segment(ctx, call._t0, call._t1, outs, mrec, pairs)
         return outs
 
     def _record_segment(self, ctx: ExecContext, t0: float, t1: float,
@@ -1801,22 +1801,25 @@ class SplitCompiledPlan:
             if not isinstance(n, int):
                 # ONE host sync per batch: it waits for the segment
                 with CollectSpan(ctx, "seam_wait", "overhead.seam_wait_ms",
-                                 cat="transition", split=True):
+                                 cat="transition", split=True,
+                                 device_wait=True):
                     n = int(n)
                 ctx.bump("host_syncs")
             rows += n
             cap = min(bucket_capacity(max(n, 1), ctx.conf), db.capacity)
-            if db.sel is None and db.thin is None:
+            lazy = db.sel is not None or db.thin is not None
+            if lazy:
+                ctx.bump("overhead.seam_lazy_count")
+                ctx.bump("overhead.seam_capacity_rows", db.capacity)
+            with CollectSpan(ctx, "seam_resolve", "overhead.seam_resolve_ms",
+                             cat="transition", split=True):
                 # num_rows stays a device scalar so segment traces are
                 # keyed on the CAPACITY BUCKET only — a drifting row
                 # count (growing table, streaming appends) reuses
                 # compiled programs instead of recompiling per exact
                 # count
-                shrunk.append(_slice_batch(db, cap, jnp.int32(n)))
-                continue
-            ctx.bump("overhead.seam_lazy_count")
-            ctx.bump("overhead.seam_capacity_rows", db.capacity)
-            shrunk.append(_resolve_at(db, cap, scope, ctx))
+                shrunk.append(_resolve_at(db, cap, scope, ctx) if lazy
+                              else _slice_batch(db, cap, jnp.int32(n)))
         return shrunk, rows
 
     def collect(self, ctx: ExecContext) -> pa.Table:

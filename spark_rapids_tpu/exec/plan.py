@@ -168,15 +168,15 @@ class ExecContext:
 def fetch_to_host(db: DeviceBatch, bound: Optional[int],
                   ctx: ExecContext) -> HostBatch:
     """The result fetch of one batch, the tail host sync every query
-    pays (`tpu.fetch`, overhead.fetch_ms): the wait for the device, the
-    copy and the Arrow build.  The wait is not split off: an explicit
-    `block_until_ready` before the transfer costs a second round trip
-    (1.4 ms of a 8.9 ms q6 collect on a v5e: PERF.md, PR 25)."""
+    pays (`tpu.fetch`, overhead.fetch_ms): the wait for the device and
+    the copy (`tpu.fetch_wait`, one `device_get`: no `block_until_ready`
+    splits them, which costs a second round trip, 1.4 ms of a 8.9 ms q6
+    collect on a v5e: PERF.md, PR 25), and the Arrow build
+    (`tpu.arrow_build`)."""
     from ..runtime.retry import retry_io
     with CollectSpan(ctx, "fetch", "overhead.fetch_ms", cat="transition"):
         hb = retry_io(ctx.conf, "d2h",
-                      lambda: fetch_result_batch(db, bound, ctx.conf,
-                                                 ctx.metrics))
+                      lambda: fetch_result_batch(db, bound, ctx))
     ctx.bump("d2h_rows", hb.num_rows)
     ctx.tracer.add_bytes("d2h_bytes", hb.rb.nbytes)
     return hb

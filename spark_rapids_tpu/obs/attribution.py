@@ -131,7 +131,8 @@ class ExplainAnalyzeReport:
     segments: List[Dict[str, Any]]
     attributed_pct: Optional[float]  # 0..100, None when not measurable
     wall_ms: float
-    device_ms: float                # union of measured execute spans
+    device_ms: float                # union of the program calls
+                                    # (`tpu.enqueue`, cat execute)
     gathers: Dict[str, int]         # gather volume delta over the run
     mesh_timeline: Dict[str, Any]
     metrics: Dict[str, Any]

@@ -500,10 +500,11 @@ class PerfHistoryStore:
 
         compile_ms = num("compile_ms")
         # the measured device-side wall this structure cost: the query
-        # wall net of compile, floored by the accumulated program
-        # dispatch wall (exec/compiled.py exec_device_ms — exact when
-        # profiling syncs, the dispatch floor otherwise)
-        device_ms = max(wall_ms - compile_ms, num("exec_device_ms"), 1e-3)
+        # wall net of compile, floored by the accumulated program call
+        # (`tpu.enqueue`, overhead.enqueue_ms — the device wall when
+        # profiling syncs, the dispatch wall otherwise)
+        device_ms = max(wall_ms - compile_ms, num("overhead.enqueue_ms"),
+                        1e-3)
         segments: Dict[str, dict] = {}
         import re
         seg_re = re.compile(r"^segment\.(?P<node>[\w#]+)\."

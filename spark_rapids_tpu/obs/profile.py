@@ -298,14 +298,15 @@ class QueryProfile:
                     ivals.append((t0, t1))
             return ivals
 
-        seam_names = ("tpu.seam", "tpu.seam_wait")
+        seam_names = ("tpu.seam", "tpu.seam_wait", "tpu.seam_resolve")
         seg_dev = sum(float(r.get("device_ms", 0.0))
                       for r in self.segments())
         dispatch_ms = float(ov.get("dispatch_ms", 0.0))
         if seg_dev <= 0.0:
-            # unprofiled run: exec_device_ms is the dispatch wall; the
-            # measured floor x launch count bounds its overhead share
-            seg_dev = float(m.get("exec_device_ms", 0.0))
+            # unprofiled run: the program call (`tpu.enqueue`) is the
+            # dispatch wall; the measured floor x launch count bounds
+            # its overhead share
+            seg_dev = float(ov.get("enqueue_ms", 0.0))
             floor = float(ov.get("dispatch_floor_ms", 0.0))
             if not dispatch_ms and floor:
                 dispatch_ms = floor * float(m.get("exec_dispatches", 0))

@@ -466,10 +466,15 @@ def get_active():
     return _FALLBACK
 
 
+def tracing(conf: TpuConf) -> bool:
+    """Tracing is on for this conf: trace.enabled or an event-log dir."""
+    return bool(conf.get(TRACE_ENABLED) or conf.get(EVENT_LOG_DIR))
+
+
 def make_tracer(conf: TpuConf):
-    """A real tracer when tracing is on for this conf (trace.enabled or
-    an event-log dir), else the shared NULL_TRACER."""
-    if not (conf.get(TRACE_ENABLED) or conf.get(EVENT_LOG_DIR)):
+    """A real tracer when tracing is on for this conf, else the shared
+    NULL_TRACER."""
+    if not tracing(conf):
         return NULL_TRACER
     global _NEXT_QUERY_ID
     with _QUERY_ID_LOCK:
@@ -484,24 +489,36 @@ class CollectSpan:
         with CollectSpan(ctx, "prepare", "overhead.prepare_ms"):
             ...
 
-    Entering opens `TraceAnnotation("tpu.<name>", query=<query seq>)`,
-    which lands on the `/host:CPU` plane of a live profiler trace, on
-    the device's clock.  Leaving adds the span's own time to
+    Entering opens `TraceAnnotation("tpu.<name>", query=<query seq>)`
+    where a profiler is live: it lands on the `/host:CPU` plane of the
+    trace, on the device's clock.  Leaving adds the span's own time to
     `ctx.metrics[key]`: its duration less that of the spans opened
     inside it, so the keys of one collect add up to the outermost
     span's duration and the counters cannot disagree with the spans.
     A `split` span is a part of its parent, not a category beside it
-    (the wait inside a fetch): its whole duration goes to its key and
-    the parent's time keeps it.  Where the context's tracer is enabled
+    (the wait inside a fetch): its own time goes to its key and the
+    parent's own time keeps it, so the parts of a span add up to no more
+    than its key.  Where the context's tracer is enabled
     the span is recorded there too, parented to the enclosing
-    CollectSpan.  Spans are serial on the collecting thread."""
+    CollectSpan, with its thread CPU time as `cpu_ms`.  Spans are serial
+    on the collecting thread.
+
+    Off-CPU time, `overhead.offcpu_ms`: the collecting thread off its
+    core while doing host work (a GIL wait, a lock, preemption).  The
+    spans' own times partition the outermost span's, so it is the
+    outermost span's wall less its thread CPU time (`time.thread_time()`),
+    less the same of each `device_wait` span inside it, which blocks on
+    the device by design.  Only those spans read the CPU clock (a system
+    call: about 6 us on the TPU v5e hosts of PERF.md), and every span
+    where a tracer may record it."""
 
     __slots__ = ("ctx", "name", "key", "cat", "split", "whole_key",
-                 "after", "_ann", "_outer", "_span", "_t0", "_t1",
-                 "_inner_ms")
+                 "device_wait", "after", "_ann", "_outer", "_span", "_t0",
+                 "_t1", "_c0", "_cpu_ms", "_inner_ms")
 
     def __init__(self, ctx, name: str, key: str, cat: str = "collect",
-                 split: bool = False, whole_key: Optional[str] = None):
+                 split: bool = False, whole_key: Optional[str] = None,
+                 device_wait: bool = False):
         self.ctx = ctx
         self.name = "tpu." + name
         self.key = key
@@ -510,6 +527,7 @@ class CollectSpan:
         #: a second key, given the whole duration (`tpu.collect`, whose
         #: own time is the residual)
         self.whole_key = whole_key
+        self.device_wait = device_wait
         #: run once the span has closed and its time is in the metrics
         self.after = None
         self._span = None
@@ -518,34 +536,55 @@ class CollectSpan:
         ctx = self.ctx
         if not ctx.query_seq:
             ctx.query_seq = next_query_seq()
-        self._ann = TraceAnnotation(self.name, query=ctx.query_seq)
-        self._ann.__enter__()
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name, query=ctx.query_seq)
+            self._ann.__enter__()
+        else:
+            self._ann = None
         open_spans = ctx.open_spans
-        self._outer = open_spans[-1] if open_spans else None
+        outer = self._outer = open_spans[-1] if open_spans else None
         open_spans.append(self)
         self._inner_ms = 0.0
+        tracer = ctx.tracer
+        # the CPU interval lies inside the wall one: off-CPU time >= 0
         self._t0 = time.perf_counter()
-        if ctx.tracer.enabled:
-            self._record_begin(ctx.tracer)
+        self._c0 = time.thread_time() if (
+            outer is None or self.device_wait or tracer.enabled
+            or (ctx.early_spans is not None and tracing(ctx.conf))) else None
+        if tracer.enabled:
+            self._record_begin(tracer)
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
         ctx = self.ctx
+        cpu = self._cpu_ms = None if self._c0 is None else \
+            (time.thread_time() - self._c0) * 1e3
         self._t1 = time.perf_counter()
         ms = (self._t1 - self._t0) * 1e3
         ctx.open_spans.pop()
         m = ctx.metrics
-        m[self.key] = m.get(self.key, 0.0) + \
-            (ms if self.split else ms - self._inner_ms)
+        m[self.key] = m.get(self.key, 0.0) + ms - self._inner_ms
         if self.whole_key is not None:
             m[self.whole_key] = m.get(self.whole_key, 0.0) + ms
-        if self._outer is not None and not self.split:
-            self._outer._inner_ms += ms
+        outer = self._outer
+        # the outermost span adds its off-CPU time, a device wait inside
+        # it takes its own back out
+        if (outer is None) != self.device_wait:
+            off = ms - cpu
+            m["overhead.offcpu_ms"] = m.get("overhead.offcpu_ms", 0.0) + \
+                (off if outer is None else -off)
+        if outer is not None:
+            # a split span is transparent: the spans inside it are its
+            # parent's children for the parent's own time
+            outer._inner_ms += self._inner_ms if self.split else ms
         if self._span is not None:
+            if cpu is not None:
+                self._span.attrs["cpu_ms"] = round(cpu, 3)
             ctx.tracer.end(self._span, self._t1)
         elif ctx.early_spans is not None:
             ctx.early_spans.append(self)
-        self._ann.__exit__(et, ev, tb)
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
         if self.after is not None:
             self.after()
         return False
@@ -574,6 +613,9 @@ def bind_tracer(ctx, tracer) -> Dict[str, int]:
     for sp in ctx.open_spans:
         sp._record_begin(tracer)
     for sp in early or ():
-        closed[sp.name] = tracer.add_span(
-            sp.name, sp.cat, sp._t0, sp._t1, parent=sp._outer_sid()).sid
+        span = tracer.add_span(sp.name, sp.cat, sp._t0, sp._t1,
+                               parent=sp._outer_sid())
+        if sp._cpu_ms is not None:
+            span.attrs["cpu_ms"] = round(sp._cpu_ms, 3)
+        closed[sp.name] = span.sid
     return closed

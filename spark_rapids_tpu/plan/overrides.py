@@ -1451,17 +1451,20 @@ class PhysicalQuery:
                         device_permit(self.conf, ctx.metrics):
                     with tracer.span("query", "query"):
                         yield
-                with CollectSpan(ctx, "finish", "overhead.finish_ms"):
-                    # metrics accumulated as device scalars (lazy
-                    # counts) coerce in ONE batched fetch at query end
-                    import jax
-                    lazy = {k: v for k, v in ctx.metrics.items()
-                            if isinstance(v, jax.Array)}
-                    if lazy:
+                # metrics accumulated as device scalars (lazy counts)
+                # coerce in ONE batched fetch at query end: a wait for
+                # the device, off the off-CPU count
+                import jax
+                lazy = {k: v for k, v in ctx.metrics.items()
+                        if isinstance(v, jax.Array)}
+                if lazy:
+                    with CollectSpan(ctx, "finish", "overhead.finish_ms",
+                                     device_wait=True):
                         ctx.bump("host_syncs")
                         for k, v in zip(lazy, jax.device_get(
                                 list(lazy.values()))):
                             ctx.metrics[k] = v.item()
+                with CollectSpan(ctx, "finish", "overhead.finish_ms"):
                     if ctx._budget is not None:
                         for k, v in ctx.budget.metrics.items():
                             ctx.metrics[f"memory.{k}"] = v
@@ -1515,7 +1518,22 @@ class PhysicalQuery:
         return jax.default_backend() == "tpu"
 
     def collect(self, ctx: Optional[ExecContext] = None) -> pa.Table:
+        """Run the plan and fetch its answer.  Called directly (a serving
+        ticket, `bench.py`) rather than from DataFrame.collect(), the
+        collect opens its own `tpu.collect`, so that its keys add up to
+        `overhead.collect_ms` there too."""
         ctx = ctx or ExecContext(self.conf)
+        if ctx.open_spans:
+            return self._collect(ctx)
+        from ..obs.tracer import CollectSpan
+        try:
+            with CollectSpan(ctx, "collect", "overhead.unattributed_ms",
+                             whole_key="overhead.collect_ms"):
+                return self._collect(ctx)
+        finally:
+            ctx.query_seq = 0            # a context used again: a new query
+
+    def _collect(self, ctx: ExecContext) -> pa.Table:
         from ..exec import ooc as O
         from ..exec.metrics import record_history
         from ..obs.tracer import CollectSpan

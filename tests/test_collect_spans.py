@@ -71,8 +71,16 @@ ADDITIVE = ("plan_ms", "host_prep_ms", "prepare_ms", "speculate_ms",
             "unattributed_ms")
 #: the spans a one-program collect opens, and those only a split one does
 COMMON = {"tpu.collect", "tpu.plan", "tpu.scope_enter", "tpu.prepare",
-          "tpu.launch", "tpu.fetch", "tpu.finish"}
-SPLIT_ONLY = {"tpu.speculate", "tpu.seam", "tpu.seam_wait"}
+          "tpu.launch", "tpu.fetch", "tpu.finish", "tpu.launch_inputs",
+          "tpu.enqueue", "tpu.launch_outputs", "tpu.fetch_wait",
+          "tpu.arrow_build"}
+SPLIT_ONLY = {"tpu.speculate", "tpu.seam", "tpu.seam_wait",
+              "tpu.seam_resolve"}
+#: split span -> the span it is a part of (its key holds its own time)
+PART_OF = {"tpu.launch_inputs": "tpu.launch", "tpu.enqueue": "tpu.launch",
+           "tpu.launch_outputs": "tpu.launch", "tpu.fetch_wait": "tpu.fetch",
+           "tpu.arrow_build": "tpu.fetch", "tpu.seam_wait": "tpu.seam",
+           "tpu.seam_resolve": "tpu.seam"}
 
 
 def _spans_of(shape, kept=False):
@@ -109,6 +117,84 @@ def test_default_conf_fills_every_key_and_they_add_up(shape):
         pytest.approx(ov["collect_ms"], abs=1e-6)
     # the wait is a part of the seam, not a category beside it
     assert ov["seam_wait_ms"] <= ov["seam_ms"] + 1e-9
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_split_spans_are_parts_of_their_parent(shape):
+    """The launch, the fetch and the seam are cut where the chip waits:
+    each part's key holds its own time, and the parts of one span add
+    up to no more than it.  The cut leaves the ADDITIVE keys adding up
+    to the wall."""
+    df = SHAPES[shape](TpuSession(WHOLE))
+    df.collect()
+    m = df.metrics()
+    ov = {k.removeprefix("overhead."): v for k, v in m.items()
+          if k.startswith("overhead.")}
+    parts = {"launch_ms": ("launch_inputs_ms", "enqueue_ms",
+                           "launch_outputs_ms"),
+             "fetch_ms": ("fetch_wait_ms", "arrow_build_ms")}
+    if shape == "seams":
+        parts["seam_ms"] = ("seam_wait_ms", "seam_resolve_ms")
+    for whole, keys in parts.items():
+        assert all(ov.get(k, 0.0) > 0.0 for k in keys), (whole, ov)
+        assert sum(ov[k] for k in keys) <= ov[whole] + 1e-9, (whole, ov)
+    assert sum(ov.get(k, 0.0) for k in ADDITIVE) == \
+        pytest.approx(ov["collect_ms"], abs=1e-6)
+    # the one timer of the program call
+    assert "exec_device_ms" not in m
+    assert 0.0 <= ov["offcpu_ms"] <= ov["collect_ms"]
+
+
+def test_a_thread_holding_the_gil_shows_as_offcpu_time():
+    """A Python thread that spins while a collect runs takes the GIL from
+    the collecting thread now and then, and keeps it for a switch
+    interval: the off-CPU time of 40 collects rises above that of the
+    same 40 collects uncontended."""
+    import threading
+    df = _q6_df(TpuSession(WHOLE))
+    df.collect()
+
+    def offcpu(n=40):
+        total = 0.0
+        for _ in range(n):
+            df.collect()
+            total += df.metrics()["overhead.offcpu_ms"]
+        return total
+
+    alone = offcpu()
+    stop = threading.Event()
+
+    def spin():
+        x = 0
+        while not stop.is_set():
+            x += 1
+
+    t = threading.Thread(target=spin, daemon=True)
+    t.start()
+    try:
+        contended = offcpu()
+    finally:
+        stop.set()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert contended > alone and contended > 2.0, (alone, contended)
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_a_direct_physical_collect_adds_up_to_its_wall(shape):
+    """PhysicalQuery.collect(ctx) called without DataFrame.collect() (a
+    serving ticket, bench.py) opens its own tpu.collect: the ADDITIVE keys
+    are filled and add up to overhead.collect_ms."""
+    from spark_rapids_tpu.exec.plan import ExecContext
+    q = SHAPES[shape](TpuSession(WHOLE)).physical()
+    for _ in range(2):                 # a context each, or one used again
+        ctx = ExecContext(q.conf)
+        q.collect(ctx)
+        m = ctx.metrics
+        assert ctx.open_spans == [] and ctx.query_seq == 0
+        assert m["overhead.collect_ms"] > 0.0 and "overhead.plan_ms" not in m
+        assert sum(m.get("overhead." + k, 0.0) for k in ADDITIVE) == \
+            pytest.approx(m["overhead.collect_ms"], abs=1e-6)
 
 
 @pytest.mark.parametrize("frame", ["same", "rebuilt"])
@@ -189,6 +275,11 @@ def test_spans_land_in_the_profiler_trace(shape, tmp_path):
         assert {e[0] for e in inside} == \
             _spans_of(shape, kept=True) - {"tpu.collect"}
         assert {e[3] for e in inside} == {query}
+        # each part lies inside an instance of the span it is a part of
+        for name, s0, s1, _q in inside:
+            if name in PART_OF:
+                assert any(p[0] == PART_OF[name] and p[1] <= s0
+                           and s1 <= p[2] for p in inside), name
     # scripts/trace_by_operator.py reads the same file: each span's own
     # time, which adds up to the annotation's wall
     tool = _by_operator()
@@ -220,14 +311,16 @@ def test_event_log_holds_the_same_spans(shape, tmp_path):
     assert {sp.name for sp in seam.values()} == _spans_of(shape)
     whole = [sp for sp in seam.values() if sp.name == "tpu.collect"]
     assert len(whole) == 1 and whole[0].parent is None
-    nested = {"tpu.seam_wait": "tpu.seam"}
     for sp in seam.values():
         if sp is whole[0]:
             continue
         parent = seam[sp.parent]               # a tpu.* span, never "query"
         assert whole[0].t0 <= sp.t0 and sp.t1 <= whole[0].t1 + 1e-9
-        if sp.name in nested:
-            assert parent.name == nested[sp.name]
+        assert 0.0 <= sp.attrs["cpu_ms"]
+        # the program call is the execute interval of the profile readers
+        assert (sp.cat == "execute") == (sp.name == "tpu.enqueue")
+        if sp.name in PART_OF:
+            assert parent.name == PART_OF[sp.name]
         elif sp.name == "tpu.prepare":         # a lone program looks its
             assert parent.name in ("tpu.collect", "tpu.launch")  # executable up inside its launch
         else:

@@ -93,7 +93,12 @@ def test_second_collect_runs_through_the_kept_plan(shape, monkeypatch):
         assert 0.0 < m["overhead.plan_ms"]
         assert sum(v for k, v in m.items() if k.startswith("overhead.")
                    and k.endswith("_ms") and k not in (
-                       "overhead.collect_ms", "overhead.seam_wait_ms")) == \
+                       "overhead.collect_ms", "overhead.offcpu_ms",
+                       "overhead.seam_wait_ms", "overhead.seam_resolve_ms",
+                       "overhead.launch_inputs_ms", "overhead.enqueue_ms",
+                       "overhead.launch_outputs_ms",
+                       "overhead.fetch_wait_ms",
+                       "overhead.arrow_build_ms")) == \
             pytest.approx(m["overhead.collect_ms"], abs=1e-6)
 
 

@@ -140,8 +140,14 @@ def test_a_warm_mesh_collect_places_nothing_and_compiles_nothing(
         parts = sum(v for k, v in m.items()
                     if k.startswith("overhead.") and k.endswith("_ms")
                     and k not in ("overhead.collect_ms",
+                                  "overhead.offcpu_ms",
                                   "overhead.seam_wait_ms",
-                                  "overhead.fetch_wait_ms"))
+                                  "overhead.seam_resolve_ms",
+                                  "overhead.launch_inputs_ms",
+                                  "overhead.enqueue_ms",
+                                  "overhead.launch_outputs_ms",
+                                  "overhead.fetch_wait_ms",
+                                  "overhead.arrow_build_ms"))
         assert parts == pytest.approx(m["overhead.collect_ms"], rel=1e-6)
     # without spans the wall's categories still add up: a placement
     # counts as an upload
