@@ -659,15 +659,18 @@ def _seam_bucket_get(key: Optional[tuple]) -> Optional[tuple]:
 #: program: an aggregate's strategy, capacity and merged batches
 #: (exec/aggregate.py `_note`, HashAggregateExec), the expressions over
 #: a wide decimal computed on the device (counted by the planner, kept
-#: on the nodes) and the largest static build bound of a join
+#: on the nodes), the largest static build bound of a join
 #: (exec/join.py), decided while the node is traced into the program,
-#: and the row gathers grouped_take put into it (`gather.ops`,
+#: the row gathers grouped_take put into it (`gather.ops`,
 #: `gather.lanes`: ops/filter.py `gather_tally`, read off the traced
-#: program; a seam program's are counted by each dispatch, `_resolve_at`).
+#: program; a seam program's are counted by each dispatch, `_resolve_at`)
+#: and the join probes that compare with a small build side's keys
+#: (`join.small_build_probes`: ops/join.py `_sorted_search`).
 #: Every other host number of a trace is a fact of the plan, copied into
 #: `ctx.metrics` when the program is compiled or adopted.
 _PER_RUN_PREFIX = ("agg.", "expr.wide_decimal_device",
-                   "join.build_bound_rows", "gather.")
+                   "join.build_bound_rows", "gather.",
+                   "join.small_build_probes")
 #: ... of which these read the largest value among the collect's
 #: programs, not the sum over them
 _PER_RUN_MAX = frozenset({"join.build_bound_rows"})

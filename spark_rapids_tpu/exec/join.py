@@ -700,7 +700,7 @@ class HashJoinExec(PlanNode):
                 # (exact ranges) need no host sync and no uniqueness
                 if len(probe_lanes) == 1 and len(build.lanes) == 1:
                     matched = J.probe_matched_lazy(build, probe_lanes,
-                                                   probe_valid)
+                                                   probe_valid, ctx)
                 else:
                     lo, counts, cum, total = J.probe_counts(
                         build, probe_lanes, probe_valid)
@@ -729,7 +729,7 @@ class HashJoinExec(PlanNode):
 
             if aligned:
                 build_idx, ok = J.probe_aligned(build, probe_lanes,
-                                                probe_valid)
+                                                probe_valid, ctx)
                 # a masked probe's live rows are NOT a prefix: gather with
                 # every position live; sel excludes dead rows downstream
                 out_rows = pb.capacity if pb.sel is not None else pb.num_rows

@@ -20,7 +20,9 @@ dictionary size for strings; packed-lane span for composite keys):
      search, no sort for the unique case, O(1) per probe row;
   3. without a domain, multi-key rows fold their lanes into a 64-bit
      mixed hash, the build side is sorted by it once, and probes binary-
-     search the sorted lane (`searchsorted`) for candidate ranges;
+     search the sorted lane (`searchsorted`) for candidate ranges; a
+     small single-lane build is searched by comparing every probe key
+     with each of its live keys instead (`_small_build_lookup`);
   4. candidate pairs expand into a static output bucket (pair ownership
      recovered by scatter + cummax, not search) and are *verified*
      lane-by-lane, so hash collisions cannot produce wrong results, they
@@ -360,6 +362,67 @@ class BuildTable:
 
 _PROBE_CACHE = {}
 
+#: A sorted single-lane build of at most this many rows of static capacity
+#: is probed by comparing every probe key with its live keys
+#: (`_small_build_lookup`), a larger one by the merge-rank search.  On a
+#: TPU v5e a 4,194,304-row probe batch compares with 8,192 live keys in 39
+#: ms and merge-ranks in 49-56 ms whatever the live count; the comparison
+#: walks the live keys alone, so fewer cost less (650: 4.4 ms;
+#: scripts/probe_microbench.py)
+SMALL_BUILD_CAP = 8_192
+#: live build keys compared with every probe row in one step of the loop
+_CMP_BLOCK = 256
+
+
+def compares_small_build(build: "BuildTable") -> bool:
+    """Whether single-lane probes of `build` compare (`_small_build_lookup`)
+    rather than merge-rank: a sorted lane (no domain) of at most
+    SMALL_BUILD_CAP rows."""
+    return build.domain is None and len(build.lanes) == 1 and \
+        build.capacity <= SMALL_BUILD_CAP
+
+
+def _sorted_search(build: "BuildTable", compare, merge, ctx):
+    """The search a probe of a sorted build compiles: `compare` for a
+    small build (compares_small_build), counted in `ctx` as
+    `join.small_build_probes`, else `merge`."""
+    if not compares_small_build(build):
+        return merge
+    if ctx is not None:
+        ctx.bump("join.small_build_probes")
+    return compare
+
+
+def _small_build_lookup(sorted_hash: jax.Array, perm: jax.Array,
+                        valid_count: jax.Array, h: jax.Array) -> jax.Array:
+    """The build row whose key equals each probe key `h` (int32; -1 where
+    none does; the largest such row where several do), by comparing `h`
+    with every live build key: no sort, no gather.
+
+    The live keys are the prefix [0, valid_count) of the sorted lane
+    (BuildTable._sort), walked _CMP_BLOCK keys a step for
+    ceil(valid_count / _CMP_BLOCK) steps, a device value.  A step is one
+    fused compare-select-max reduced over the block's keys, the major
+    axis, into the probe-long accumulator.  The rows past `valid_count`
+    hold the all-ones sentinel, which a probe key of -1 equals: their row
+    is -1 before any comparison, so they never match."""
+    n = sorted_hash.shape[0]
+    pad = -n % _CMP_BLOCK
+    keys = jnp.pad(sorted_hash, (0, pad)).reshape(-1, _CMP_BLOCK)
+    live = jnp.arange(n + pad, dtype=jnp.int32) < valid_count
+    rows = jnp.where(live, jnp.pad(perm.astype(jnp.int32), (0, pad)),
+                     jnp.int32(-1)).reshape(-1, _CMP_BLOCK)
+
+    def step(i, acc):
+        k = jax.lax.dynamic_index_in_dim(keys, i, keepdims=False)
+        r = jax.lax.dynamic_index_in_dim(rows, i, keepdims=False)
+        hit = jnp.where(h[None, :] == k[:, None], r[:, None], jnp.int32(-1))
+        return jnp.maximum(acc, jnp.max(hit, axis=0))
+
+    steps = (valid_count + (_CMP_BLOCK - 1)) // _CMP_BLOCK
+    return jax.lax.fori_loop(0, steps, step,
+                             jnp.full(h.shape, -1, jnp.int32))
+
 
 def _merge_rank(sorted_vals: jax.Array, queries: jax.Array,
                 side: str) -> jax.Array:
@@ -402,8 +465,47 @@ def _dense_probe_pos(lane: jax.Array, probe_valid: jax.Array,
     return pos, inb
 
 
+def _aligned_merge(perm, sorted_hash, valid_count, h, p_valid):
+    """probe_aligned's sorted search: the slot at searchsorted-left is
+    the unique candidate -> (build row, ok)."""
+    lo = _merge_rank(sorted_hash, h, side="left")
+    in_range = lo < valid_count
+    pos = jnp.clip(lo, 0, sorted_hash.shape[0] - 1)
+    # the candidate's hash and its build row as ONE gathered matrix: a
+    # TPU gather pays per gathered row, and this one is as long as the
+    # probe.  With a single lane the hash IS the key (composite_hash), so
+    # its equality is the key's; and the sorted order holds exactly the
+    # valid-key rows before `valid_count` (BuildTable._sort), so a
+    # candidate in range has a valid key: no second and third gather
+    # through `build_idx` to say the same again
+    cand = jnp.take(jnp.stack([sorted_hash, perm.astype(jnp.uint64)],
+                              axis=1), pos, axis=0)
+    ok = p_valid & in_range & (cand[:, 0] == h)
+    return cand[:, 1].astype(jnp.int32), ok
+
+
+def _aligned_compare(perm, sorted_hash, valid_count, h, p_valid):
+    row = _small_build_lookup(sorted_hash, perm, valid_count, h)
+    return jnp.maximum(row, 0), p_valid & (row >= 0)
+
+
+def _matched_merge(perm, sorted_hash, valid_count, h, p_valid):
+    """probe_matched_lazy's sorted search: a non-empty candidate range is
+    a match."""
+    lo = _merge_rank(sorted_hash, h, side="left")
+    hi = _merge_rank(sorted_hash, h, side="right")
+    lo = jnp.minimum(lo, valid_count)
+    hi = jnp.minimum(hi, valid_count)
+    return p_valid & (hi > lo)
+
+
+def _matched_compare(perm, sorted_hash, valid_count, h, p_valid):
+    return p_valid & (_small_build_lookup(sorted_hash, perm, valid_count,
+                                          h) >= 0)
+
+
 def probe_aligned(build: BuildTable, probe_lanes: List[jax.Array],
-                  probe_valid: jax.Array):
+                  probe_valid: jax.Array, ctx=None):
     """Probe a build side whose keys are UNIQUE: each probe row has at
     most one match, so the output is probe-aligned — (build_idx, ok) with
     shape (probe_capacity,) and NO host sync (output capacity is the
@@ -411,7 +513,9 @@ def probe_aligned(build: BuildTable, probe_lanes: List[jax.Array],
 
     With a dense domain this is ONE gather from the direct-address
     table — no search, and the build needed no sort.  Otherwise the slot
-    at searchsorted-left is the unique candidate.
+    at searchsorted-left is the unique candidate, or, for a small build,
+    the one live key equal to the probe's (`_small_build_lookup`; counted
+    in `ctx`).
 
     SINGLE-LANE ONLY: with one canonical lane the lane is exact (zero
     collisions).  With multiple lanes the composite hash can collide
@@ -438,30 +542,14 @@ def probe_aligned(build: BuildTable, probe_lanes: List[jax.Array],
             fn = jax.jit(run)
             _PROBE_CACHE[sig] = fn
         return fn(build.slot, probe_lanes[0], probe_valid)
+    search = _sorted_search(build, _aligned_compare, _aligned_merge, ctx)
     sig = ("aligned", build.capacity, probe_valid.shape[0],
-           len(probe_lanes))
+           len(probe_lanes), search)
     fn = _PROBE_CACHE.get(sig)
     if fn is None:
-        bcap = build.capacity
-
         def run(perm, sorted_hash, valid_count, p_lanes, p_valid):
-            h = composite_hash(p_lanes)
-            lo = _merge_rank(sorted_hash, h, side="left")
-            in_range = lo < valid_count
-            pos = jnp.clip(lo, 0, bcap - 1)
-            # the candidate's hash and its build row as ONE gathered
-            # matrix: a TPU gather pays per gathered row, and this one is
-            # as long as the probe.  With a single lane the hash IS the
-            # key (composite_hash), so its equality is the key's; and the
-            # sorted order holds exactly the valid-key rows before
-            # `valid_count` (BuildTable._sort), so a candidate in range
-            # has a valid key: no second and third gather through
-            # `build_idx` to say the same again
-            cand = jnp.take(
-                jnp.stack([sorted_hash, perm.astype(jnp.uint64)], axis=1),
-                pos, axis=0)
-            ok = p_valid & in_range & (cand[:, 0] == h)
-            return cand[:, 1].astype(jnp.int32), ok
+            return search(perm, sorted_hash, valid_count,
+                          composite_hash(p_lanes), p_valid)
         fn = jax.jit(run)
         _PROBE_CACHE[sig] = fn
     return fn(build.perm, build.sorted_hash, build.valid_count,
@@ -469,7 +557,7 @@ def probe_aligned(build: BuildTable, probe_lanes: List[jax.Array],
 
 
 def probe_matched_lazy(build: BuildTable, probe_lanes: List[jax.Array],
-                       probe_valid: jax.Array) -> jax.Array:
+                       probe_valid: jax.Array, ctx=None) -> jax.Array:
     """Per-probe-row matched flag with NO host sync — sound only for a
     SINGLE canonical lane, where the "hash" is the lane itself and a
     non-empty candidate range proves a true match (semi/anti joins need
@@ -504,20 +592,17 @@ def probe_matched_lazy(build: BuildTable, probe_lanes: List[jax.Array],
             fn = jax.jit(run)
             _PROBE_CACHE[sig] = fn
         return fn(build.offs, probe_lanes[0], probe_valid)
-    sig = ("matched_lazy", build.capacity, probe_valid.shape[0])
+    search = _sorted_search(build, _matched_compare, _matched_merge, ctx)
+    sig = ("matched_lazy", build.capacity, probe_valid.shape[0], search)
     fn = _PROBE_CACHE.get(sig)
     if fn is None:
-        def run(sorted_hash, valid_count, lanes, pvalid):
-            h = composite_hash(lanes)
-            lo = _merge_rank(sorted_hash, h, side="left")
-            hi = _merge_rank(sorted_hash, h, side="right")
-            lo = jnp.minimum(lo, valid_count)
-            hi = jnp.minimum(hi, valid_count)
-            return pvalid & (hi > lo)
+        def run(perm, sorted_hash, valid_count, lanes, pvalid):
+            return search(perm, sorted_hash, valid_count,
+                          composite_hash(lanes), pvalid)
         fn = jax.jit(run)
         _PROBE_CACHE[sig] = fn
-    return fn(build.sorted_hash, build.valid_count, tuple(probe_lanes),
-              probe_valid)
+    return fn(build.perm, build.sorted_hash, build.valid_count,
+              tuple(probe_lanes), probe_valid)
 
 
 def probe_counts(build: BuildTable, probe_lanes: List[jax.Array],

@@ -9,8 +9,11 @@
 Every path is validated against the same queries on the slow/sized path
 (uniqueness knowledge stripped) and against a pyarrow oracle.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute  # noqa: F401
 import pytest
 
 from spark_rapids_tpu.exec.join import CrossJoinExec, HashJoinExec
@@ -18,6 +21,7 @@ from spark_rapids_tpu.exec.plan import (ExecContext, FilterExec,
                                         HashAggregateExec, HostScanExec,
                                         ProjectExec, SortExec)
 from spark_rapids_tpu.plan import expressions as E
+from spark_rapids_tpu.ops import join as J
 from spark_rapids_tpu.plan.aggregates import Count, Sum
 
 
@@ -275,3 +279,153 @@ def test_topn_output_capacity_bounded():
     assert len(outs) == 1
     assert outs[0].capacity <= 1024    # bucket_capacity(5) at defaults
     assert top.collect().column("x").to_pylist() == [0, 1, 2, 3, 4]
+
+
+# -- a small sorted build side is probed by comparison ----------------------
+
+_BLK = J._CMP_BLOCK
+_CAP = 1024             # the build's bucket in every case but three
+_SMALL_CASES = {        # case -> (build rows, live keys)
+    "live_0": (_CAP, 0), "live_1": (_CAP, 1),
+    "live_blk-1": (_CAP, _BLK - 1), "live_blk": (_CAP, _BLK),
+    "live_blk+1": (_CAP, _BLK + 1), "live_full": (_CAP, _CAP),
+    "null_keys": (_CAP, 600), "masked_probe": (_CAP, 600),
+    "negative": (_CAP, 600), "above_2_32": (_CAP, 600),
+    "duplicates": (_CAP, 600),
+    "live_4096": (4096, 4096),      # the largest bucket under the cap
+    "over_live": (J.SMALL_BUILD_CAP + 1, J.SMALL_BUILD_CAP + 1),
+    "over_cap": (2 * J.SMALL_BUILD_CAP, 600),
+}
+
+
+def _small_build_tables(case):
+    """(probe, build) pyarrow tables of a case: single int64 keys spread
+    over far more than 16 x the build's capacity, so no direct-address
+    table is built and the build is a sorted lane.  The build's first
+    `live` rows (w < 10 x live) pass the filter the join is given; a
+    third of the probe keys are live build keys, -1 is among them (the
+    dead rows' sentinel)."""
+    rows, live = _SMALL_CASES[case]
+    rng = np.random.default_rng(sorted(_SMALL_CASES).index(case))
+    lo, hi = {"negative": (-2**40, 0),
+              "above_2_32": (2**32, 2**62)}.get(case, (-2**40, 2**40))
+    keys = np.unique(rng.integers(lo, hi, rows + 64))
+    keys = rng.permutation(keys)[:rows].astype(np.int64)
+    if case == "negative":
+        keys[keys == -1] = -2        # -1 probes against dead rows only
+    if case == "duplicates":
+        keys[1:live:3] = keys[0:live - 1:3]
+    bmask = (rng.random(rows) < 0.2) if case == "null_keys" else None
+    build = pa.table({"k": pa.array(keys, pa.int64(), mask=bmask),
+                      "w": pa.array(np.arange(rows, dtype=np.int64) * 10)})
+    n = 3000
+    pk = rng.integers(lo, hi, n)
+    if live:
+        hit = rng.random(n) < 0.34
+        pk[hit] = keys[rng.integers(0, live, int(hit.sum()))]
+    pk[:3] = [-1, -1, keys[0]]
+    if case == "above_2_32":
+        pk[3] = keys[0] & 0xFFFFFFFF          # equal in the low word only
+    pmask = (rng.random(n) < 0.05) if case == "null_keys" else None
+    probe = pa.table({"fk": pa.array(pk, pa.int64(), mask=pmask),
+                      "v": pa.array(np.arange(n, dtype=np.int64))})
+    return probe, build
+
+
+def _rows(tbl, names):
+    return sorted(zip(*[tbl.column(c).to_pylist() for c in names]),
+                  key=repr)
+
+
+@pytest.mark.parametrize("case,jt", [
+    (case, jt) for case in sorted(_SMALL_CASES)
+    for jt in ("inner", "left_outer", "left_semi", "left_anti")
+    # duplicate build keys are not a unique build: inner and outer joins
+    # take the sized path there
+    if case != "duplicates" or jt in ("left_semi", "left_anti")])
+def test_small_sorted_build_probe(case, jt):
+    """The comparison lookup of a small sorted build side: the join equals
+    pyarrow's and each probe equals the merge-rank path called directly,
+    bit for bit.  A build over SMALL_BUILD_CAP rows of capacity, whether
+    its keys are all live (over_live) or few (over_cap), merge-ranks and
+    counts no comparison."""
+    probe, build = _small_build_tables(case)
+    rows, live = _SMALL_CASES[case]
+    left = HostScanExec.from_table(probe)
+    if case == "masked_probe":
+        left = FilterExec(E.GreaterThan(E.ColumnRef("v"), E.Literal(700)),
+                          left)
+        probe = probe.filter(pa.compute.greater(probe["v"], 700))
+    right = FilterExec(E.LessThan(E.ColumnRef("w"), E.Literal(10 * live)),
+                       HostScanExec.from_table(build))
+    j = HashJoinExec(jt, [E.ColumnRef("fk")], [E.ColumnRef("k")], left,
+                     right)
+    ctx = ExecContext()
+    out = j.collect(ctx)
+    assert "join_dense_domain" not in ctx.metrics
+    small = case not in ("over_live", "over_cap")
+    assert ctx.metrics.get("join.small_build_probes", 0) == int(small)
+    oracle = probe.join(build.filter(pa.compute.less(build["w"], 10 * live)),
+                        keys="fk", right_keys="k",
+                        join_type=jt.replace("_", " "))
+    names = ["fk", "v"] + (["w"] if jt in ("inner", "left_outer") else [])
+    assert _rows(out, names) == _rows(oracle, names)
+
+    # the probe itself, against the merge-rank path called directly
+    bdb = next(iter(HostScanExec.from_table(build).execute(ExecContext())))
+    pdb = next(iter(HostScanExec.from_table(probe).execute(ExecContext())))
+    bt = J.BuildTable(bdb, [bdb.columns[0]], extra_valid=bdb.row_mask() &
+                      (bdb.columns[1].data < 10 * live))
+    assert bt.domain is None and J.compares_small_build(bt) == small
+    assert (bt.capacity <= J.SMALL_BUILD_CAP) == small
+    lanes = J.key_cols_lanes([pdb.columns[0]])
+    mask = jnp.asarray(np.random.default_rng(live).random(pdb.capacity)
+                       < 0.9)
+    pvalid = pdb.row_mask() & pdb.columns[0].validity & mask
+    args = (bt.perm, bt.sorted_hash, bt.valid_count,
+            J.composite_hash(lanes), pvalid)
+    pctx = ExecContext()
+    if jt in ("inner", "left_outer"):
+        idx, ok = J.probe_aligned(bt, lanes, pvalid, pctx)
+        want_idx, want_ok = jax.jit(J._aligned_merge)(*args)
+        assert np.array_equal(np.asarray(ok), np.asarray(want_ok))
+        assert np.array_equal(np.asarray(idx)[np.asarray(ok)],
+                              np.asarray(want_idx)[np.asarray(want_ok)])
+    else:
+        assert np.array_equal(
+            np.asarray(J.probe_matched_lazy(bt, lanes, pvalid, pctx)),
+            np.asarray(jax.jit(J._matched_merge)(*args)))
+    assert pctx.metrics.get("join.small_build_probes", 0) == int(small)
+
+
+def _whole_plan_join(s, probe, build):
+    df = s.from_arrow(probe).join(s.from_arrow(build), left_on=["fk"],
+                                  right_on=["k"], how="inner")
+    return df.group_by("g").agg((Sum(E.ColumnRef("w")), "sw")).sort("g")
+
+
+@pytest.mark.parametrize("dense", [False, True])
+def test_small_build_probe_counter(dense):
+    """`join.small_build_probes` counts the probes compiled with the
+    comparison lookup once a program run, through a kept plan: one a probe
+    batch for a small sorted build, 0 where the build keys have a dense
+    domain (the join cell's q3 and q5 shapes)."""
+    from spark_rapids_tpu.session import TpuSession
+    rng = np.random.default_rng(38)
+    keys = np.arange(500, dtype=np.int64) * (1 if dense else 2**31 + 11)
+    build = pa.table({"k": keys, "w": np.arange(500, dtype=np.int64)})
+    fk = keys[rng.integers(0, 500, 5000)]
+    fk[::7] = -5                    # no build key
+    probe = pa.table({"fk": fk, "g": np.arange(5000, dtype=np.int64) % 5})
+    conf = {"spark.rapids.tpu.sql.compile.wholePlan": "ON",
+            "spark.rapids.tpu.sql.batchSizeRows": "2048"}
+    df = _whole_plan_join(TpuSession(conf), probe, build)
+    cpu = _whole_plan_join(
+        TpuSession({"spark.rapids.tpu.sql.enabled": "false"}), probe,
+        build).collect()
+    for _ in range(2):              # compiled, then through the kept plan
+        assert df.collect().to_pydict() == cpu.to_pydict()
+        m = df.metrics()
+        assert m["whole_plan_compiled_queries"] == 1
+        assert m.get("join.small_build_probes", 0) == (0 if dense else 3)
+    assert m.get("plan.reused") == 1

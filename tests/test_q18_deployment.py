@@ -360,6 +360,36 @@ def test_q18_splits_under_its_oversized_build_sides(bench):
         assert (m["gather.ops"], m["gather.lanes"]) == (59, 477)
 
 
+def test_q18_probes_its_small_build_sides_by_comparison(bench):
+    """The cell's plan where the order keys span more than 2**20 (SF0.25):
+    the seams shrink the two build sides the plan splits under (the named
+    orders, the large orders) to the bucket of their few live rows, where
+    no direct-address table fits such a span.  In 131,072-row batches
+    orders comes in 3 batches, as SF10's comes in 4 of 4,194,304 rows, so
+    the named orders' build is bucketed to 4,096 rows as there: every
+    probe batch of the join back to lineitem (12) and of the semi-join of
+    orders (3) compares its keys with the build's live keys (ops/join.py
+    `compares_small_build`), counted once a program run; the customer
+    join keeps its dense domain.  At SF10 the same plan reads 15 + 4."""
+    tables = bench.gen.gen_tables(0.25, 2147486311,
+                                  bench.q18.SOURCE_COLUMNS)
+    conf = dict(WHOLE, **{
+        "spark.rapids.tpu.sql.batchSizeRows": "131072",
+        "spark.rapids.tpu.sql.compile.seamSplitMinRows": "1024"})
+    df = bench.q18.build(TpuSession(conf), tables)
+    reference = bench.q18.reference(tables)
+    assert reference.num_rows >= 2
+    for _ in range(2):            # compiled, then through the kept plan
+        verdict = bench.compare.judge([("q18", df.collect())],
+                                      {"q18": reference}, 0)
+        assert verdict["correct"], verdict["numbers"]
+        m = df.metrics()
+        assert bench.checks.collect_faults(m) == []
+        assert m["overhead.seam_count"] == 4
+        assert m["join.small_build_probes"] == 12 + 3
+        assert m["join_dense_domain"] == 1
+
+
 # -- what the sorted aggregate of many batches forced ----------------------
 
 def test_a_sorted_aggregate_of_many_batches_aggregates_once(bench):
