@@ -397,6 +397,7 @@ def _node_exprs(node) -> Optional[list]:
                        GlobalLimitExec, HashAggregateExec, LocalLimitExec,
                        ProjectExec, RangeExec, SampleExec, SortExec,
                        TopNExec, UnionExec)
+    from .window import WindowExec
     if isinstance(node, ProjectExec):
         return list(node.exprs)
     if isinstance(node, FilterExec):
@@ -410,6 +411,11 @@ def _node_exprs(node) -> Optional[list]:
                 + list(getattr(node, "build_conds", None) or ()))
     if isinstance(node, ExpandExec):
         return [e for p in node.projections for e in p]
+    if isinstance(node, WindowExec):
+        return (list(node.partition_keys)
+                + [e for e, _asc, _nf in node.order_keys]
+                + [spec.child for spec, _n in node.window_exprs
+                   if spec.child is not None])
     if isinstance(node, (HostScanExec, DeviceResidentScanExec, SortExec,
                          TopNExec, GlobalLimitExec, LocalLimitExec,
                          UnionExec, CoalesceBatchesExec, RangeExec,
@@ -438,6 +444,16 @@ def _node_extras(node) -> tuple:
                        node.batch_rows))
     if isinstance(node, SampleExec):
         extras.append((node.fraction, node.seed))
+    from .window import WindowExec
+    if isinstance(node, WindowExec):
+        # how _node_exprs' list splits, the order directions, and each
+        # function's kind, parameters, resolved frame and output name
+        # (its input tree is among the expressions)
+        extras.append(("window", len(node.partition_keys),
+                       tuple((asc, nf) for _e, asc, nf in node.order_keys),
+                       tuple((type(spec).__name__, spec._fp_extra(),
+                              node._resolved_frame(spec).fp(), name)
+                             for spec, name in node.window_exprs)))
     jt = getattr(node, "join_type", None)
     if jt is not None:
         extras.append(("join", jt, getattr(node, "lazy_sel", None),
@@ -664,13 +680,17 @@ def _seam_bucket_get(key: Optional[tuple]) -> Optional[tuple]:
 #: the row gathers grouped_take put into it (`gather.ops`,
 #: `gather.lanes`: ops/filter.py `gather_tally`, read off the traced
 #: program; a seam program's are counted by each dispatch, `_resolve_at`)
-#: and the join probes that compare with a small build side's keys
-#: (`join.small_build_probes`: ops/join.py `_sorted_search`).
+#: the join probes that compare with a small build side's keys
+#: (`join.small_build_probes`: ops/join.py `_sorted_search`), the window
+#: operators traced into the program (`window.in_program`, WindowExec)
+#: and the rows of capacity ExpandExec's grouping sets put into it
+#: (`expand.capacity_rows`).
 #: Every other host number of a trace is a fact of the plan, copied into
 #: `ctx.metrics` when the program is compiled or adopted.
 _PER_RUN_PREFIX = ("agg.", "expr.wide_decimal_device",
                    "join.build_bound_rows", "gather.",
-                   "join.small_build_probes")
+                   "join.small_build_probes", "window.in_program",
+                   "expand.capacity_rows")
 #: ... of which these read the largest value among the collect's
 #: programs, not the sum over them
 _PER_RUN_MAX = frozenset({"join.build_bound_rows"})

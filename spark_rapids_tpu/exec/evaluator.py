@@ -145,9 +145,12 @@ def _build_inputs(meta, col_data, col_valid):
                     lane.itemsize < phys.itemsize:
                 narrow = view
                 view = view.astype(phys)
+        # a DOUBLE held as its bit pattern keeps it beside the f64 view
+        bits = d if isinstance(dtype, t.DoubleType) and \
+            d.dtype == jnp.int64 else None
         inputs[name] = DevVal(view, v, dtype, dictionary, hi,
                               offsets=offsets, elem_valid=elem_valid,
-                              narrow=narrow)
+                              narrow=narrow, bits=bits)
         raw[name] = d          # storage lane (f64-bits stay int64)
     return inputs, raw
 
@@ -186,8 +189,16 @@ def _jit_key(exprs, db, aux, conf, tag):
 
 
 def evaluate_projection(exprs: Sequence[Expression], names: Sequence[str],
-                        db: DeviceBatch, conf: TpuConf) -> DeviceBatch:
-    """Project `db` through bound expressions -> new DeviceBatch."""
+                        db: DeviceBatch, conf: TpuConf,
+                        exact_doubles: bool = False) -> DeviceBatch:
+    """Project `db` through bound expressions -> new DeviceBatch.
+
+    `exact_doubles`: a DOUBLE whose exact bit pattern is at hand (a host
+    column passed through, a decimal cast, a division of such doubles:
+    DevVal.bits) leaves as that int64 storage lane, as a scan delivers it,
+    and not through the device's f64; for projections whose output goes
+    on to other operators (ProjectExec, a window's inputs).  An operator
+    that projects its own inputs and reads them as f64 leaves it off."""
     if db.thin is not None:
         # late materialization: referenced deferred columns resolve here
         # (ONE composed gather per lane source); unreferenced ones stay
@@ -202,7 +213,8 @@ def evaluate_projection(exprs: Sequence[Expression], names: Sequence[str],
         db = ensure_prefix(db, conf)
     pctx, hostvals, aux = _prepare(exprs, db, conf)
     has_sel = db.sel is not None
-    key = _jit_key(exprs, db, aux, conf, ("project", has_sel))
+    key = _jit_key(exprs, db, aux, conf, ("project", has_sel,
+                                          exact_doubles))
     fn = _JIT_CACHE.get(key)
     if fn is None:
         capacity = db.capacity
@@ -227,6 +239,8 @@ def evaluate_projection(exprs: Sequence[Expression], names: Sequence[str],
                     # FOR-narrowed lane rides through the projection
                     # un-widened (the decode stays sunk downstream)
                     data = dv.narrow
+                elif exact_doubles and dv.bits is not None:
+                    data = dv.bits
                 else:
                     data = storage_view(dv.data, e.dtype)
                 valid = dv.validity if dv.validity is not None \
@@ -263,11 +277,13 @@ def project_batch(exprs: Sequence[Expression], names: Sequence[str],
     join chain deferred.  Computed expressions still materialize exactly
     the columns they reference (early materialization)."""
     if db.thin is None:
-        return evaluate_projection(exprs, names, db, conf)
+        return evaluate_projection(exprs, names, db, conf,
+                                   exact_doubles=True)
     if any(c.offsets is not None for c in db.columns):
         # ragged lanes can force an internal prefix compaction whose
         # row order would desync from pass-through lanes — stay dense
-        return evaluate_projection(exprs, names, db, conf)
+        return evaluate_projection(exprs, names, db, conf,
+                                   exact_doubles=True)
     from ..columnar.lanes import (ThinState, materialize_refs,
                                   passthrough_positions)
     pass_map = passthrough_positions(db, exprs)
@@ -278,11 +294,13 @@ def project_batch(exprs: Sequence[Expression], names: Sequence[str],
         # a computed expr may have materialized a pass-through column too
         pass_map = {oi: p for oi, p in pass_map.items() if p in ts.pending}
     if ts is None or not pass_map:
-        return evaluate_projection(exprs, names, db, conf)
+        return evaluate_projection(exprs, names, db, conf,
+                                   exact_doubles=True)
     cols: List[Optional[DeviceColumn]] = [None] * len(exprs)
     if eval_idx:
         ev = evaluate_projection([exprs[i] for i in eval_idx],
-                                 [names[i] for i in eval_idx], db, conf)
+                                 [names[i] for i in eval_idx], db, conf,
+                                 exact_doubles=True)
         for i, c in zip(eval_idx, ev.columns):
             cols[i] = c
     used: List = []

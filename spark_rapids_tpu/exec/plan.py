@@ -1345,4 +1345,9 @@ class ExpandExec(PlanNode):
     def execute(self, ctx: ExecContext) -> Iterator[DeviceBatch]:
         for db in self.child.execute(ctx):
             for proj in self.projections:
-                yield evaluate_projection(proj, self.names, db, ctx.conf)
+                out = evaluate_projection(proj, self.names, db, ctx.conf)
+                if ctx.traced:
+                    # the rows of capacity a program carries for the
+                    # grouping sets: the input's, once a projection
+                    ctx.bump("expand.capacity_rows", out.capacity)
+                yield out

@@ -15,6 +15,11 @@ TPU shape: the exec
      expression, and emits the child columns + window outputs in sorted
      order (Spark's WindowExec also emits child order = sort order).
 
+Inside a whole-plan program (`ExecContext.traced`) the same steps are
+traced into the caller's program: no batch is dropped for a count the host
+would have to read, the batches stack under their row masks, the sort puts
+dead rows last, and the window kernel is traced inline, not jitted apart.
+
 Out-of-core inputs: batches are merged under the memory budget's retry
 machinery upstream (exec/plan.py CoalesceBatchesExec); partition-chunked
 OOC windows (GpuCachedDoublePassWindowExec analogue) can layer on the same
@@ -74,12 +79,22 @@ class WindowExec(PlanNode):
         return default_frame(bool(self.order_keys))
 
     def execute(self, ctx: ExecContext) -> Iterator[DeviceBatch]:
-        batches = [db for db in self.child.execute(ctx)
-                   if int(db.num_rows) > 0]
-        if not batches:
-            return
-        db = batches[0] if len(batches) == 1 \
-            else concat_batches(batches, ctx.conf)
+        if ctx.traced:
+            # inside a whole-plan program a row count is a value of the
+            # program: every batch rides along, its dead rows under the
+            # row mask, stacked as the sorted aggregate stacks them; the
+            # sort below puts them last
+            batches = list(self.child.execute(ctx))
+            if not batches:
+                return
+            db = concat_batches(batches, ctx.conf, masked=True)
+        else:
+            batches = [db for db in self.child.execute(ctx)
+                       if int(db.num_rows) > 0]
+            if not batches:
+                return
+            db = batches[0] if len(batches) == 1 \
+                else concat_batches(batches, ctx.conf)
 
         child_names = list(db.names)
         n_child = len(child_names)
@@ -108,7 +123,8 @@ class WindowExec(PlanNode):
             inputs.append(spec.child)
             v_idx.append(len(aug_exprs) - 1)
             spec_input_idx.append(len(inputs) - 1)
-        aug = evaluate_projection(aug_exprs, aug_names, db, ctx.conf)
+        aug = evaluate_projection(aug_exprs, aug_names, db, ctx.conf,
+                                  exact_doubles=True)
 
         # --- 3. sort by (partition, order) ---
         sort_keys = [SortKey(i, True, True) for i in p_idx]
@@ -132,24 +148,29 @@ class WindowExec(PlanNode):
             if has_value_range else ()
         from .aggregate import _seg_knobs
         scatter_free, max_ops = _seg_knobs(ctx.conf)
-        key = ("window", s.capacity,
-               tuple(sp.fingerprint() for sp, _f, _i in specs_frames),
-               tuple(f.fp() for _s, f, _i in specs_frames),
-               tuple(i for _s, _f, i in specs_frames),
-               order_dirs, scatter_free, max_ops,
-               tuple((c.dtype.simple_string, str(c.data.dtype))
-                     for c in part_cols + order_cols + val_cols))
-        fn = _WINDOW_JIT_CACHE.get(key)
-        if fn is None:
-            traced = window_trace(
+
+        def program():
+            return window_trace(
                 tuple((c.dtype,) for c in part_cols),
                 tuple((c.dtype,) for c in order_cols),
                 tuple((c.dtype,) for c in val_cols),
                 specs_frames, s.capacity, order_dirs=order_dirs,
                 scatter_free=scatter_free, max_sort_operands=max_ops)
-            fn = jax.jit(traced)
-            _WINDOW_JIT_CACHE[key] = fn
 
+        if ctx.traced:
+            fn = program()             # traced into the caller's program
+            ctx.bump("window.in_program")
+        else:
+            key = ("window", s.capacity,
+                   tuple(sp.fingerprint() for sp, _f, _i in specs_frames),
+                   tuple(f.fp() for _s, f, _i in specs_frames),
+                   tuple(i for _s, _f, i in specs_frames),
+                   order_dirs, scatter_free, max_ops,
+                   tuple((c.dtype.simple_string, str(c.data.dtype))
+                         for c in part_cols + order_cols + val_cols))
+            fn = _WINDOW_JIT_CACHE.get(key)
+            if fn is None:
+                fn = _WINDOW_JIT_CACHE[key] = jax.jit(program())
         outs = fn(tuple(c.data for c in part_cols),
                   tuple(c.validity for c in part_cols),
                   tuple(c.data for c in order_cols),

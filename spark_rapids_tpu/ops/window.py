@@ -39,6 +39,8 @@ from .groupby import (_bits_from_order, _bits_total_order,
 from .kernels import blocked_cumsum, compute_view
 from .segments import blocked_seg_scan, row0_true
 
+_NEG_ZERO = np.int64(-2 ** 63)
+
 
 def _seg_scan(vals: jax.Array, boundary: jax.Array, op) -> jax.Array:
     """Segmented inclusive scan: resets at rows where boundary is True.
@@ -71,7 +73,15 @@ def _boundary_from_lanes(lanes: List[jax.Array], capacity: int) -> jax.Array:
 def _key_eq_lanes(cols_info, datas, valids) -> List[jax.Array]:
     lanes: List[jax.Array] = []
     for (dt,), d, v in zip(cols_info, datas, valids):
-        lanes.extend(l for l in _null_first_key_lanes(compute_view(d, dt), v, dt)
+        if isinstance(dt, t.DoubleType) and d.dtype == jnp.int64:
+            # a DOUBLE held as its bit pattern: peers by the bits (NaN
+            # canonical, -0.0 = 0.0), not through this TPU's f64, which
+            # keeps ~48 of 53 bits and would tie neighbours
+            d = _bits_total_order(jnp.where(d == _NEG_ZERO, 0, d))
+            dt = None
+        else:
+            d = compute_view(d, dt)
+        lanes.extend(l for l in _null_first_key_lanes(d, v, dt)
                      if l is not None)
     return lanes
 

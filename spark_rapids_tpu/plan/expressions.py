@@ -140,7 +140,7 @@ class DevVal:
 
     def __init__(self, data, validity, dtype: t.DataType,
                  dictionary: Optional[pa.Array] = None, hi=None,
-                 offsets=None, elem_valid=None, narrow=None):
+                 offsets=None, elem_valid=None, narrow=None, bits=None):
         self.data = data
         self.validity = validity      # None = all rows valid
         self.dtype = dtype
@@ -153,6 +153,11 @@ class DevVal:
         # (comparisons, narrow arithmetic) compute on it, everything
         # else reads the full-width `data` view
         self.narrow = narrow
+        # a DOUBLE's exact int64 bit pattern beside its f64 `data`: a
+        # host column's storage lane, or a result built in integer
+        # arithmetic (ops/f64bits.py); consumers that keep it (Divide, a
+        # projection's output) stay exact where f64 on the device is not
+        self.bits = bits
 
 
 class Expression:
@@ -828,6 +833,14 @@ class Divide(BinaryArithmetic):
 
     def _eval_dev(self, ctx, kids):
         l, r = kids
+        if l.bits is not None and r.bits is not None:
+            # two doubles whose bit patterns are at hand: the IEEE
+            # quotient, as the host computes it, on every backend
+            from ..ops.f64bits import divide_double_bits
+            bits, nonzero = divide_double_bits(l.bits, r.bits)
+            return DevVal(jax.lax.bitcast_convert_type(bits, jnp.float64),
+                          merge_validity(l.validity, r.validity, nonzero),
+                          t.DOUBLE, bits=bits)
         ld = l.data.astype(jnp.float64)
         rd = r.data.astype(jnp.float64)
         safe_r = jnp.where(rd == 0.0, jnp.float64(1.0), rd)
@@ -2052,6 +2065,11 @@ class Cast(Expression):
                 data, ok = D.rescale(u, src.scale, dst.scale)
                 ok = ok & D.fits_precision(data, dst.precision)
                 return DevVal(data, merge_validity(valid, ok), dst)
+            if isinstance(dst, t.DoubleType):
+                from ..ops.f64bits import decimal_to_double_bits
+                bits = decimal_to_double_bits(u, src.scale)
+                return DevVal(jax.lax.bitcast_convert_type(
+                    bits, jnp.float64), valid, dst, bits=bits)
             if t.is_floating(dst):
                 f = D.to_double(u, src.scale)
                 return DevVal(f.astype(compute_dtype(dst)), valid, dst)

@@ -1358,6 +1358,28 @@ class PhysicalQuery:
             stack.extend(getattr(m, "children", ()))
         return out
 
+    def whole_plan_trace_errors(self) -> List[str]:
+        """What keeps this device plan from running as whole-plan
+        programs: the error its trace raises where a node needs the host
+        (a row count read with int(), a traced value used as a Python
+        bool), or [] when it traces.  Traces the plan as ONE program and
+        compiles and runs nothing (a split plan's segments are the same
+        nodes over seam leaves).  A client that must not be answered by
+        the eager engine asks before it collects."""
+        if self.kind != "device":
+            return [f"plan kind {self.kind!r}"]
+        from ..exec.compiled import (_TRACE_FALLBACK_ERRORS, CompiledPlan,
+                                     release_scan_uploads)
+        try:
+            CompiledPlan(self.root, self.conf).make_jaxpr(
+                ExecContext(self.conf))
+        except _TRACE_FALLBACK_ERRORS as e:
+            return [f"{type(e).__name__}: "
+                    + " ".join(str(e).split())[:300]]
+        finally:
+            release_scan_uploads(self.root)
+        return []
+
     def _instrumented(self, ctx: ExecContext):
         """Shared observability wiring: span tracer, per-op metrics,
         profiler trace, concurrency permit, budget counters

@@ -219,15 +219,20 @@ def test_encoded_off_key_discriminant_is_neutral(tables):
 # low-cardinality keys: demographic averages (q7/q26), the day-name
 # pivot (q43), and the per-channel union re-aggregations (q56/q60);
 # plus the dense-matched semi/anti presence scatters
-# (join.matchedViaPresence) in the date_dim semi-filter shapes.
+# (join.matchedViaPresence) in the date_dim semi-filter shapes, and in
+# the item and date_dim semi-filters under the window queries that trace
+# since their windows do (q12/q20/q98 one each, q70 two: each is
+# ops/join.py BuildTable.present; their windows add none).
 DS_DENSE_GROUPBY_QUERIES = {"q7", "q26", "q43", "q56", "q60",
-                            "q19", "q33", "q55", "q65", "q73", "q96"}
+                            "q19", "q33", "q55", "q65", "q73", "q96",
+                            "q12", "q20", "q70", "q98"}
 
-# Not traceable as ONE whole-plan XLA program yet: window execs make
-# host partition decisions (q12/q20/q36/q70/q86/q98) and q93's join
-# probe sizing needs concrete counts.  bench.py --suite tpcds reports
-# these in the coverage matrix; per-query stats stay None.
-DS_UNTRACEABLE = {"q12", "q20", "q36", "q70", "q86", "q93", "q98"}
+# Not traceable as ONE whole-plan XLA program yet: q93's join probe
+# sizing needs concrete counts (a build side on two keys that is not
+# unique).  bench.py --suite tpcds reports it in the coverage matrix;
+# its stats stay None.  The window queries (q12/q20/q36/q70/q86/q98)
+# trace since windows run inside the program.
+DS_UNTRACEABLE = {"q93"}
 
 
 @pytest.fixture(scope="module")
